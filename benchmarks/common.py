@@ -30,8 +30,11 @@ HISTORY = os.path.join(REPO, "BENCH_history.jsonl")
 def run_worker(code: str, n_devices: int = 1, timeout: int = 1200,
                extra_env: dict | None = None) -> dict:
     """Run a snippet in a fresh process; the snippet must print one JSON line
-    prefixed with ``RESULT:``."""
+    prefixed with ``RESULT:``.  The worker is held to the CPU (its devices
+    are fake host devices): an accelerator belongs to one process at a time,
+    and the parent may hold it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if n_devices > 1:
         env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "

@@ -300,7 +300,13 @@ def run_e2e(iters: int = 3, smoke: bool = False):
     quickstart = None
     if not smoke:
         # the acceptance workload: examples/quickstart.py --steps 500 end to
-        # end (training + periodic eval), parsed from its own report
+        # end (training + periodic eval), parsed from its own report.  The
+        # child needs the device this process already holds, so it can only
+        # run where that device is the CPU.
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "the quickstart acceptance child cannot share the "
+                f"{jax.default_backend()} device held by this process")
         import re
         import subprocess
         res = subprocess.run(

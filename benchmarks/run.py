@@ -122,4 +122,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    if common.SRC not in sys.path:
+        sys.path.insert(0, common.SRC)
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     main()

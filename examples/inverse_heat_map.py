@@ -182,4 +182,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     main()

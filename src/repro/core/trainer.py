@@ -54,7 +54,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import utils
 from repro.core import fused, halo, losses, nets
 from repro.kernels import ops
 from repro.core.domain import Decomposition, Topology
@@ -451,7 +450,7 @@ class DistributedDDTrainer(_DDCommon):
             unsq = lambda t: jax.tree.map(lambda x: x[None], t)
             return unsq(params), unsq(opt_l), step + 1, unsq(terms)
 
-        shmapped = utils.shard_map(
+        shmapped = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(spec, spec, P(), spec, spec, spec, spec),
@@ -489,7 +488,7 @@ class DistributedDDTrainer(_DDCommon):
             terms = jax.tree.map(lambda x: x[:, None], terms)
             return unsq(p), unsq(o), step + steps, terms
 
-        shmapped = utils.shard_map(
+        shmapped = jax.shard_map(
             local_chunk,
             mesh=self.mesh,
             in_specs=(spec, spec, P(), spec, spec, spec, spec),
@@ -555,7 +554,7 @@ class DistributedDDTrainer(_DDCommon):
             # good is collectively agreed -> identical on all shards (out P())
             return unsq(p), unsq(o), step + good, ok[None], good, terms
 
-        shmapped = utils.shard_map(
+        shmapped = jax.shard_map(
             local_chunk,
             mesh=self.mesh,
             in_specs=(spec, spec, P(), spec, spec, spec, spec, spec),
@@ -705,7 +704,7 @@ class DataParallelTrainer:
             return params, opt, err_new, step + 1, terms
 
         in_specs = self._specs()
-        shmapped = utils.shard_map(
+        shmapped = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=in_specs,
@@ -740,7 +739,7 @@ class DataParallelTrainer:
             return params, opt, err_new, step + steps, terms
 
         in_specs = self._specs()
-        shmapped = utils.shard_map(
+        shmapped = jax.shard_map(
             local_chunk,
             mesh=self.mesh,
             in_specs=in_specs,
@@ -801,7 +800,7 @@ class DataParallelTrainer:
             return params, opt, err_new, step + good, ok, good, terms
 
         in_specs = self._specs()[:4] + (P(), P("sub"))
-        shmapped = utils.shard_map(
+        shmapped = jax.shard_map(
             local_chunk,
             mesh=self.mesh,
             in_specs=in_specs,

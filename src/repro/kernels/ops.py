@@ -2,6 +2,7 @@
 selection (TPU targets compiled kernels; CPU validates via interpret=True)."""
 from __future__ import annotations
 
+import warnings
 from functools import partial
 
 import jax
@@ -154,17 +155,27 @@ def _fused_bwd_fits(n_weights, d_in, block_n, itemsize) -> bool:
     """Static VMEM estimate for one `_kernel2_bwd` block: residual streams
     (L·(1+2d) row tiles) + x/cu/cx + cotangent tiles + weight & cotangent
     stacks.  When the stack is too deep/wide to fit, the "fused" selector
-    degrades to the checkpointed-ref save/recompute (the documented fallback)
-    instead of dying in the Mosaic compiler — decided from static shapes, so
-    forward and backward always agree.  Hidden-layer-free stacks (depth 0:
-    one affine, nothing to spill) also take the checkpointed path — the
-    residual-saving kernel requires >= 1 hidden layer."""
+    degrades to the checkpointed-ref save/recompute instead of dying in the
+    Mosaic compiler — decided from static shapes, so forward and backward
+    always agree — and warns (once per shape, by the default warning filter)
+    with the estimate and the budget, so the step-down is never silent.
+    Hidden-layer-free stacks (depth 0: one affine, nothing to spill) also take
+    the checkpointed path — the residual-saving kernel requires >= 1 hidden
+    layer."""
     L = n_weights - 1
     if L < 1:
         return False
     row_tiles = (1 + 2 * d_in) * L + 3 + 2 * d_in     # (block_n, WPAD) tiles
     fixed = 2 * n_weights * WPAD * WPAD + 3 * n_weights * WPAD
-    return (row_tiles * block_n * WPAD + fixed) * itemsize <= _BWD_VMEM_BUDGET
+    estimate = (row_tiles * block_n * WPAD + fixed) * itemsize
+    if estimate <= _BWD_VMEM_BUDGET:
+        return True
+    warnings.warn(
+        f"fused reverse kernel needs ~{estimate} B of VMEM per block "
+        f"(budget {_BWD_VMEM_BUDGET} B) at {L} hidden layers, d_in={d_in}, "
+        f"block_n={block_n}: using the checkpointed jnp backward "
+        "(pinn2-bwd-ref) instead", RuntimeWarning)
+    return False
 
 
 def _prune_mask(d2_dirs, d_in, dtype):

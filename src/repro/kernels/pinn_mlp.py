@@ -85,7 +85,8 @@ def _kernel(x_ref, w_ref, b_ref, a_ref, u_ref, du_ref, *, n_layers, d_in, act):
     x_ref:  (block_n, WPAD)          input block (cols >= d_in are zero)
     w_ref:  (n_layers+1, WPAD, WPAD) padded weight stack
     b_ref:  (n_layers+1, WPAD)       padded biases
-    a_ref:  (n_layers+1,)            adaptive slopes (last entry unused)
+    a_ref:  (n_layers+1, WPAD)       adaptive slopes, broadcast over lanes
+                                     (last row unused)
     u_ref:  (block_n, WPAD)          primal output (cols >= out_dim are junk)
     du_ref: (d_in, block_n, WPAD)    input-Jacobian
     """
@@ -95,7 +96,7 @@ def _kernel(x_ref, w_ref, b_ref, a_ref, u_ref, du_ref, *, n_layers, d_in, act):
     # first-layer tangents: e_j @ W0 = row j of W0
     ts = [jnp.broadcast_to(w_ref[0][j, :][None, :], h.shape) for j in range(d_in)]
     for l in range(n_layers):
-        a = a_ref[l]
+        a = a_ref[l:l + 1, :]
         z = a * h
         g = phi(z)
         dg = dphi(z) * a
@@ -128,7 +129,7 @@ def _kernel2_run(x_ref, w_ref, b_ref, a_ref, u_ref, du_ref, d2u_ref,
             for j in range(d_in):
                 t_ref[l, j] = ts[j]
                 s_ref[l, j] = ss[j]
-        a = a_ref[l]
+        a = a_ref[l:l + 1, :]
         z = a * h
         d1 = dphi(z) * a
         d2 = d2phi(z) * (a * a)
@@ -218,7 +219,7 @@ def _kernel2_bwd(x_ref, w_ref, a_ref, h_ref, t_ref, s_ref,
     bar_t = [cdu_ref[j] for j in range(d_in)]
     bar_s = [cd2u_ref[j] for j in range(d_in)]
     for l in reversed(range(n_layers)):
-        a = a_ref[l]
+        a = a_ref[l:l + 1, :]
         h = h_ref[l]
         t = [t_ref[l, j] for j in range(d_in)]
         s = [s_ref[l, j] for j in range(d_in)]
@@ -267,6 +268,14 @@ def _kernel2_bwd(x_ref, w_ref, a_ref, h_ref, t_ref, s_ref,
     cb_ref[0] += jnp.sum(bar_h, axis=0)
 
 
+def _lane_slopes(a_vec):
+    """(L+1,) slopes -> (L+1, WPAD) lane-broadcast tile.  A 1-D slope operand
+    stops compiling once the launch is vmapped over subdomains: its batched
+    block (1, L+1) breaks Mosaic's rule that the last two block dims be
+    (8, 128)-divisible or span the array."""
+    return jnp.broadcast_to(a_vec[:, None], (a_vec.shape[0], WPAD))
+
+
 def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
                     block_n=256, interpret=False):
     """x_pad: (N, WPAD) with N % block_n == 0. Returns (u (N, WPAD), du (d_in, N, WPAD))."""
@@ -282,7 +291,7 @@ def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
             pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
             pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
-            pl.BlockSpec((n_layers + 1,), lambda i: (0,)),
+            pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -293,7 +302,7 @@ def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             jax.ShapeDtypeStruct((d_in, n, WPAD), x_pad.dtype),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, b_stack, a_vec)
+    )(x_pad, w_stack, b_stack, _lane_slopes(a_vec))
 
 
 def pinn_mlp_pallas2(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
@@ -312,7 +321,7 @@ def pinn_mlp_pallas2(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
             pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
             pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
-            pl.BlockSpec((n_layers + 1,), lambda i: (0,)),
+            pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -325,7 +334,7 @@ def pinn_mlp_pallas2(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             jax.ShapeDtypeStruct((d_in, n, WPAD), x_pad.dtype),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, b_stack, a_vec)
+    )(x_pad, w_stack, b_stack, _lane_slopes(a_vec))
 
 
 def pinn_mlp_pallas2_res(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
@@ -347,7 +356,7 @@ def pinn_mlp_pallas2_res(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
             pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
             pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
-            pl.BlockSpec((n_layers + 1,), lambda i: (0,)),
+            pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -368,7 +377,7 @@ def pinn_mlp_pallas2_res(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             jax.ShapeDtypeStruct((n_layers, d_in, n, WPAD), dt),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, b_stack, a_vec)
+    )(x_pad, w_stack, b_stack, _lane_slopes(a_vec))
 
 
 def pinn_mlp_pallas2_bwd(x_pad, w_stack, a_vec, h_res, t_res, s_res,
@@ -396,7 +405,7 @@ def pinn_mlp_pallas2_bwd(x_pad, w_stack, a_vec, h_res, t_res, s_res,
         in_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
             pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_layers + 1,), lambda i: (0,)),
+            pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
             pl.BlockSpec((n_layers, block_n, WPAD), lambda i: (0, i, 0)),
             pl.BlockSpec((n_layers, d_in, block_n, WPAD),
                          lambda i: (0, 0, i, 0)),
@@ -419,4 +428,5 @@ def pinn_mlp_pallas2_bwd(x_pad, w_stack, a_vec, h_res, t_res, s_res,
             jax.ShapeDtypeStruct((n_layers + 1, WPAD), dt),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, a_vec, h_res, t_res, s_res, cu, cdu, cd2u)
+    )(x_pad, w_stack, _lane_slopes(a_vec), h_res, t_res, s_res, cu, cdu,
+      cd2u)

@@ -30,7 +30,6 @@ from repro.models import batch_struct, build_model
 from repro.models import layers as layers_mod
 from repro.models.sharding import rules_for, spec as lspec, use_rules
 from repro.optim import adam as adam_lib
-from repro import utils
 from repro.utils import hlo as hlo_utils
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -161,7 +160,7 @@ def _lower_one(cfg: ModelConfig, arch: str, shape: ShapeConfig, multi_pod: bool,
            "mesh": "x".join(str(s) for s in mesh.devices.shape),
            "n_devices": int(np.prod(mesh.devices.shape))}
 
-    with utils.set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         p_struct = param_structs(model)
         if bf16_params and shape.kind != "train":
             # serving checkpoints stored bf16: no per-use converts, half the reads

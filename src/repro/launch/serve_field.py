@@ -345,4 +345,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

@@ -35,6 +35,7 @@ from repro.core.pdes import REGISTRY as PDE_REGISTRY
 from repro.data import make_batch
 from repro.models import build_model, make_batch as make_lm_batch
 from repro.optim import adam as adam_lib
+from repro.utils import enable_compile_cache
 
 
 # ------------------------------------------------------------------------ PINN
@@ -66,8 +67,11 @@ def run_pinn(args) -> dict:
 
     dd = DDConfig(method=METHODS[args.method], weights=LossWeights(),
                   couple_gradients=args.couple, local_steps=args.local_steps)
-    cls = DistributedDDTrainer if (args.distributed and
-                                   len(jax.devices()) >= topo.n_sub) else ReferenceTrainer
+    if args.distributed and len(jax.devices()) < topo.n_sub:
+        raise ValueError(
+            f"--distributed needs one device per subdomain: {topo.n_sub} "
+            f"subdomains, {len(jax.devices())} {jax.default_backend()} devices")
+    cls = DistributedDDTrainer if args.distributed else ReferenceTrainer
     trainer = cls(pde, model_cfg, topo, dd, lrs=args.lr)
     state = trainer.init(args.seed)
     b = batch.device_arrays()
@@ -194,4 +198,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

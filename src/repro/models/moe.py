@@ -202,9 +202,7 @@ def moe_ffn_shardmap(cfg: ModelConfig, p, x):
 
     tok_spec = P(batch_axes, None)
     w_spec = P("model", None, None)
-    from repro.utils import shard_map as _shard_map
-
-    out = _shard_map(
+    out = jax.shard_map(
         body,
         in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
         out_specs=tok_spec,

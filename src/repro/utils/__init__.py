@@ -1,6 +1,7 @@
 """Small shared utilities: pytree helpers, timing, numerics."""
 from __future__ import annotations
 
+import os
 import time
 from contextlib import contextmanager
 from typing import Any, Callable, Iterator
@@ -12,35 +13,30 @@ import numpy as np
 Pytree = Any
 
 
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma: bool = True):
-    """Version-compat ``shard_map``: jax >= 0.5 exposes ``jax.shard_map`` with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map`` with the
-    older ``check_rep`` spelling.  ``mesh=None`` resolves the active mesh
-    context (``utils.set_mesh`` / ``with mesh:``).  One call site, both APIs."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if mesh is None else {"mesh": mesh}
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
-    if mesh is None:
-        from jax._src.mesh import thread_resources
+    Entry points (scripts, launchers, ``chip_smoke.py``) call this before
+    their first compile; importing the library never does.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no other
+    directory is set here.  Otherwise the cache is ``<repo>/.jax_cache``: a
+    fixed path, because a later run finds its entries only under the same
+    path.
 
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise ValueError("shard_map with mesh=None needs an active mesh "
-                             "context (utils.set_mesh)")
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def set_mesh(mesh):
-    """Version-compat mesh activation: ``jax.set_mesh`` (>= 0.6) or the Mesh
-    context manager (0.4.x), under which ``with_sharding_constraint`` accepts
-    bare PartitionSpecs.  Use as ``with utils.set_mesh(mesh): ...``."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    The minimum compile time worth caching drops from JAX's 1 s to 0.  Most
+    programs an entry point compiles (eager ops, the serving engine's
+    per-bucket programs, single kernels) take less than that each, so the
+    default would compile them again on every run; reading an entry back
+    costs one small file.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        repo = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                            "..", "..", ".."))
+        path = os.path.join(repo, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def tree_stack(trees: list[Pytree]) -> Pytree:

@@ -40,11 +40,10 @@ def test_real_lowering_collectives(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.utils.hlo import collective_bytes
-from repro.utils import shard_map
 mesh = Mesh(np.array(jax.devices()), ("d",))
 def f(x):
     return jax.lax.psum(x, "d")
-sh = shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P(), check_vma=False)
+sh = jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P(), check_vma=False)
 txt = jax.jit(sh).lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile().as_text()
 out = collective_bytes(txt)
 assert out["counts"].get("all-reduce", 0) >= 1, out

@@ -510,3 +510,26 @@ def test_forward_packed_matches_unpacked():
     u2, du2 = ops.pinn_mlp_forward_packed(x, packed, out_dim=1, interpret=True)
     np.testing.assert_allclose(u1, u2, rtol=0, atol=0)
     np.testing.assert_allclose(du1, du2, rtol=0, atol=0)
+
+
+def test_fused_bwd_step_down_is_not_silent():
+    """A block too large for the fused reverse kernel's VMEM budget steps
+    down to the checkpointed backward with a warning naming the estimate and
+    the budget, and its gradients still match the ref backward."""
+    rng = np.random.default_rng(23)
+    Ws, bs, a = _mk_mlp(rng, 2, 20, 5, 1, jnp.float32)
+    x = jnp.asarray(rng.uniform(-1, 1, (40, 2)), jnp.float32)
+
+    def loss(bwd):
+        def f(Ws, bs, a):
+            u, du, d2u = pinn_mlp_forward2(x, Ws, bs, a, block_n=4096,
+                                           interpret=True, bwd=bwd)
+            return jnp.sum(u ** 2) + jnp.sum(du ** 2) + jnp.sum(d2u ** 2)
+        return f
+
+    with pytest.warns(RuntimeWarning,
+                      match=r"needs ~\d+ B of VMEM .*budget \d+ B"):
+        g = jax.grad(loss("fused"), argnums=(0, 1, 2))(Ws, bs, a)
+    g_ref = jax.grad(loss("ref"), argnums=(0, 1, 2))(Ws, bs, a)
+    for l1, l2 in zip(jax.tree.leaves(g), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(l1, l2, rtol=1e-6, atol=1e-7)

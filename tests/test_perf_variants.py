@@ -26,7 +26,6 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.models import build_model, make_batch
 from repro.models.sharding import rules_for, use_rules
-from repro.utils import set_mesh
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 cfg0 = get_config("deepseek-moe-16b").reduced(n_heads=4, n_kv_heads=4, vocab=512,
@@ -37,7 +36,7 @@ outs = {}
 for sm in (False, True):
     cfg = dataclasses.replace(cfg0, moe_shard_map=sm, dtype="float32")
     model = build_model(cfg)
-    with set_mesh(mesh), use_rules(rules_for()):
+    with jax.set_mesh(mesh), use_rules(rules_for()):
         params = model.init(jax.random.PRNGKey(0))
         loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, batch)
     outs[sm] = (float(loss), grads)
@@ -63,7 +62,6 @@ from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.models import build_model, make_batch
 from repro.models.sharding import rules_for, use_rules
-from repro.utils import set_mesh
 import dataclasses
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -77,7 +75,7 @@ for seqpar in (False, True):
     rules = rules_for()
     if seqpar:
         rules["res_seq"] = "model"
-    with set_mesh(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         params = model.init(jax.random.PRNGKey(0))
         loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, batch)
     outs[seqpar] = (float(loss), grads)
