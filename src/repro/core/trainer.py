@@ -114,22 +114,21 @@ def _traced_dispatch(trainer, name: str, steps, call):
     """Host-side chunk span around a public ``run_chunk*`` dispatch.
 
     ``trainer.tracer is None`` (the default) takes ``call()`` verbatim — no
-    span object, no clock read, no blocking, and the jitted program is the
+    span object, no annotation, no clock read, and the jitted program is the
     same object either way (trace-count/HLO parity asserted in
     tests/test_tracing.py).  With a tracer attached, the span brackets the
-    dispatch and ``block_until_ready`` pins its end to the device actually
-    finishing (chunk granularity only: ONE block per chunk, so the <= 2%
-    overhead bound of benchmarks/obs_telemetry.py holds).  The span parents
-    to the caller's active span (the supervisor's chunk root) via the
-    tracer's stack."""
+    host's dispatch only: it does not wait for the device, so tracing leaves
+    the host/device overlap as it is.  The device's side is read from the
+    device trace, where this span also stands (the tracer mirrors it onto
+    the profiler); the supervisor's chunk root, which ends after the health
+    fetch, gives the chunk's whole extent.  The span parents to the
+    caller's active span via the tracer's stack."""
     tr = getattr(trainer, "tracer", None)
     if tr is None:
         return call()
     with tr.span(name, lane="train", steps=steps,
                  trainer=type(trainer).__name__):
-        out = call()
-        jax.block_until_ready(out)
-    return out
+        return call()
 
 
 # ------------------------------------------------------- in-graph telemetry

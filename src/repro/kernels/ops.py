@@ -15,6 +15,7 @@ from repro.kernels.pinn_mlp import (
     WPAD, _act_quad, pinn_mlp_pallas, pinn_mlp_pallas2, pinn_mlp_pallas2_bwd,
     pinn_mlp_pallas2_res,
 )
+from repro.obs.profiling import scope
 
 
 def _on_tpu() -> bool:
@@ -109,9 +110,10 @@ def pinn_mlp_forward_packed(x, packed, out_dim, act="tanh", block_n=256,
 #       jax.vjp through ref.pinn_mlp_ref2 inside the backward (op-granular
 #       checkpointing).  Kept as the correctness reference and the fallback
 #       for stacks the residual-saving kernel does not cover.
-# Both paths are wrapped in jax.named_scope markers ("pinn2-bwd-fused" /
-# "pinn2-bwd-ref") so compiled-HLO tests can assert WHICH backward a training
-# step actually contains.
+# Both paths are wrapped in named-scope markers ("pinn2-bwd-fused" /
+# "pinn2-bwd-ref", repro.obs.profiling.SCOPES) so compiled-HLO tests can
+# assert WHICH backward a training step actually contains; every Pallas
+# launch also carries its own fixed name from the same table.
 
 
 def _zero_pruned_rows(d2u, d2_dirs, d_in):
@@ -224,13 +226,13 @@ def _pinn_mlp_forward2_bwd(act, block_n, interpret, d2_dirs, bwd, saved, cts):
             len(saved[1]), saved[0].shape[1], block_n,
             np.dtype(saved[0].dtype).itemsize)):
         x, Ws, bs, a = saved
-        with jax.named_scope("pinn2-bwd-ref"):
+        with scope("bwd_ref"):
             _, vjp = jax.vjp(lambda xx, W, b, aa: ref.pinn_mlp_ref2(
                 xx, W, b, aa, act=act, d2_dirs=d2_dirs), x, Ws, bs, a)
             return vjp(cts)
     if not pallas:
         x, Ws, a, res = saved
-        with jax.named_scope("pinn2-bwd-fused"):
+        with scope("bwd_fused"):
             return ref._ref2_bwd(x, Ws, a, res, _act_quad(act), d2_dirs, cts)
     x, Ws, a, h_res, t_res, s_res = saved
     L = len(Ws)
@@ -245,7 +247,7 @@ def _pinn_mlp_forward2_bwd(act, block_n, interpret, d2_dirs, bwd, saved, cts):
     n_pad = ((N + block_n - 1) // block_n) * block_n
     pad2 = lambda c: _pad_to(_pad_to(c, n_pad, 0), WPAD, 1)
     pad3 = lambda c: _pad_to(_pad_to(c, n_pad, 1), WPAD, 2)
-    with jax.named_scope("pinn2-bwd-fused"):
+    with scope("bwd_fused"):
         cx, cw, cb, ca_part = pinn_mlp_pallas2_bwd(
             _pad_points(x, block_n), w_stack, a_vec, h_res, t_res, s_res,
             pad2(cu), pad3(cdu), pad3(cd2u), d_in=d_in, act=act,
@@ -301,7 +303,7 @@ def _forward2_select_fwd(x, Ws, bs, a, code, d2_dirs):
 
 def _forward2_select_bwd(d2_dirs, saved, cts):
     x, Ws, a, code, res = saved
-    with jax.named_scope("pinn2-bwd-fused-select"):
+    with scope("bwd_fused_select"):
         cx, cWs, cbs, ca = ref._ref2_bwd(x, Ws, a, res,
                                          ref._select_quad(code), d2_dirs, cts)
     # the integer activation code has no tangent space

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.obs.profiling import SCOPES
+
 WPAD = 128  # lane-aligned padded width
 
 
@@ -276,6 +278,16 @@ def _lane_slopes(a_vec):
     return jnp.broadcast_to(a_vec[:, None], (a_vec.shape[0], WPAD))
 
 
+def _launch(phase, kernel, args, **spec):
+    """Call ``pl.pallas_call(kernel, **spec)`` on ``args`` under the launch's
+    fixed name (``SCOPES[phase]``), given both as the call's ``name`` and as
+    a named scope: the compiled custom call is then ``%<name>.N`` whatever
+    jit / vmap / jvp wraps the launch."""
+    name = SCOPES[phase]
+    with jax.named_scope(name):
+        return pl.pallas_call(kernel, name=name, **spec)(*args)
+
+
 def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
                     block_n=256, interpret=False):
     """x_pad: (N, WPAD) with N % block_n == 0. Returns (u (N, WPAD), du (d_in, N, WPAD))."""
@@ -284,8 +296,9 @@ def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
     n_layers = w_stack.shape[0] - 1
     grid = (n // block_n,)
     kernel = functools.partial(_kernel, n_layers=n_layers, d_in=d_in, act=act)
-    return pl.pallas_call(
-        kernel,
+    return _launch(
+        "kernel_eval1", kernel,
+        (x_pad, w_stack, b_stack, _lane_slopes(a_vec)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -302,7 +315,7 @@ def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             jax.ShapeDtypeStruct((d_in, n, WPAD), x_pad.dtype),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, b_stack, _lane_slopes(a_vec))
+    )
 
 
 def pinn_mlp_pallas2(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
@@ -314,8 +327,9 @@ def pinn_mlp_pallas2(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
     n_layers = w_stack.shape[0] - 1
     grid = (n // block_n,)
     kernel = functools.partial(_kernel2, n_layers=n_layers, d_in=d_in, act=act)
-    return pl.pallas_call(
-        kernel,
+    return _launch(
+        "kernel_eval2", kernel,
+        (x_pad, w_stack, b_stack, _lane_slopes(a_vec)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -334,7 +348,7 @@ def pinn_mlp_pallas2(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             jax.ShapeDtypeStruct((d_in, n, WPAD), x_pad.dtype),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, b_stack, _lane_slopes(a_vec))
+    )
 
 
 def pinn_mlp_pallas2_res(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
@@ -349,8 +363,9 @@ def pinn_mlp_pallas2_res(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
     kernel = functools.partial(_kernel2_res, n_layers=n_layers, d_in=d_in,
                                act=act)
     dt = x_pad.dtype
-    return pl.pallas_call(
-        kernel,
+    return _launch(
+        "kernel_res", kernel,
+        (x_pad, w_stack, b_stack, _lane_slopes(a_vec)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -377,7 +392,7 @@ def pinn_mlp_pallas2_res(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
             jax.ShapeDtypeStruct((n_layers, d_in, n, WPAD), dt),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, b_stack, _lane_slopes(a_vec))
+    )
 
 
 def pinn_mlp_pallas2_bwd(x_pad, w_stack, a_vec, h_res, t_res, s_res,
@@ -399,8 +414,10 @@ def pinn_mlp_pallas2_bwd(x_pad, w_stack, a_vec, h_res, t_res, s_res,
     kernel = functools.partial(_kernel2_bwd, n_layers=n_layers, d_in=d_in,
                                act=act)
     dt = x_pad.dtype
-    return pl.pallas_call(
-        kernel,
+    return _launch(
+        "bwd_fused", kernel,
+        (x_pad, w_stack, _lane_slopes(a_vec), h_res, t_res, s_res, cu, cdu,
+         cd2u),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -428,5 +445,4 @@ def pinn_mlp_pallas2_bwd(x_pad, w_stack, a_vec, h_res, t_res, s_res,
             jax.ShapeDtypeStruct((n_layers + 1, WPAD), dt),
         ],
         interpret=interpret,
-    )(x_pad, w_stack, _lane_slopes(a_vec), h_res, t_res, s_res, cu, cdu,
-      cd2u)
+    )

@@ -13,8 +13,9 @@ Entry points:
 * :class:`EventLog` / :func:`validate_events` — JSONL event sink with a
   per-run manifest and a strict, smoke-validated schema;
 * :class:`CompileWatcher` / :func:`comp_comm_split` / :func:`scope` —
-  compile/retrace counting, walltime comp-vs-comm splitting, and the
-  named-scope annotation vocabulary;
+  the compile tracer (trace / lower / backend intervals by function, cache
+  hits), walltime comp-vs-comm splitting, and the named-scope vocabulary
+  that also names every Pallas kernel launch;
 * :class:`Tracer` / :class:`Span` — span-based causal tracing with
   trace_id propagation (see EXPERIMENTS.md §Tracing), exported to
   Chrome-trace/Perfetto timelines via :mod:`repro.obs.trace_export`;

@@ -26,7 +26,9 @@ Design constraints, in order:
 * **one clock** — the tracer takes the same injectable clock as the registry
   (:func:`repro.obs.make_obs` wires them together), so span timestamps,
   metric timers, and event ``t`` fields share a timebase and tests stub time
-  instead of sleeping.
+  instead of sleeping.  Each live span also opens a
+  ``jax.profiler.TraceAnnotation``, so the same spans stand on the device
+  trace's clock in any profile recorded meanwhile.
 
 Span lifecycle: :meth:`Tracer.start_trace` opens a root, :meth:`Span.child` /
 :meth:`Tracer.span` open children (``Tracer.span`` parents to the innermost
@@ -53,7 +55,7 @@ class Span:
     """
 
     __slots__ = ("tracer", "trace_id", "span_id", "parent_id", "name",
-                 "lane", "t0", "t1", "attrs", "sampled", "_ended")
+                 "lane", "t0", "t1", "attrs", "sampled", "_ended", "_note")
 
     def __init__(self, tracer: "Tracer", trace_id: str, span_id: int,
                  parent_id: int | None, name: str, lane: str | None,
@@ -63,6 +65,7 @@ class Span:
         self.t0, self.t1 = t0, None
         self.attrs, self.sampled = attrs, sampled
         self._ended = False
+        self._note = None
 
     # ------------------------------------------------------------- tree ops
     def child(self, name: str, lane: str | None = None, **attrs) -> "Span":
@@ -98,6 +101,8 @@ class Span:
     # ------------------------------------------------- active-span stacking
     def __enter__(self) -> "Span":
         self.tracer._stack.append(self)
+        self._note = self.tracer._annotation(self.name)
+        self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -107,6 +112,8 @@ class Span:
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         self.end()
+        self._note.__exit__(exc_type, exc, tb)
+        self._note = None
         return False
 
     def __repr__(self) -> str:  # debugging/tests
@@ -118,6 +125,13 @@ class Span:
 class Tracer:
     """Bounded, samplable span recorder with one injectable clock.
 
+    Every span entered with ``with`` (or ``__enter__``) is mirrored into
+    ``jax.profiler.TraceAnnotation``: a profile recorded meanwhile
+    (``jax.profiler.trace``) shows the span on its host plane, beside the
+    device's operations, and with no profile recording it is one cheap
+    check.  Retrospective spans (``record``) and spans only ``end()``-ed
+    stay in the ring alone.
+
     ``sample_rate`` in [0, 1] is applied per TRACE by a deterministic
     systematic sampler (every 1/rate-th trace records; no RNG, so tests and
     repeated runs see identical decisions).  ``capacity`` bounds the
@@ -128,8 +142,11 @@ class Tracer:
 
     def __init__(self, clock=time.perf_counter, sample_rate: float = 1.0,
                  capacity: int = 8192):
+        import jax.profiler
+
         assert 0.0 <= sample_rate <= 1.0 and capacity > 0
         self.clock = clock
+        self._annotation = jax.profiler.TraceAnnotation
         self.sample_rate = float(sample_rate)
         self.capacity = int(capacity)
         self._ring: deque[Span] = deque(maxlen=self.capacity)
@@ -176,13 +193,17 @@ class Tracer:
                     parent.span_id, name, lane or parent.lane,
                     self.clock(), dict(attrs), parent.sampled)
 
+    def active(self) -> Span | None:
+        """The innermost span entered as a context manager, if any."""
+        return self._stack[-1] if self._stack else None
+
     def span(self, name: str, parent: Span | None = None,
              lane: str | None = None, **attrs) -> Span:
         """Open a span under ``parent``, or under the innermost ACTIVE span
         when ``parent`` is omitted (a new root if none is active).  Use as a
         context manager to make it the active span for nested calls."""
-        if parent is None and self._stack:
-            parent = self._stack[-1]
+        if parent is None:
+            parent = self.active()
         return self._open(name, parent, lane, attrs)
 
     def record(self, name: str, t0: float, t1: float,

@@ -48,7 +48,8 @@ from repro.data import make_batch
 from repro.kernels import ops
 from repro.obs import (
     CompileWatcher, Counter, EventLog, Histogram, MetricsRegistry, Obs,
-    ObsSchemaError, SCHEMA_VERSION, make_obs, read_events, validate_events,
+    ObsSchemaError, SCHEMA_VERSION, Tracer, make_obs, read_events,
+    validate_events,
 )
 from repro.runtime import Fault, FaultInjector, Supervisor, SupervisorConfig
 from repro.serve import (
@@ -282,14 +283,123 @@ def test_named_scopes_survive_into_compiled_hlo():
 # --------------------------------------------------------- retrace flatness
 
 def test_compile_watcher_counts_compiles_not_cache_hits():
+    """The watched blocks dispatch ``f`` and nothing else.  Inputs are made
+    outside them: once a trainer's ``init`` has run ``jnp.ones`` under
+    ``jax.vmap`` (``nets.stacked_init``), JAX keeps a dispatch-cache entry
+    for the eager ``convert_element_type`` that ``jnp.ones`` starts with
+    that has no fast path, so every later eager ``jnp.ones`` in the process
+    re-enters Python dispatch and reports a jaxpr trace (no compile)."""
     f = jax.jit(lambda x: x * 2 + 1)
+    xs = [jnp.ones((7,)) for _ in range(6)]
     with CompileWatcher() as w1:
-        f(jnp.ones((7,)))             # fresh shape: at least one compile
+        f(xs[0])                      # fresh shape: at least one compile
     assert w1.backend_compiles >= 1
     with CompileWatcher() as w2:
-        for _ in range(5):
-            f(jnp.ones((7,)))         # cache hits: dead flat
+        for x in xs[1:]:
+            f(x)                      # cache hits: dead flat
     assert w2.backend_compiles == 0 and w2.traces == 0
+
+
+def _fresh_compile(tmp_path):
+    def f(x):
+        return x * 2 + 1
+
+    x = jnp.ones((5,))
+    with CompileWatcher() as w:
+        jax.jit(f)(x)
+    for phase in ("trace", "lower", "backend"):
+        assert w.by_fun[phase]["f"] == 1, phase
+        assert w.seconds(phase) > 0
+    # jnp's jitted multiply and add trace inside f; one module is built
+    assert w.traces == 3 and w.backend_compiles == 1
+    assert sum(w.by_fun["lower"].values()) == 1
+    # no persistent cache here
+    assert w.by_fun["cache_hit"] == w.by_fun["cache_miss"] == {}
+    assert w.compile_seconds == w.seconds("backend")
+
+
+def _nested_union(tmp_path):
+    inner = jax.jit(lambda x: jnp.sin(x) + 1)
+
+    def outer(x):
+        return inner(x) * 2
+
+    x = jnp.ones((6,))
+    with CompileWatcher() as w:
+        jax.jit(outer)(x)
+    traces = [(a, b) for p, _f, a, b, _c in w.intervals if p == "trace"]
+    assert w.by_fun["trace"]["outer"] == w.by_fun["trace"]["<lambda>"] == 1
+    assert w.by_fun["lower"] == w.by_fun["backend"] == {"outer": 1}
+    (oa, ob), = [(a, b) for p, f, a, b, _c in w.intervals
+                 if p == "trace" and f == "outer"]
+    assert all(oa <= a and b <= ob for a, b in traces)   # inner inside outer
+    assert w.seconds("trace") == pytest.approx(ob - oa)
+    assert w.seconds("trace") < sum(b - a for a, b in traces)
+
+
+def _cache_hit(tmp_path):
+    from jax._src import compilation_cache
+
+    def g(x):
+        return jnp.cos(x) * 3
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+    x = jnp.ones((9,))
+    tracer = Tracer()
+    try:
+        for k, v in zip(keys, (str(tmp_path / "cache"), 0.0, 0)):
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        with CompileWatcher() as w1:
+            jax.jit(g)(x)
+        jax.clear_caches()
+        with tracer.span("outer"), CompileWatcher(tracer=tracer) as w2:
+            jax.jit(g)(x)
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    # counts per fun_name: the eager ops of jnp.ones have their own programs
+    assert w1.by_fun["cache_miss"].get("g") == 1
+    assert "g" not in w1.by_fun["cache_hit"]
+    assert w2.by_fun["cache_hit"].get("g") == 1
+    assert "g" not in w2.by_fun["cache_miss"]
+    backend = [s for s in tracer.spans() if s.name == "compile.backend"
+               and s.attrs["fun"] == "g"]
+    assert len(backend) == 1 and backend[0].attrs["cache"] == "hit"
+
+
+def _tracer_spans(tmp_path):
+    def h(x):
+        return jnp.tanh(x) - 1
+
+    x = jnp.ones((3,))
+    tracer = Tracer()
+    reg = MetricsRegistry()
+    with tracer.span("setup") as root:
+        with CompileWatcher(registry=reg, tracer=tracer) as w:
+            jax.jit(h)(x)
+    spans = [s for s in tracer.spans() if s.name.startswith("compile.")]
+    assert sorted(s.name for s in spans if s.attrs["fun"] == "h") == [
+        "compile.backend", "compile.lower", "compile.trace"]
+    for s in spans:
+        assert s.parent_id == root.span_id and s.trace_id == root.trace_id
+        # one offset moves time.time() onto the tracer's clock
+        assert root.t0 - 0.05 <= s.t0 <= s.t1 <= root.t1 + 0.05
+    snap = reg.snapshot("obs.compile/")
+    assert snap["obs.compile/backend_compiles"] == w.backend_compiles >= 1
+    assert snap["obs.compile/traces"] == w.traces >= 1
+
+
+@pytest.mark.parametrize("case", [_fresh_compile, _nested_union, _cache_hit,
+                                  _tracer_spans], ids=lambda c: c.__name__[1:])
+def test_compile_tracer(case, tmp_path):
+    """The compile tracer's phases, per-function counts, unions, cache tags
+    and spans, each on a function no other test compiles."""
+    case(tmp_path)
 
 
 def test_retrace_flat_across_guard_and_lr_scale():

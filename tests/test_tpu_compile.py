@@ -11,16 +11,23 @@ The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.  All such compiles stay in this one file for the same reason.
 """
+import importlib.util
+import os
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.obs.profiling import SCOPES
 from repro.kernels.pinn_mlp import (WPAD, pinn_mlp_pallas2,
                                     pinn_mlp_pallas2_bwd,
                                     pinn_mlp_pallas2_res)
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 L, D_IN, BLOCK_N, ROWS = 5, 2, 256, 20224
 N_SUB, WIDTH, N_POINTS = 4, 20, 20120
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -89,6 +96,13 @@ def test_vmapped_training_grad_compiles_for_v5e(one_chip, precision):
     ``ops._on_tpu()`` sees the CPU here)."""
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
 
+    hlo = _compile(_training_grad(), _mlp_shapes(sds), precision)
+    assert hlo.count("tpu_custom_call") >= 2   # _kernel2_res + _kernel2_bwd
+    assert "pinn2-bwd-fused" in hlo and "pinn2-bwd-ref" not in hlo
+    _assert_kernel_names(hlo, ("kernel_res", "bwd_fused"))
+
+
+def _training_grad():
     def loss(x, Ws, bs, a):
         u, du, d2u = ops.pinn_mlp_forward2(x, Ws, bs, a, interpret=False,
                                            d2_dirs=(0,))
@@ -97,16 +111,61 @@ def test_vmapped_training_grad_compiles_for_v5e(one_chip, precision):
     def total(x, Ws, bs, a):
         return jnp.sum(jax.vmap(loss)(x, Ws, bs, a))
 
-    hlo = _compile(jax.grad(total, argnums=(1, 2, 3)), _mlp_shapes(sds),
-                   precision)
-    assert hlo.count("tpu_custom_call") >= 2   # _kernel2_res + _kernel2_bwd
-    assert "pinn2-bwd-fused" in hlo and "pinn2-bwd-ref" not in hlo
+    return jax.grad(total, argnums=(1, 2, 3))
+
+
+def _serving_forward(order):
+    fwd = ops.pinn_mlp_forward2 if order == 2 else ops.pinn_mlp_forward
+    return jax.vmap(lambda x, W, b, a: fwd(x, W, b, a, interpret=False))
 
 
 def test_vmapped_serving_forward_compiles_for_v5e(one_chip):
     """The serving engine's kernel use: ``_kernel2`` vmapped over the
     stacked subdomain axis."""
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-    fn = jax.vmap(lambda x, W, b, a: ops.pinn_mlp_forward2(
-        x, W, b, a, interpret=False))
-    assert "tpu_custom_call" in _compile(fn, _mlp_shapes(sds), None)
+    hlo = _compile(_serving_forward(2), _mlp_shapes(sds), None)
+    assert "tpu_custom_call" in hlo
+    _assert_kernel_names(hlo, ("kernel_eval2",))
+
+
+def _kernel_reader(metric):
+    """``is_kernel`` of the benchmark's reader ``bench/metrics/<metric>.py``,
+    loaded by path as the harness loads it."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    spec = importlib.util.spec_from_file_location(
+        "kernel_reader_" + metric,
+        os.path.join(REPO, "bench", "metrics", metric + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.is_kernel
+
+
+def _assert_kernel_names(hlo, phases):
+    """Every compiled Pallas kernel is ``%<name>.N`` with ``<name>`` from
+    ``SCOPES``, whatever jit / vmap / jvp wraps it, and the benchmark's
+    kernel readers each accept exactly their training kernel's name and
+    never a serving forward's."""
+    calls = [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    bases = [re.fullmatch(r"%(.+)\.\d+", c).group(1) for c in calls]
+    assert sorted(set(bases)) == sorted(SCOPES[p] for p in phases), calls
+    res, bwd = (_kernel_reader("kernel_res_roofline"),
+                _kernel_reader("kernel_bwd_roofline"))
+    for c, base in zip(calls, bases):
+        name = c + " [tpu_custom_call]"       # as bench/trace.py names it
+        assert res(name) == (base == SCOPES["kernel_res"]), name
+        assert bwd(name) == (base == SCOPES["bwd_fused"]), name
+    for phase in ("kernel_eval2", "kernel_eval1"):
+        name = f"%{SCOPES[phase]}.7 [tpu_custom_call]"
+        assert not res(name) and not bwd(name)
+
+
+def test_kernel_names_are_stable_for_v5e(one_chip):
+    """The first-order serving forward's kernel, vmapped as the engine
+    vmaps it, is named from ``SCOPES`` too (the training grad's and the
+    second-order forward's names are checked where they are compiled
+    above)."""
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    hlo = _compile(_serving_forward(1), _mlp_shapes(sds), None)
+    _assert_kernel_names(hlo, ("kernel_eval1",))

@@ -358,10 +358,60 @@ def test_traced_guarded_chunk_bitwise_and_hlo_parity(trainer_setup):
         np.testing.assert_array_equal(np.asarray(t_off[k]),
                                       np.asarray(t_on[k]))
     assert bool(h_off["ok"]) == bool(h_on["ok"])
-    # exactly one dispatch span per chunk call, blocked until ready
+    # exactly one dispatch span per chunk call: the host's dispatch
     spans = tracer.spans()
     assert [s.name for s in spans] == ["train.run_chunk_guarded"]
     assert spans[0].t1 > spans[0].t0
+
+
+def test_tracer_puts_dispatch_span_on_host_plane(trainer_setup, tmp_path):
+    """A traced chunk's dispatch span is also a host event of the recorded
+    profile, where the device's operations are."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    _topo, b, tr = trainer_setup
+    state = tr.init(0)
+    tr.tracer = Tracer()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            state, _terms, health = tr.run_chunk_guarded(state, b, 4)
+            assert bool(health["ok"])
+    finally:
+        tr.tracer = None
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [e.name for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events]
+    assert host.count("train.run_chunk_guarded") == 1
+
+
+def test_dispatch_builds_annotations_only_with_a_tracer(trainer_setup,
+                                                        monkeypatch):
+    """With ``tracer=None`` the dispatch path builds no annotation (nor any
+    span); an attached tracer builds one per chunk dispatch."""
+    import jax.profiler
+
+    built = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **kw):
+        built.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    _topo, b, tr = trainer_setup
+    tr.tracer = None
+    tr.run_chunk_guarded(tr.init(0), b, 4)
+    assert built == []
+    tr.tracer = Tracer()
+    try:
+        tr.run_chunk_guarded(tr.init(0), b, 4)
+    finally:
+        tr.tracer = None
+    assert built == ["train.run_chunk_guarded"]
 
 
 def test_supervisor_one_trace_per_attempt_and_event_trace_ids(tmp_path,
