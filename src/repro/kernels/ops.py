@@ -12,8 +12,8 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.pinn_mlp import (
-    WPAD, _act_quad, pinn_mlp_pallas, pinn_mlp_pallas2, pinn_mlp_pallas2_bwd,
-    pinn_mlp_pallas2_res,
+    WPAD, _act_quad, layout, pinn_mlp_pallas, pinn_mlp_pallas2,
+    pinn_mlp_pallas2_bwd, pinn_mlp_pallas2_res,
 )
 from repro.obs.profiling import scope
 
@@ -123,21 +123,103 @@ def _zero_pruned_rows(d2u, d2_dirs, d_in):
     return d2u * _prune_mask(d2_dirs, d_in, d2u.dtype)
 
 
-def _forward2_impl(x, Ws, bs, a, act, block_n, interpret, d2_dirs):
+def _layout_of(Ws):
+    """The second-order kernels' stream layout for this net (static)."""
+    return layout(Ws[0].shape[0], max(int(w.shape[1]) for w in Ws),
+                  Ws[-1].shape[1])
+
+
+def _to_tiles(streams, lay):
+    """n_streams (N, <= seg) arrays -> (n_tiles, N, WPAD) in ``lay``: each
+    stream padded out to the whole tile at the start of its segment, and a
+    tile's streams summed (disjoint lanes: exact)."""
+    def place(st, k):
+        off = (k % lay.per_tile) * lay.seg
+        return jnp.pad(st, ((0, 0), (off, WPAD - off - st.shape[1])))
+
+    per, tiles = lay.per_tile, []
+    for i in range(lay.n_tiles):
+        placed = [place(streams[k], k) for k in range(i * per, (i + 1) * per)]
+        tiles.append(sum(placed[1:], placed[0]))
+    return jnp.stack(tiles)
+
+
+def _point_rows(x, block_n):
+    """(N, d_in) points -> (x_rows, N_pad): the coordinates as rows, the
+    points on the lanes, padded to whole blocks and 8 rows."""
     N, d_in = x.shape
-    out_dim = Ws[-1].shape[1]
+    n_pad = ((N + block_n - 1) // block_n) * block_n
+    return jnp.pad(x.T, ((0, -(-d_in // 8) * 8 - d_in), (0, n_pad - N)))
+
+
+def _out_rows(streams, lay, n_pad):
+    """n_streams (N, n_out) arrays -> the kernel's (out_rows, N_pad) rows."""
+    rows = jnp.zeros((lay.out_rows, n_pad), streams[0].dtype)
+    for k, st in enumerate(streams):
+        r = lay.out_row(k)
+        rows = rows.at[r:r + lay.n_out, :st.shape[0]].set(st.T)
+    return rows
+
+
+def _blockdiag(w, lay):
+    """(WPAD, WPAD) ``blockdiag(w, …, w)``, one copy per stream of a tile:
+    ``w`` tiled over the tile's segments and selected on the diagonal
+    blocks (exact), then padded once."""
+    per, seg = lay.per_tile, lay.seg
+    blk = _pad_to(_pad_to(w, seg, 0), seg, 1)
+    if per > 1:
+        block = np.arange(per * seg) // seg
+        blk = jnp.where(block[:, None] == block[None, :],
+                        jnp.tile(blk, (per, per)), 0.0)
+    return _pad_to(_pad_to(blk, WPAD, 0), WPAD, 1)
+
+
+def _weight_stack(Ws, lay):
+    """(L, WPAD, WPAD): W₀ padded, then ``blockdiag(W_l)`` per later layer."""
+    return jnp.stack([_pad_to(_pad_to(Ws[0], WPAD, 0), WPAD, 1)]
+                     + [_blockdiag(w, lay) for w in Ws[1:]])
+
+
+def pack_mlp2(Ws, bs, a, lay):
+    """Stack an MLP pytree for the second-order kernels in layout ``lay``.
+
+    Returns (w_stack (L, WPAD, WPAD), b_stack (L, n_tiles, WPAD), a_vec
+    (L,)): W₀ padded, then ``blockdiag(W_l)`` per later layer; each bias in
+    stream 0, and row 0 also carries the first layer's tangents t₀,j = W₀[j]
+    in stream 1 + j (they do not depend on x).  Pure pad/stack, so XLA CSEs
+    duplicate packs in one jit scope like :func:`pack_mlp`'s.
+    """
+    d_in = Ws[0].shape[0]
+    zero = jnp.zeros((1, lay.seg), bs[0].dtype)
+    rows = [[bs[0][None]] + [Ws[0][j:j + 1] for j in range(d_in)]
+            + [zero] * d_in]
+    rows += [[b[None]] + [zero] * (lay.n_streams - 1) for b in bs[1:]]
+    b_stack = jnp.stack([_to_tiles(r, lay)[:, 0] for r in rows])
+    return _weight_stack(Ws, lay), b_stack, _pad_to(a, len(Ws), 0)
+
+
+def _forward2_impl(x, Ws, bs, a, act, block_n, interpret, d2_dirs):
     if interpret is None:
         if not _on_tpu():
             return ref.pinn_mlp_ref2(x, Ws, bs, a, act=act, d2_dirs=d2_dirs)
         interpret = False
-    w_stack, b_stack, a_vec = pack_mlp(Ws, bs, a)
-    u, du, d2u = pinn_mlp_pallas2(_pad_points(x, block_n), w_stack, b_stack,
-                                  a_vec, d_in=d_in, act=act, block_n=block_n,
-                                  interpret=interpret)
+    lay = _layout_of(Ws)
+    out = pinn_mlp_pallas2(_point_rows(x, block_n), *pack_mlp2(Ws, bs, a, lay),
+                           lay=lay, act=act, block_n=block_n,
+                           interpret=interpret)
+    return _split_out(out, lay, x, d2_dirs)
+
+
+def _split_out(out, lay, x, d2_dirs):
+    """Kernel output rows -> (u (N, out), du (d_in, N, out), d2u) for the
+    call on points ``x``."""
+    N, d = x.shape[0], lay.d_in
+    st = [out[lay.out_row(k):lay.out_row(k) + lay.n_out, :N].T
+          for k in range(lay.n_streams)]
     # the VMEM-resident kernel computes every direction (pruning buys nothing
     # there); zero the unused rows so every dispatch path agrees with the ref
-    d2u = _zero_pruned_rows(d2u, d2_dirs, d_in)
-    return u[:N, :out_dim], du[:, :N, :out_dim], d2u[:, :N, :out_dim]
+    return (st[0], jnp.stack(st[1:1 + d]),
+            _zero_pruned_rows(jnp.stack(st[1 + d:]), d2_dirs, d))
 
 
 BWD_PATHS = ("fused", "ref")  # valid custom-VJP backward selectors
@@ -153,30 +235,30 @@ def _use_jnp_recurrence(interpret) -> bool:
     return interpret is None and not _on_tpu()
 
 
-def _fused_bwd_fits(n_weights, d_in, block_n, itemsize) -> bool:
-    """Static VMEM estimate for one `_kernel2_bwd` block: residual streams
-    (L·(1+2d) row tiles) + x/cu/cx + cotangent tiles + weight & cotangent
-    stacks.  When the stack is too deep/wide to fit, the "fused" selector
-    degrades to the checkpointed-ref save/recompute instead of dying in the
-    Mosaic compiler — decided from static shapes, so forward and backward
-    always agree — and warns (once per shape, by the default warning filter)
-    with the estimate and the budget, so the step-down is never silent.
-    Hidden-layer-free stacks (depth 0: one affine, nothing to spill) also take
-    the checkpointed path — the residual-saving kernel requires >= 1 hidden
-    layer."""
+def _fused_bwd_fits(lay, n_weights, block_n, itemsize) -> bool:
+    """Static VMEM estimate for one `_kernel2_bwd` block in layout ``lay``:
+    residual tiles (L·n_tiles row tiles) + x/cx + the output cotangent
+    tiles + weight & cotangent stacks.  When the stack is too deep/wide to
+    fit, the "fused" selector degrades to the checkpointed-ref
+    save/recompute instead of dying in the Mosaic compiler — decided from
+    static shapes, so forward and backward always agree — and warns (once
+    per shape, by the default warning filter) with the estimate and the
+    budget, so the step-down is never silent.  Hidden-layer-free stacks
+    (depth 0: one affine, nothing to spill) also take the checkpointed path
+    — the residual-saving kernel requires >= 1 hidden layer."""
     L = n_weights - 1
     if L < 1:
         return False
-    row_tiles = (1 + 2 * d_in) * L + 3 + 2 * d_in     # (block_n, WPAD) tiles
-    fixed = 2 * n_weights * WPAD * WPAD + 3 * n_weights * WPAD
+    row_tiles = lay.n_tiles * (L + 1) + 2            # (block_n, WPAD) tiles
+    fixed = 2 * n_weights * WPAD * WPAD + (2 + lay.n_tiles) * n_weights * WPAD
     estimate = (row_tiles * block_n * WPAD + fixed) * itemsize
     if estimate <= _BWD_VMEM_BUDGET:
         return True
     warnings.warn(
         f"fused reverse kernel needs ~{estimate} B of VMEM per block "
-        f"(budget {_BWD_VMEM_BUDGET} B) at {L} hidden layers, d_in={d_in}, "
-        f"block_n={block_n}: using the checkpointed jnp backward "
-        "(pinn2-bwd-ref) instead", RuntimeWarning)
+        f"(budget {_BWD_VMEM_BUDGET} B) at {L} hidden layers, "
+        f"d_in={lay.d_in}, {lay.name} layout, block_n={block_n}: using the "
+        "checkpointed jnp backward (pinn2-bwd-ref) instead", RuntimeWarning)
     return False
 
 
@@ -192,39 +274,38 @@ def _pinn_mlp_forward2(x, Ws, bs, a, act, block_n, interpret, d2_dirs, bwd):
     return _forward2_impl(x, Ws, bs, a, act, block_n, interpret, d2_dirs)
 
 
+def _fused_fits(Ws, x, block_n) -> bool:
+    return _fused_bwd_fits(_layout_of(Ws), len(Ws), block_n,
+                           np.dtype(x.dtype).itemsize)
+
+
 def _pinn_mlp_forward2_fwd(x, Ws, bs, a, act, block_n, interpret, d2_dirs, bwd):
-    N, d_in = x.shape
     pallas = not _use_jnp_recurrence(interpret)
-    if bwd == "ref" or (pallas and not _fused_bwd_fits(
-            len(Ws), d_in, block_n, np.dtype(x.dtype).itemsize)):
+    if bwd == "ref" or (pallas and not _fused_fits(Ws, x, block_n)):
         # checkpointed oracle: save inputs, recompute in bwd — explicitly
         # requested, or the fused reverse sweep's residual blocks won't fit
         return (_forward2_impl(x, Ws, bs, a, act, block_n, interpret, d2_dirs),
                 (x, Ws, bs, a))
-    out_dim = Ws[-1].shape[1]
     if not pallas:
         outs, res = ref._ref2_impl(x, Ws, bs, a, _act_quad(act)[:3], d2_dirs,
                                    save=True)
         return outs, (x, Ws, a, res)
-    w_stack, b_stack, a_vec = pack_mlp(Ws, bs, a)
-    u, du, d2u, h_res, t_res, s_res = pinn_mlp_pallas2_res(
-        _pad_points(x, block_n), w_stack, b_stack, a_vec, d_in=d_in, act=act,
+    lay = _layout_of(Ws)
+    out, res = pinn_mlp_pallas2_res(
+        _point_rows(x, block_n), *pack_mlp2(Ws, bs, a, lay), lay=lay, act=act,
         block_n=block_n, interpret=bool(interpret))
-    d2u = _zero_pruned_rows(d2u, d2_dirs, d_in)
-    outs = (u[:N, :out_dim], du[:, :N, :out_dim], d2u[:, :N, :out_dim])
-    # w_stack/a_vec are NOT saved: the bwd repacks them from (Ws, a) — a pure
+    # the weight stack is NOT saved: the bwd repacks it from Ws — a pure
     # pad/stack that XLA CSEs against the forward's pack (PR-1 HLO test), so
     # the residual footprint doesn't carry the padded weights twice
-    return outs, (x, Ws, a, h_res, t_res, s_res)
+    return _split_out(out, lay, x, d2_dirs), (x, Ws, a, res)
 
 
 def _pinn_mlp_forward2_bwd(act, block_n, interpret, d2_dirs, bwd, saved, cts):
     # mirror the fwd's STATIC dispatch (selector + backend + shape-derived
     # VMEM fit) so the saved-pytree structure is always interpreted correctly
     pallas = not _use_jnp_recurrence(interpret)
-    if bwd == "ref" or (pallas and not _fused_bwd_fits(
-            len(saved[1]), saved[0].shape[1], block_n,
-            np.dtype(saved[0].dtype).itemsize)):
+    if bwd == "ref" or (pallas and not _fused_fits(saved[1], saved[0],
+                                                   block_n)):
         x, Ws, bs, a = saved
         with scope("bwd_ref"):
             _, vjp = jax.vjp(lambda xx, W, b, aa: ref.pinn_mlp_ref2(
@@ -234,10 +315,8 @@ def _pinn_mlp_forward2_bwd(act, block_n, interpret, d2_dirs, bwd, saved, cts):
         x, Ws, a, res = saved
         with scope("bwd_fused"):
             return ref._ref2_bwd(x, Ws, a, res, _act_quad(act), d2_dirs, cts)
-    x, Ws, a, h_res, t_res, s_res = saved
-    L = len(Ws)
-    w_stack = jnp.stack([_pad_to(_pad_to(w, WPAD, 0), WPAD, 1) for w in Ws])
-    a_vec = _pad_to(a, L, 0)
+    x, Ws, a, res = saved
+    lay = _layout_of(Ws)
     N, d_in = x.shape
     cu, cdu, cd2u = cts
     if d2_dirs is not None and tuple(d2_dirs) != tuple(range(d_in)):
@@ -245,17 +324,34 @@ def _pinn_mlp_forward2_bwd(act, block_n, interpret, d2_dirs, bwd, saved, cts):
         # cotangents must not flow (parity with the pruned jnp backward)
         cd2u = cd2u * _prune_mask(d2_dirs, d_in, cd2u.dtype)
     n_pad = ((N + block_n - 1) // block_n) * block_n
-    pad2 = lambda c: _pad_to(_pad_to(c, n_pad, 0), WPAD, 1)
-    pad3 = lambda c: _pad_to(_pad_to(c, n_pad, 1), WPAD, 2)
+    ct = _out_rows((cu, *cdu, *cd2u), lay, n_pad)
     with scope("bwd_fused"):
         cx, cw, cb, ca_part = pinn_mlp_pallas2_bwd(
-            _pad_points(x, block_n), w_stack, a_vec, h_res, t_res, s_res,
-            pad2(cu), pad3(cdu), pad3(cd2u), d_in=d_in, act=act,
-            block_n=block_n, interpret=bool(interpret))
-    cWs = tuple(cw[i, :w.shape[0], :w.shape[1]] for i, w in enumerate(Ws))
-    cbs = tuple(cb[i, :w.shape[1]] for i, w in enumerate(Ws))
-    ca = jnp.sum(ca_part, axis=1)[:a.shape[0]].astype(a.dtype)
-    return cx[:N, :d_in], cWs, cbs, ca
+            _point_rows(x, block_n), _weight_stack(Ws, lay),
+            _pad_to(a, len(Ws), 0), res, ct,
+            lay=lay, act=act, block_n=block_n, interpret=bool(interpret))
+    return (cx[:d_in, :N].T,) + _fold_param_cts(cw, cb, Ws, lay) + (
+        jnp.sum(ca_part, axis=1)[:a.shape[0]].astype(a.dtype),)
+
+
+def _fold_param_cts(cw, cb, Ws, lay):
+    """The reverse kernel's accumulators -> (W̄s, b̄s): W̄_l sums cw's
+    stream-diagonal blocks; b̄_l is stream 0 of cb; W̄₀[j] adds the row sums
+    of t̄₀,j (stream 1 + j of cb's row 0)."""
+    seg, d_in = lay.seg, lay.d_in
+    w0 = Ws[0].shape[1]
+    per = lay.per_tile
+    cb0 = [cb[0, k // per, (k % per) * seg:(k % per) * seg + w0]
+           for k in range(1 + d_in)]
+    cWs = [cw[0, :d_in, :w0] + jnp.stack(cb0[1:])]
+    for l, w in enumerate(Ws[1:], start=1):
+        i, o = w.shape
+        blocks = [cw[l, k * seg:k * seg + i, k * seg:k * seg + o]
+                  for k in range(lay.per_tile)]
+        cWs.append(sum(blocks[1:], blocks[0]))
+    cbs = [cb0[0]] + [cb[l, 0, :w.shape[1]]
+                      for l, w in enumerate(Ws[1:], start=1)]
+    return tuple(cWs), tuple(cbs)
 
 
 _pinn_mlp_forward2.defvjp(_pinn_mlp_forward2_fwd, _pinn_mlp_forward2_bwd)

@@ -33,7 +33,8 @@ from repro.obs.events import (EVENT_KINDS, EventLog, ObsSchemaError,
                               SCHEMA_VERSION, check_fields, read_events,
                               validate_events)
 from repro.obs.profiling import (CompileWatcher, SCOPES, comp_comm_split,
-                                 compile_counts, halo_traffic, scope)
+                                 compile_counts, halo_traffic, launch_counts,
+                                 scope)
 from repro.obs.registry import (Counter, CounterGroup, Gauge, Histogram,
                                 MetricsRegistry)
 from repro.obs.trace_export import (ChromeTraceError, export_chrome_trace,
@@ -90,7 +91,7 @@ __all__ = [
     "EventLog", "ObsSchemaError", "check_fields", "read_events",
     "validate_events", "EVENT_KINDS", "SCHEMA_VERSION",
     "CompileWatcher", "SCOPES", "comp_comm_split", "compile_counts",
-    "halo_traffic", "scope",
+    "halo_traffic", "launch_counts", "scope",
     "Span", "Tracer",
     "ChromeTraceError", "export_chrome_trace", "halo_flow_events",
     "to_chrome", "training_timeline", "validate_chrome_trace",

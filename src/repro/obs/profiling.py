@@ -148,6 +148,27 @@ def compile_counts() -> dict:
             "compile_seconds": round(_seconds["backend"], 6)}
 
 
+_launches: dict[tuple[str, str], int] = defaultdict(int)
+
+
+def count_launch(phase: str, layout: str) -> None:
+    """Count one trace of a Pallas PINN launch (``phase`` from ``SCOPES``)
+    under its stream layout: ``"packed"`` (every stream in one 128-lane
+    tile) or ``"per_stream"`` (one stream per tile).  Trace time only, so a
+    step that runs a compiled program costs nothing."""
+    _launches[(phase, layout)] += 1
+
+
+def launch_counts() -> dict:
+    """Process-lifetime traced Pallas PINN launches by layout, and by
+    ``phase/layout`` (monotone, like :func:`compile_counts`)."""
+    out = {"packed": 0, "per_stream": 0}
+    for (phase, layout), n in sorted(_launches.items()):
+        out[layout] += n
+        out[f"{phase}/{layout}"] = n
+    return out
+
+
 def union_seconds(intervals) -> float:
     """Length of the union of ``(start, end)`` intervals: a nested jit's
     trace lies inside its parent's, so summing would count it twice."""

@@ -104,6 +104,12 @@ def test_forward2_width_128_exact_lanes():
     (3, 64, 5, 2),    # 3 input directions
     (2, 128, 2, 1),   # exact lane width, no padding
     (1, 33, 4, 1),    # single direction, odd width
+    (2, 20, 5, 1),    # the Burgers cell's net — streams packed in one tile
+    (2, 25, 3, 1),    # packed, just inside the fit (5 x 25 = 125 lanes)
+    (2, 26, 3, 1),    # just outside it (130 lanes): one stream per tile
+    (2, 24, 3, 3),    # packed with three outputs
+    (3, 18, 4, 2),    # packed, three input directions (7 x 18 = 126)
+    (2, 40, 2, 30),   # outputs wider than 128 / 5: two output row blocks
 ])
 def test_forward2_parity_sweep(act, dtype, d_in, width, depth, out):
     _check(act, dtype, d_in, width, depth, out)
@@ -356,10 +362,51 @@ def test_bwd_parity_pruned_dirs():
     (3, 64, 5, 2),    # 3 input directions
     (2, 128, 2, 1),   # exact lane width, no padding
     (1, 33, 4, 1),    # single direction, odd width
+    (2, 20, 5, 1),    # the Burgers cell's net — streams packed in one tile
+    (2, 25, 3, 1),    # packed, just inside the fit (5 x 25 = 125 lanes)
+    (2, 26, 3, 1),    # just outside it (130 lanes): one stream per tile
+    (2, 24, 3, 3),    # packed with three outputs
+    (3, 18, 4, 2),    # packed, three input directions (7 x 18 = 126)
+    (2, 40, 2, 30),   # outputs wider than 128 / 5: two output row blocks
 ])
 @pytest.mark.parametrize("d2_dirs", [None, (0,), ()])
 def test_bwd_parity_sweep(act, d_in, width, depth, out, d2_dirs):
     _vjp_bundle_check(act, d_in, width, depth, out, d2_dirs)
+
+
+@pytest.mark.parametrize("width,packed", [(20, True), (80, False)])
+def test_stream_layout_of_saved_residuals(width, packed):
+    """The training forward saves ONE residual stack.  The Burgers net (width
+    20, d_in 2: 5 x 20 lanes) packs its five streams into one tile per
+    layer, a fifth of the one-stream-per-tile bytes; the heat net's width
+    80 keeps one stream per tile.  The launch counter names the layout the
+    traced launches took."""
+    from repro.obs import launch_counts
+
+    L, d_in, n, block_n = 5, 2, 300, 256
+    rng = np.random.default_rng(_seed("layout", width))
+    Ws, bs, a = _mk_mlp(rng, d_in, width, L, 1, jnp.float32)
+    x = jnp.asarray(rng.uniform(-1, 1, (n, d_in)), jnp.float32)
+    before = launch_counts()
+    _, saved = jax.eval_shape(
+        lambda *p: ops._pinn_mlp_forward2_fwd(*p, "tanh", block_n, True, None,
+                                              "fused"), x, Ws, bs, a)
+    after = launch_counts()
+    (res,) = saved[3:]
+    n_pad, streams = 512, 1 + 2 * d_in
+    per_stream_bytes = L * streams * n_pad * ops.WPAD * 4
+    got_bytes = res.size * res.dtype.itemsize
+    kind = "packed" if packed else "per_stream"
+    if packed:
+        assert res.shape == (L, 1, n_pad, ops.WPAD)
+        assert got_bytes <= per_stream_bytes // 4
+    else:
+        assert res.shape == (L, streams, n_pad, ops.WPAD)
+        assert got_bytes == per_stream_bytes
+    assert after[f"kernel_res/{kind}"] == before.get(f"kernel_res/{kind}",
+                                                     0) + 1
+    other = "per_stream" if packed else "packed"
+    assert after[other] == before[other]
 
 
 def test_bwd_selector_roundtrip():

@@ -12,6 +12,11 @@ from benchmarks import setup_phases
 def test_setup_phases_driver(obs):
     import jax
 
+    # start from empty JAX caches: what an earlier test in this worker left
+    # there (the chip smoke's per-point jvp serving oracle does) can send
+    # every dispatch of the window off JAX's C++ fast path, and each such
+    # dispatch reports a trace
+    jax.clear_caches()
     cell = tiny.tiny_cell("burgers_xpinn_2x2.train", 2**31 + 11, 0.5)
     out = setup_phases.measure(cell, jax.devices()[:1], time.perf_counter(),
                                obs)

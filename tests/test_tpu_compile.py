@@ -3,9 +3,10 @@
 Compiles against a described (not attached) ``v5e:2x2`` topology, so the
 chip's own compiler refuses here what interpret mode cannot see: block
 tiling rules, VMEM limits, device memory.  Shapes are the chip smoke's
-(``chip_smoke.py``): 5 hidden layers of width 20 (padded to 128 lanes),
-``d_in = 2``, ``block_n = 256`` and 20,224 rows (20,000 residual, 40
-interface and 80 boundary points of one subdomain, padded to the block).
+(``chip_smoke.py``): 5 hidden layers of width 20 (the five tangent
+streams packed into one 128-lane tile), ``d_in = 2``, ``block_n = 256``
+and 20,224 rows (20,000 residual, 40 interface and 80 boundary points of
+one subdomain, padded to the block).
 
 The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -22,8 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.obs.profiling import SCOPES
-from repro.kernels.pinn_mlp import (WPAD, pinn_mlp_pallas2,
+from repro.obs.profiling import SCOPES, launch_counts
+from repro.kernels.pinn_mlp import (WPAD, layout, pinn_mlp_pallas2,
                                     pinn_mlp_pallas2_bwd,
                                     pinn_mlp_pallas2_res)
 
@@ -58,19 +59,22 @@ def _compile(fn, args, precision):
     return compiled.as_text()
 
 
-def _kernel_case(name, sds):
-    x = sds(ROWS, WPAD)
-    w, b, a = sds(L + 1, WPAD, WPAD), sds(L + 1, WPAD), sds(L + 1)
-    kw = dict(d_in=D_IN, act="tanh", block_n=BLOCK_N, interpret=False)
+def _kernel_case(name, sds, width):
+    """A kernel's launch and operand shapes for a net of ``width``: the
+    chip smoke's 20 packs all five streams into one tile, the heat map's
+    80 keeps one stream per tile."""
+    lay = layout(D_IN, width, 1)
+    x = sds(lay.x_rows, ROWS)
+    w, b, a = (sds(L + 1, WPAD, WPAD), sds(L + 1, lay.n_tiles, WPAD),
+               sds(L + 1))
+    kw = dict(lay=lay, act="tanh", block_n=BLOCK_N, interpret=False)
     if name == "_kernel2":
         return lambda *r: pinn_mlp_pallas2(*r, **kw), (x, w, b, a)
     if name == "_kernel2_res":
         return lambda *r: pinn_mlp_pallas2_res(*r, **kw), (x, w, b, a)
-    stream = sds(L, D_IN, ROWS, WPAD)
-    tangent = sds(D_IN, ROWS, WPAD)
     return (lambda *r: pinn_mlp_pallas2_bwd(*r, **kw),
-            (x, w, a, sds(L, ROWS, WPAD), stream, stream, x, tangent,
-             tangent))
+            (x, w, a, sds(L, lay.n_tiles, ROWS, WPAD),
+             sds(lay.out_rows, ROWS)))
 
 
 def _mlp_shapes(sds):
@@ -80,13 +84,22 @@ def _mlp_shapes(sds):
     return sds(N_SUB, N_POINTS, D_IN), Ws, bs, sds(N_SUB, L)
 
 
+_PHASES = {"_kernel2": "kernel_eval2", "_kernel2_res": "kernel_res",
+           "_kernel2_bwd": "bwd_fused"}
+
+
 @pytest.mark.parametrize("precision", PRECISIONS)
 @pytest.mark.parametrize("kernel", ["_kernel2", "_kernel2_res",
-                                    "_kernel2_bwd"])
+                                    "_kernel2_bwd", "_kernel2_res-w80",
+                                    "_kernel2_bwd-w80"])
 def test_kernel_compiles_for_v5e(one_chip, kernel, precision):
+    """Each second-order kernel compiles under its fixed name: at the
+    smoke's width 20 with the streams packed into one tile, and (``-w80``)
+    at width 80 with one stream per tile."""
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-    fn, args = _kernel_case(kernel, sds)
-    assert "tpu_custom_call" in _compile(fn, args, precision)
+    name, _, width = kernel.partition("-w")
+    fn, args = _kernel_case(name, sds, int(width or WIDTH))
+    _assert_kernel_names(_compile(fn, args, precision), (_PHASES[name],))
 
 
 @pytest.mark.parametrize("precision", PRECISIONS)
@@ -96,7 +109,12 @@ def test_vmapped_training_grad_compiles_for_v5e(one_chip, precision):
     ``ops._on_tpu()`` sees the CPU here)."""
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
 
+    before = launch_counts()
     hlo = _compile(_training_grad(), _mlp_shapes(sds), precision)
+    after = launch_counts()
+    # width 20: both kernels take the packed layout, none one stream per tile
+    assert after["packed"] - before["packed"] >= 2
+    assert after["per_stream"] == before["per_stream"]
     assert hlo.count("tpu_custom_call") >= 2   # _kernel2_res + _kernel2_bwd
     assert "pinn2-bwd-fused" in hlo and "pinn2-bwd-ref" not in hlo
     _assert_kernel_names(hlo, ("kernel_res", "bwd_fused"))
@@ -146,7 +164,8 @@ def _assert_kernel_names(hlo, phases):
     ``SCOPES``, whatever jit / vmap / jvp wraps it, and the benchmark's
     kernel readers each accept exactly their training kernel's name and
     never a serving forward's."""
-    calls = [ln.split(" = ")[0].strip() for ln in hlo.splitlines()
+    calls = [ln.split(" = ")[0].strip().removeprefix("ROOT ")
+             for ln in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     bases = [re.fullmatch(r"%(.+)\.\d+", c).group(1) for c in calls]
     assert sorted(set(bases)) == sorted(SCOPES[p] for p in phases), calls
