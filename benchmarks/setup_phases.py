@@ -19,7 +19,9 @@ program bare, as the benchmark does.  Prints one JSON line:
 * the window's compiles and traces, by ``bench/harness.CompileCount`` and
   (``--obs 1``) by ``CompileWatcher``;
 * the Pallas PINN launches traced in the process, by stream layout
-  (``repro.obs.launch_counts``: packed or one stream per tile);
+  (``repro.obs.launch_counts``: packed or one stream per tile), and the
+  collectives traced, by scope and opcode with their bytes per device
+  (``repro.obs.collective_counts``; none in a one-chip cell);
 * the program's ``train.run_chunk_guarded`` spans on the profile's host
   plane, and the chunk-boundary idle split between the harness's
   ``bench.fetch_health`` span and that dispatch span (median microseconds);
@@ -102,7 +104,8 @@ def measure(cell, devs, t_start: float, obs: bool) -> dict:
 
     from bench import flops, harness, trace
     from bench import train as btrain
-    from repro.obs import CompileWatcher, Tracer, launch_counts
+    from repro.obs import (CompileWatcher, Tracer, collective_counts,
+                           launch_counts)
 
     cfg = cell.config
     tracer = Tracer() if obs else None
@@ -126,7 +129,8 @@ def measure(cell, devs, t_start: float, obs: bool) -> dict:
     out = {"seed": cell.seed, "obs": int(obs), "setup_s": setup_s,
            "first_ok": first["ok"], "steps_per_s": steps / (t1 - t0),
            "window_compiles": {"compile_count": [cc.compiles, cc.traces]},
-           "pinn_launches": launch_counts()}
+           "pinn_launches": launch_counts(),
+           "collectives": collective_counts()}
     if obs:
         by_fun = setup.by_fun
         per = {}

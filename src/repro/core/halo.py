@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.domain import Topology
+from repro.obs.profiling import count_collective, scope
 
 
 def exchange_ppermute(payload: jax.Array, topo: Topology, axis_name: str = "sub") -> jax.Array:
@@ -32,19 +33,22 @@ def exchange_ppermute(payload: jax.Array, topo: Topology, axis_name: str = "sub"
     Bracketed by the ``dd-comm-halo`` named scope (repro.obs.profiling): every
     collective-permute the chunk driver issues carries the scope in its HLO
     op_name, so profilers and the comp/comm splitter attribute it to the
-    communication phase."""
-    with jax.named_scope("dd-comm-halo"):
+    communication phase.  Each trace counts its K collective-permutes, with
+    the bytes each device sends, in ``repro.obs.collective_counts()``."""
+    with scope("comm"):
         outs = []
         for k in range(topo.n_slots):
             outs.append(
                 jax.lax.ppermute(payload[k], axis_name=axis_name, perm=topo.perms[k])
             )
+            count_collective("comm", "collective-permute",
+                             payload[k].size * payload.dtype.itemsize)
         return jnp.stack(outs, axis=0)
 
 
 def exchange_gather(payload: jax.Array, topo: Topology) -> jax.Array:
     """payload: (n_sub, K, n_iface, C) stacked -> received, zeros where no neighbor."""
-    with jax.named_scope("dd-comm-halo"):
+    with scope("comm"):
         nbr = jnp.asarray(topo.neighbor)                # (n_sub, K)
         safe = jnp.maximum(nbr, 0)
         k_idx = jnp.arange(topo.n_slots)[None, :]       # (1, K)

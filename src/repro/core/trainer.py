@@ -60,6 +60,7 @@ from repro.core.domain import Decomposition, Topology
 from repro.core.losses import CPINN, XPINN, LossWeights, SubBatch
 from repro.core.nets import SubdomainModelConfig
 from repro.core.pdes import PDE
+from repro.obs.profiling import count_collective, scope
 from repro.optim import adam as adam_lib
 from repro.optim.compress import CompressionConfig, compress_decompress
 
@@ -406,10 +407,13 @@ class DistributedDDTrainer(_DDCommon):
         state.opt["count"] = jnp.zeros((self.topo.n_sub,), jnp.int32)
         return state
 
-    def _local_outer_body(self, params, opt, act_code, lr, wmask, batch: SubBatch):
+    def _local_outer_body(self, params, opt, act_code, lr, wmask, batch: SubBatch,
+                          exchange: bool = True):
         """One outer step for ONE shard (no leading axis), inside shard_map.
         Same single-entry-per-loss-evaluation structure as the reference
-        trainer, with ppermute as the exchange."""
+        trainer, with ppermute as the exchange (``exchange=False``: the
+        local payload in its place, as ``disable_exchange`` does — same
+        shapes, no collective)."""
         cfg = self.cfg
         net_eval = lambda p: self._net_eval(p, act_code, wmask, batch)
 
@@ -420,7 +424,7 @@ class DistributedDDTrainer(_DDCommon):
         with jax.named_scope("dd-comp-forward"):
             outs, vjp_fn = jax.vjp(net_eval, params)
         own0 = outs[1]
-        if cfg.disable_exchange:
+        if cfg.disable_exchange or not exchange:
             recv = self._maybe_stop(own0)
         else:
             recv = self._maybe_stop(halo.exchange_tree_ppermute(own0, self.topo, "sub"))
@@ -529,14 +533,21 @@ class DistributedDDTrainer(_DDCommon):
                 p2, o2, t = self._local_outer_body(p, o, ac, l, wm, b)
                 return (p2, o2), t
 
-            nan_terms = _nan_like(jax.eval_shape(live, (p, o))[1])
+            # the frozen branch's terms take their shapes from a probe that
+            # skips the exchange, so a trace issues (and counts) the halo once
+            nan_terms = _nan_like(jax.eval_shape(
+                lambda a: self._local_outer_body(*a, ac, l, wm, b,
+                                                 exchange=False)[2], (p, o)))
 
             def body(carry, _):
                 (p, o), ok, good = carry
                 # collective agreement: every shard freezes the moment ANY
                 # shard trips (one scalar pmin per step — the SPMD analogue of
                 # the reference trainer's jnp.all over the stacked ok vector)
-                all_ok = jax.lax.pmin(ok.astype(jnp.int32), "sub") > 0
+                vote = ok.astype(jnp.int32)
+                with scope("sync"):
+                    all_ok = jax.lax.pmin(vote, "sub") > 0
+                count_collective("sync", "all-reduce", vote.dtype.itemsize)
                 (p, o), terms = jax.lax.cond(all_ok, live,
                                              lambda a: (a, nan_terms), (p, o))
                 healthy = jnp.isfinite(terms["loss"]) & jnp.isfinite(_sqnorm(p))

@@ -32,9 +32,9 @@ from dataclasses import dataclass
 from repro.obs.events import (EVENT_KINDS, EventLog, ObsSchemaError,
                               SCHEMA_VERSION, check_fields, read_events,
                               validate_events)
-from repro.obs.profiling import (CompileWatcher, SCOPES, comp_comm_split,
-                                 compile_counts, halo_traffic, launch_counts,
-                                 scope)
+from repro.obs.profiling import (CompileWatcher, SCOPES, collective_counts,
+                                 comp_comm_split, compile_counts,
+                                 halo_traffic, launch_counts, scope)
 from repro.obs.registry import (Counter, CounterGroup, Gauge, Histogram,
                                 MetricsRegistry)
 from repro.obs.trace_export import (ChromeTraceError, export_chrome_trace,
@@ -90,8 +90,8 @@ __all__ = [
     "Counter", "CounterGroup", "Gauge", "Histogram", "MetricsRegistry",
     "EventLog", "ObsSchemaError", "check_fields", "read_events",
     "validate_events", "EVENT_KINDS", "SCHEMA_VERSION",
-    "CompileWatcher", "SCOPES", "comp_comm_split", "compile_counts",
-    "halo_traffic", "launch_counts", "scope",
+    "CompileWatcher", "SCOPES", "collective_counts", "comp_comm_split",
+    "compile_counts", "halo_traffic", "launch_counts", "scope",
     "Span", "Tracer",
     "ChromeTraceError", "export_chrome_trace", "halo_flow_events",
     "to_chrome", "training_timeline", "validate_chrome_trace",

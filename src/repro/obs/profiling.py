@@ -42,7 +42,9 @@ import numpy as np
 
 # The annotation scheme: one stable name per phase.  Keys are the phase
 # vocabulary ("comm", "comp_forward", ...), values the HLO-visible scope
-# names.  The ``kernel_*`` names (and ``bwd_fused``) are also the Pallas
+# names.  ``comm`` brackets the halo's collective-permutes and ``sync`` the
+# guard's per-step all-reduce (the shards' agreement to freeze).  The
+# ``kernel_*`` names (and ``bwd_fused``) are also the Pallas
 # launches' own names (``kernels/pinn_mlp.py``): each compiled custom call
 # is ``%<name>.N`` in the HLO and in the device trace, whatever jit / vmap /
 # jvp wraps it.  The training forward's name keeps ``pinn_mlp_forward2`` and
@@ -50,6 +52,7 @@ import numpy as np
 # kernel readers match on.
 SCOPES = {
     "comm": "dd-comm-halo",
+    "sync": "dd-comm-agree",
     "comp_forward": "dd-comp-forward",
     "comp_update": "dd-comp-update",
     "kernel_res": "pinn_mlp_forward2-res",
@@ -167,6 +170,27 @@ def launch_counts() -> dict:
         out[layout] += n
         out[f"{phase}/{layout}"] = n
     return out
+
+
+_collectives: dict[str, list[int]] = defaultdict(lambda: [0, 0])
+
+
+def count_collective(phase: str, opcode: str, nbytes: int) -> None:
+    """Count one trace of a collective the program issues under the scope
+    ``SCOPES[phase]``: ``opcode`` is its HLO opcode (``collective-permute``,
+    ``all-reduce``) and ``nbytes`` the payload each device sends.  Trace
+    time only, like :func:`count_launch`."""
+    c = _collectives[f"{SCOPES[phase]}/{opcode}"]
+    c[0] += 1
+    c[1] += int(nbytes)
+
+
+def collective_counts() -> dict:
+    """Process-lifetime traced collectives keyed ``"<scope>/<opcode>"``,
+    each ``{"ops": n, "bytes": bytes per device}`` (monotone, like
+    :func:`launch_counts`)."""
+    return {k: {"ops": n, "bytes": b}
+            for k, (n, b) in sorted(_collectives.items())}
 
 
 def union_seconds(intervals) -> float:

@@ -10,7 +10,8 @@ the operand size from the printed OUTPUT type signature and the op semantics:
     reduce-scatter                               : operand = output * group_size
 
 (group size parsed from ``replica_groups``; ``-start`` counted once, ``-done``
-skipped).  Totals are per-device, matching cost_analysis' per-device convention; the
+skipped; an async ``collective-permute-start`` prints (operand, output, contexts)
+and counts its operand).  Totals are per-device, matching cost_analysis' per-device convention; the
 spec's total-bytes / (chips x link_bw) equals our per-device bytes / link_bw.
 """
 from __future__ import annotations
@@ -26,8 +27,11 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# a tuple signature; a TPU layout inside it holds parentheses of its own
+# (``f32[20,1]{0,1:T(1,128)S(1)}``)
+_TUPLE = r"\((?:[^()]|\([^()]*\))*\)"
 _COLL_RE = re.compile(
-    r"=\s*(\([^)]*\)|\w+\[[\d,]*\][^\s]*)\s+"
+    r"=\s*(" + _TUPLE + r"|\w+\[[\d,]*\][^\s]*)\s+"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\("
 )
@@ -60,6 +64,23 @@ def _group_size(line: str) -> int:
     return 1
 
 
+def _op_bytes(sig: str, kind: str, start: bool, g: int) -> float:
+    """Per-device operand bytes of one collective from its printed output
+    signature (``start``: the async ``-start`` op's tuple)."""
+    out_bytes = _sig_bytes(sig)
+    if kind == "all-gather":
+        # start-op tuple prints (operand, output): take largest as output
+        return out_bytes / (1 + 1.0 / g) / g if start else out_bytes / g
+    if kind == "reduce-scatter":
+        return out_bytes * g
+    if kind == "all-reduce" and start:
+        return out_bytes / 2  # start tuple prints (operand, output)
+    if kind == "collective-permute" and start:
+        # (operand, output, context...): the operand is the first shape
+        return _sig_bytes(_SHAPE_RE.search(sig).group(0))
+    return out_bytes
+
+
 def collective_bytes(hlo_text: str) -> dict:
     """Per-device operand bytes by collective kind (+ op counts)."""
     by_kind: dict[str, float] = defaultdict(float)
@@ -69,17 +90,7 @@ def collective_bytes(hlo_text: str) -> dict:
         if not m:
             continue
         sig, kind = m.group(1), m.group(2)
-        out_bytes = _sig_bytes(sig)
-        g = _group_size(line)
-        if kind == "all-gather":
-            # start-op tuple prints (operand, output): take largest as output
-            op_bytes = out_bytes / (1 + 1.0 / g) / g if m.group(3) else out_bytes / g
-        elif kind == "reduce-scatter":
-            op_bytes = out_bytes * g
-        elif kind == "all-reduce" and m.group(3):
-            op_bytes = out_bytes / 2  # start tuple prints (operand, output)
-        else:
-            op_bytes = out_bytes
+        op_bytes = _op_bytes(sig, kind, bool(m.group(3)), _group_size(line))
         by_kind[kind] += op_bytes
         counts[kind] += 1
     return {
@@ -127,13 +138,7 @@ def top_collectives(hlo_text: str, n: int = 12) -> list[dict]:
             continue
         sig, kind = m.group(1), m.group(2)
         g = _group_size(line)
-        b = _sig_bytes(sig)
-        if kind == "all-gather":
-            b = b / (1 + 1.0 / g) / g if m.group(3) else b / g
-        elif kind == "reduce-scatter":
-            b = b * g
-        elif kind == "all-reduce" and m.group(3):
-            b = b / 2
+        b = _op_bytes(sig, kind, bool(m.group(3)), g)
         meta = re.search(r'op_name="([^"]+)"', line)
         out.append({"kind": kind, "bytes": b, "group": g, "sig": sig[:60],
                     "op_name": (meta.group(1)[-110:] if meta else "")})

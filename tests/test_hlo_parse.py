@@ -56,3 +56,17 @@ print("HLO-OK", out["total_bytes"])
 def test_op_histogram():
     hist = dict(op_histogram(HLO))
     assert hist.get("all-reduce", 0) >= 1
+
+
+def test_tpu_async_collective_permute():
+    """A v5e's collective-permute is an async pair whose start prints a
+    tuple (operand, output, contexts) with parentheses in its layouts: it
+    counts once, at the operand's bytes."""
+    lay = "{0,1:T(1,128)S(1)}"
+    hlo = (f"  %cp-start = (f32[20,1]{lay}, f32[20,1]{lay}, u32[]{{:S(2)}}, "
+           "u32[]{:S(2)}) collective-permute-start(%s), channel_id=1, "
+           "source_target_pairs={{0,1},{1,0}}\n"
+           f"  %cp-done = f32[20,1]{lay} collective-permute-done(%cp-start)\n")
+    out = collective_bytes(hlo)
+    assert out["counts"] == {"collective-permute": 1}
+    assert out["bytes_by_kind"]["collective-permute"] == 20 * 4

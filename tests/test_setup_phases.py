@@ -21,6 +21,7 @@ def test_setup_phases_driver(obs):
     out = setup_phases.measure(cell, jax.devices()[:1], time.perf_counter(),
                                obs)
     assert out["first_ok"] and out["steps_per_s"] > 0
+    assert isinstance(out["collectives"], dict)
     # the traced window compiles nothing, by either counter
     assert out["window_compiles"]["compile_count"] == [0, 0]
     spans = out["program_spans_on_host_plane"]

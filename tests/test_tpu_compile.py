@@ -6,7 +6,8 @@ tiling rules, VMEM limits, device memory.  Shapes are the chip smoke's
 (``chip_smoke.py``): 5 hidden layers of width 20 (the five tangent
 streams packed into one 128-lane tile), ``d_in = 2``, ``block_n = 256``
 and 20,224 rows (20,000 residual, 40 interface and 80 boundary points of
-one subdomain, padded to the block).
+one subdomain, padded to the block).  The four-chip cPINN strip's guarded
+chunk compiles over all four described chips at the benchmark cell's size.
 
 The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -20,9 +21,11 @@ import sys
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
+from repro.obs import halo_traffic
 from repro.obs.profiling import SCOPES, launch_counts
 from repro.kernels.pinn_mlp import (WPAD, layout, pinn_mlp_pallas2,
                                     pinn_mlp_pallas2_bwd,
@@ -36,17 +39,21 @@ PRECISIONS = [None, "highest"]  # training default; the smoke's oracle phase
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            return topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:  # no TPU compiler to describe the chip with
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, args, precision):
@@ -188,3 +195,57 @@ def test_kernel_names_are_stable_for_v5e(one_chip):
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
     hlo = _compile(_serving_forward(1), _mlp_shapes(sds), None)
     _assert_kernel_names(hlo, ("kernel_eval1",))
+
+
+def test_four_chip_cpinn_chunk_compiles_for_v5e(v5e_2x2):
+    """The benchmark cell ``burgers_cpinn_4x1.train_4chip``: the sharded
+    trainer's guarded 100-step chunk, one subdomain per described chip,
+    runs the packed fused kernels, and each scan step issues the halo's 4
+    collective-permutes (2 edge colours x ``u`` and the flux, 80 B each)
+    and the guard's one all-reduce.  ``interpret=False`` is set on the
+    trainer's residual path because ``ops._on_tpu()`` sees the CPU here."""
+    import dataclasses
+
+    import numpy as np
+
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from bench import harness, problems, train
+    from repro.core import DistributedDDTrainer, build_topology
+    from repro.core.losses import SubBatch
+
+    cfg = harness.Cell("burgers_cpinn_4x1.train_4chip", 1, 1.0, False).config
+    pde, decomp, model, dd = train.program_parts(cfg)
+    topo = build_topology(decomp, int(cfg["n_iface"]))
+    data = problems.make_data(cfg, problems.Geometry(cfg["domain"]), 1)
+    b = problems.pack_batch(data, topo.neighbor, int(cfg["n_iface"]))
+    mesh = Mesh(np.array(v5e_2x2.devices), ("sub",))
+    tr = DistributedDDTrainer(pde, model, topo, dd, mesh=mesh,
+                              act_codes=cfg["activations"],
+                              lrs=float(cfg["lr"]))
+    tr.res_path = dataclasses.replace(tr.res_path, interpret=False)
+    sub, rep = NamedSharding(mesh, P("sub")), NamedSharding(mesh, P())
+    sds = lambda x, sh: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                             sharding=sh)
+    state = jax.tree.map(lambda x: sds(x, sub if np.ndim(x) else rep),
+                         tr.init(0))
+    args = (state, SubBatch(**{k: sds(v, sub) for k, v in b.items()}),
+            sds(np.ones(4, np.float32), sub))
+
+    before = launch_counts()
+    hlo = _compile(tr._build_guarded_chunk(100), args,
+                   cfg["matmul_precision"])
+    after = launch_counts()
+    assert after["packed"] - before["packed"] >= 2
+    assert after["per_stream"] == before["per_stream"]
+    assert "tpu_custom_call" in hlo and "pinn2-bwd-ref" not in hlo
+    _assert_kernel_names(hlo, ("kernel_res", "bwd_fused"))
+    in_step = [ln for ln in hlo.splitlines() if "/while/body/" in ln]
+    permutes = [ln for ln in in_step if " collective-permute-start(" in ln]
+    agree = [ln for ln in in_step if " all-reduce(" in ln]
+    assert len(permutes) == 4 and len(agree) == 1, (permutes, agree)
+    assert all(SCOPES["comm"] in ln for ln in permutes)
+    assert SCOPES["sync"] in agree[0]
+    traffic = halo_traffic(hlo)
+    assert traffic["collective_permute_ops"] == 4
+    assert traffic["collective_permute_bytes"] == 4 * 20 * 4
