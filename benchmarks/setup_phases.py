@@ -19,7 +19,7 @@ program bare, as the benchmark does.  Prints one JSON line:
 * the window's compiles and traces, by ``bench/harness.CompileCount`` and
   (``--obs 1``) by ``CompileWatcher``;
 * the Pallas PINN launches traced in the process, by stream layout
-  (``repro.obs.launch_counts``: packed or one stream per tile), and the
+  (``repro.obs.launch_counts``: streams stacked per tile), and the
   collectives traced, by scope and opcode with their bytes per device
   (``repro.obs.collective_counts``; none in a one-chip cell);
 * the program's ``train.run_chunk_guarded`` spans on the profile's host

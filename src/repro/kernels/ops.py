@@ -129,27 +129,13 @@ def _layout_of(Ws):
                   Ws[-1].shape[1])
 
 
-def _to_tiles(streams, lay):
-    """n_streams (N, <= seg) arrays -> (n_tiles, N, WPAD) in ``lay``: each
-    stream padded out to the whole tile at the start of its segment, and a
-    tile's streams summed (disjoint lanes: exact)."""
-    def place(st, k):
-        off = (k % lay.per_tile) * lay.seg
-        return jnp.pad(st, ((0, 0), (off, WPAD - off - st.shape[1])))
-
-    per, tiles = lay.per_tile, []
-    for i in range(lay.n_tiles):
-        placed = [place(streams[k], k) for k in range(i * per, (i + 1) * per)]
-        tiles.append(sum(placed[1:], placed[0]))
-    return jnp.stack(tiles)
-
-
-def _point_rows(x, block_n):
-    """(N, d_in) points -> (x_rows, N_pad): the coordinates as rows, the
-    points on the lanes, padded to whole blocks and 8 rows."""
+def _point_rows(x, lay, block_n):
+    """(N, d_in) points -> (x_rows, N_pad): the coordinates as rows and a row
+    of ones after them, the points on the lanes, padded to whole blocks."""
     N, d_in = x.shape
     n_pad = ((N + block_n - 1) // block_n) * block_n
-    return jnp.pad(x.T, ((0, -(-d_in // 8) * 8 - d_in), (0, n_pad - N)))
+    rows = jnp.concatenate([x.T, jnp.ones((1, N), x.dtype)])
+    return jnp.pad(rows, ((0, lay.x_rows - d_in - 1), (0, n_pad - N)))
 
 
 def _out_rows(streams, lay, n_pad):
@@ -163,8 +149,8 @@ def _out_rows(streams, lay, n_pad):
 
 def _blockdiag(w, lay):
     """(WPAD, WPAD) ``blockdiag(w, …, w)``, one copy per stream of a tile:
-    ``w`` tiled over the tile's segments and selected on the diagonal
-    blocks (exact), then padded once."""
+    ``w`` padded to (seg, seg), tiled over the tile's streams and selected
+    on the diagonal blocks (exact), then padded once."""
     per, seg = lay.per_tile, lay.seg
     blk = _pad_to(_pad_to(w, seg, 0), seg, 1)
     if per > 1:
@@ -174,28 +160,39 @@ def _blockdiag(w, lay):
     return _pad_to(_pad_to(blk, WPAD, 0), WPAD, 1)
 
 
-def _weight_stack(Ws, lay):
-    """(L, WPAD, WPAD): W₀ padded, then ``blockdiag(W_l)`` per later layer."""
-    return jnp.stack([_pad_to(_pad_to(Ws[0], WPAD, 0), WPAD, 1)]
-                     + [_blockdiag(w, lay) for w in Ws[1:]])
+def _hidden_stack(Ws, lay):
+    """(L, WPAD, WPAD): ``blockdiag(W_lᵀ)`` of each layer after the first —
+    the forward's orientation (the reverse takes its transpose)."""
+    return jnp.stack([_blockdiag(w.T, lay) for w in Ws[1:]])
+
+
+def _first_layer(W0, b0, lay):
+    """(n_tiles, WPAD, x_rows): the first layer against x's rows, tile by
+    tile.  h's rows hold W₀ᵀ and, in the ones column, b₀; t_j's rows hold
+    t₀,j = W₀[j] in the ones column (the tangents do not depend on x); the
+    s_j rows stay zero (s₀ = 0)."""
+    d = lay.d_in
+    blocks = [jnp.concatenate([W0.T, b0[:, None]], axis=1)]
+    blocks += [jnp.pad(W0[j][:, None], ((0, 0), (d, 0))) for j in range(d)]
+    w0 = jnp.zeros((lay.n_tiles * WPAD, lay.x_rows), W0.dtype)
+    for k, blk in enumerate(blocks):
+        r = lay.row(k)
+        w0 = w0.at[r:r + blk.shape[0], :blk.shape[1]].set(blk)
+    return w0.reshape(lay.n_tiles, WPAD, lay.x_rows)
 
 
 def pack_mlp2(Ws, bs, a, lay):
-    """Stack an MLP pytree for the second-order kernels in layout ``lay``.
+    """Stack an MLP pytree for the second-order forward kernels in ``lay``.
 
-    Returns (w_stack (L, WPAD, WPAD), b_stack (L, n_tiles, WPAD), a_vec
-    (L,)): W₀ padded, then ``blockdiag(W_l)`` per later layer; each bias in
-    stream 0, and row 0 also carries the first layer's tangents t₀,j = W₀[j]
-    in stream 1 + j (they do not depend on x).  Pure pad/stack, so XLA CSEs
-    duplicate packs in one jit scope like :func:`pack_mlp`'s.
+    Returns (w0 (n_tiles, WPAD, x_rows), w_stack (L, WPAD, WPAD), b_stack
+    (L, seg), a_vec (L,)): the first layer against x's rows
+    (:func:`_first_layer`), ``blockdiag(W_lᵀ)`` and the bias of each later
+    layer.  Pure pad/stack, so XLA CSEs duplicate packs in one jit scope
+    like :func:`pack_mlp`'s.
     """
-    d_in = Ws[0].shape[0]
-    zero = jnp.zeros((1, lay.seg), bs[0].dtype)
-    rows = [[bs[0][None]] + [Ws[0][j:j + 1] for j in range(d_in)]
-            + [zero] * d_in]
-    rows += [[b[None]] + [zero] * (lay.n_streams - 1) for b in bs[1:]]
-    b_stack = jnp.stack([_to_tiles(r, lay)[:, 0] for r in rows])
-    return _weight_stack(Ws, lay), b_stack, _pad_to(a, len(Ws), 0)
+    b_stack = jnp.stack([_pad_to(b, lay.seg, 0) for b in bs[1:]])
+    return (_first_layer(Ws[0], bs[0], lay), _hidden_stack(Ws, lay), b_stack,
+            _pad_to(a, len(Ws) - 1, 0))
 
 
 def _forward2_impl(x, Ws, bs, a, act, block_n, interpret, d2_dirs):
@@ -204,7 +201,8 @@ def _forward2_impl(x, Ws, bs, a, act, block_n, interpret, d2_dirs):
             return ref.pinn_mlp_ref2(x, Ws, bs, a, act=act, d2_dirs=d2_dirs)
         interpret = False
     lay = _layout_of(Ws)
-    out = pinn_mlp_pallas2(_point_rows(x, block_n), *pack_mlp2(Ws, bs, a, lay),
+    out = pinn_mlp_pallas2(_point_rows(x, lay, block_n),
+                           *pack_mlp2(Ws, bs, a, lay),
                            lay=lay, act=act, block_n=block_n,
                            interpret=interpret)
     return _split_out(out, lay, x, d2_dirs)
@@ -237,8 +235,8 @@ def _use_jnp_recurrence(interpret) -> bool:
 
 def _fused_bwd_fits(lay, n_weights, block_n, itemsize) -> bool:
     """Static VMEM estimate for one `_kernel2_bwd` block in layout ``lay``:
-    residual tiles (L·n_tiles row tiles) + x/cx + the output cotangent
-    tiles + weight & cotangent stacks.  When the stack is too deep/wide to
+    the stash rows + the live cotangent tiles + x/cx + the output cotangent
+    rows + weight & cotangent stacks.  When the stack is too deep/wide to
     fit, the "fused" selector degrades to the checkpointed-ref
     save/recompute instead of dying in the Mosaic compiler — decided from
     static shapes, so forward and backward always agree — and warns (once
@@ -249,9 +247,11 @@ def _fused_bwd_fits(lay, n_weights, block_n, itemsize) -> bool:
     L = n_weights - 1
     if L < 1:
         return False
-    row_tiles = lay.n_tiles * (L + 1) + 2            # (block_n, WPAD) tiles
-    fixed = 2 * n_weights * WPAD * WPAD + (2 + lay.n_tiles) * n_weights * WPAD
-    estimate = (row_tiles * block_n * WPAD + fixed) * itemsize
+    rows = (L * lay.res_rows + lay.n_tiles * WPAD + 2 * lay.x_rows
+            + lay.out_rows)
+    fixed = (2 * L * WPAD * WPAD + (lay.n_tiles + 1) * WPAD * lay.x_rows
+             + 2 * L * lay.seg * WPAD)
+    estimate = (rows * block_n + fixed) * itemsize
     if estimate <= _BWD_VMEM_BUDGET:
         return True
     warnings.warn(
@@ -292,8 +292,8 @@ def _pinn_mlp_forward2_fwd(x, Ws, bs, a, act, block_n, interpret, d2_dirs, bwd):
         return outs, (x, Ws, a, res)
     lay = _layout_of(Ws)
     out, res = pinn_mlp_pallas2_res(
-        _point_rows(x, block_n), *pack_mlp2(Ws, bs, a, lay), lay=lay, act=act,
-        block_n=block_n, interpret=bool(interpret))
+        _point_rows(x, lay, block_n), *pack_mlp2(Ws, bs, a, lay), lay=lay,
+        act=act, block_n=block_n, interpret=bool(interpret))
     # the weight stack is NOT saved: the bwd repacks it from Ws — a pure
     # pad/stack that XLA CSEs against the forward's pack (PR-1 HLO test), so
     # the residual footprint doesn't carry the padded weights twice
@@ -325,32 +325,35 @@ def _pinn_mlp_forward2_bwd(act, block_n, interpret, d2_dirs, bwd, saved, cts):
         cd2u = cd2u * _prune_mask(d2_dirs, d_in, cd2u.dtype)
     n_pad = ((N + block_n - 1) // block_n) * block_n
     ct = _out_rows((cu, *cdu, *cd2u), lay, n_pad)
+    w0t = _pad_to(_pad_to(Ws[0], lay.x_rows, 0), WPAD, 1)
+    # the forward's stack (XLA CSEs the pack), in the reverse's orientation
+    w_stack = jnp.swapaxes(_hidden_stack(Ws, lay), 1, 2)
     with scope("bwd_fused"):
-        cx, cw, cb, ca_part = pinn_mlp_pallas2_bwd(
-            _point_rows(x, block_n), _weight_stack(Ws, lay),
-            _pad_to(a, len(Ws), 0), res, ct,
+        cx, cw0, cw, cb, ca = pinn_mlp_pallas2_bwd(
+            _point_rows(x, lay, block_n), w0t, w_stack,
+            _pad_to(a, len(Ws) - 1, 0), res, ct,
             lay=lay, act=act, block_n=block_n, interpret=bool(interpret))
-    return (cx[:d_in, :N].T,) + _fold_param_cts(cw, cb, Ws, lay) + (
-        jnp.sum(ca_part, axis=1)[:a.shape[0]].astype(a.dtype),)
+    return (cx[:d_in, :N].T,) + _fold_param_cts(cw0, cw, cb, Ws, lay) + (
+        jnp.sum(ca, axis=(1, 2))[:a.shape[0]].astype(a.dtype),)
 
 
-def _fold_param_cts(cw, cb, Ws, lay):
-    """The reverse kernel's accumulators -> (W̄s, b̄s): W̄_l sums cw's
-    stream-diagonal blocks; b̄_l is stream 0 of cb; W̄₀[j] adds the row sums
-    of t̄₀,j (stream 1 + j of cb's row 0)."""
-    seg, d_in = lay.seg, lay.d_in
-    w0 = Ws[0].shape[1]
-    per = lay.per_tile
-    cb0 = [cb[0, k // per, (k % per) * seg:(k % per) * seg + w0]
-           for k in range(1 + d_in)]
-    cWs = [cw[0, :d_in, :w0] + jnp.stack(cb0[1:])]
-    for l, w in enumerate(Ws[1:], start=1):
+def _fold_param_cts(cw0, cw, cb, Ws, lay):
+    """The reverse kernel's accumulators -> (W̄s, b̄s): W̄₀ is cw0's h rows
+    against x's rows, plus each t̄₀,j's sum over points (t_j's rows of the
+    ones column); b̄₀ is h's rows of the ones column; W̄_l sums cw's
+    stream-diagonal blocks; b̄_l sums cb's lane partials."""
+    d, seg, w0 = lay.d_in, lay.seg, Ws[0].shape[1]
+    ones = cw0.reshape(-1, lay.x_rows)[:, d]
+    cWs = [cw0[0, :w0, :d].T
+           + jnp.stack([ones[lay.row(1 + j):lay.row(1 + j) + w0]
+                        for j in range(d)])]
+    cbs = [ones[:w0]]
+    for l, w in enumerate(Ws[1:]):
         i, o = w.shape
         blocks = [cw[l, k * seg:k * seg + i, k * seg:k * seg + o]
                   for k in range(lay.per_tile)]
         cWs.append(sum(blocks[1:], blocks[0]))
-    cbs = [cb0[0]] + [cb[l, 0, :w.shape[1]]
-                      for l, w in enumerate(Ws[1:], start=1)]
+        cbs.append(jnp.sum(cb[l, :o], axis=1))
     return tuple(cWs), tuple(cbs)
 
 

@@ -16,11 +16,13 @@ second derivatives d²u/dx_j² — together with (u, du) everything the Burgers 
 Navier-Stokes / heat-conduction residuals and cPINN fluxes consume, in ONE
 VMEM-resident pass.
 
-Tiling: grid over collocation-point blocks (``block_n`` rows, 8-row sublane
-aligned); weights are padded to (WPAD, WPAD) = (128, 128) lanes — MXU-aligned.
-The second-order kernels lane-pack their 1 + 2·d_in streams (h, t_j, s_j)
-into one tile where they fit (:class:`Layout`), each affine layer then one
-matmul against ``blockdiag(W, …, W)``; wider nets keep a tile per stream.
+Tiling: grid over collocation-point blocks of ``block_n`` points; weights
+are padded to (WPAD, WPAD) = (128, 128) — MXU-aligned.  The first-order
+kernel carries the points on the sublanes.  The second-order kernels carry
+them on the lanes and stack their 1 + 2·d_in streams (h, t_j, s_j) on the
+sublanes, 8-row aligned (:class:`Layout`): each affine layer is one matmul
+per tile of streams against ``blockdiag(Wᵀ, …, Wᵀ)``, and no value crosses
+lanes.
 Adaptive activations (tanh/sin/cos x trainable slope, paper refs [26,27]) are
 selected statically per call.
 
@@ -126,26 +128,30 @@ def _kernel(x_ref, w_ref, b_ref, a_ref, u_ref, du_ref, *, n_layers, d_in, act):
 
 @dataclasses.dataclass(frozen=True)
 class Layout:
-    """Where the second-order kernels keep their 1 + 2·d_in row streams.
+    """Where the second-order kernels keep their 1 + 2·d_in streams.
 
     Stream 0 is h (the running affine output), streams 1..d_in the first
-    tangents t_j and streams d_in+1..2·d_in the second tangents s_j.  Stream
-    k lies in tile ``k // per_tile`` at lanes ``[(k % per_tile)·seg,
-    (k % per_tile + 1)·seg)``.  A narrow net packs every stream into ONE
-    128-lane tile (``seg`` = its width), so each affine layer is one matmul
-    against ``blockdiag(W, …, W)``; a wide one gives each stream a tile of
-    its own (``seg`` = WPAD, one stream per tile).  Both run the same
-    kernel code: only the lane moves and kind selects below differ.
+    tangents t_j and streams d_in+1..2·d_in the second tangents s_j.  Inside
+    a kernel every stream is a ``(seg, block_n)`` block with the points on
+    the lanes: ``seg`` rows, the net's widest layer rounded up to whole
+    8-row sublane groups.  An affine layer multiplies ``(WPAD, block_n)``
+    tiles that stack ``per_tile`` streams on the sublanes by
+    ``blockdiag(Wᵀ, …, Wᵀ)``: stream k lies in tile ``k // per_tile`` at
+    rows ``(k % per_tile)·seg``.  Every offset is a multiple of 8 rows, so
+    putting a stream into a tile or taking it out moves whole vregs, and no
+    value ever crosses lanes.  The rule is read from the shapes: width 20 at
+    d_in 2 packs five 24-row streams into one tile, width 80 takes a tile
+    per stream.
 
-    Per-point kernel I/O crosses HBM with the points on the lanes: x as
-    ``x_rows`` rows (coordinate j in row j), the output streams and their
-    cotangents as ``out_rows`` rows (output o of stream k in row
-    ``k·n_out + o`` of its row block), so neither the kernel nor the XLA
-    code around it moves 128-lane rows that hold one or two values.
+    Per-point kernel I/O crosses HBM the same way, as rows with the points
+    on the lanes: x as ``x_rows`` rows (coordinate j in row j, then a row of
+    ones that carries the first layer's bias and tangents), output o of
+    stream k in row ``k·n_out + o`` of ``out_rows``, and the training
+    forward's stash as the streams stacked, ``res_rows`` rows per layer.
     """
 
     d_in: int
-    seg: int    # lanes per stream; WPAD for one stream per tile
+    seg: int    # rows per stream
     n_out: int  # the net's output width
 
     @property
@@ -153,46 +159,41 @@ class Layout:
         return 1 + 2 * self.d_in
 
     @property
-    def packed(self) -> bool:
-        return self.seg < WPAD
-
-    @property
     def per_tile(self) -> int:
-        return self.n_streams if self.packed else 1
+        return min(WPAD // self.seg, self.n_streams)
 
     @property
     def n_tiles(self) -> int:
-        return self.n_streams // self.per_tile
+        return -(-self.n_streams // self.per_tile)
 
     @property
     def name(self) -> str:
-        return "packed" if self.packed else "per_stream"
+        return f"{self.per_tile}_per_tile"
+
+    def row(self, k: int) -> int:
+        """Stream k's first row in the stack of tiles."""
+        return (k // self.per_tile) * WPAD + (k % self.per_tile) * self.seg
+
+    def tile_rows(self, i: int) -> int:
+        """Rows of tile i that hold streams (the rest are zero)."""
+        return self.seg * min(self.per_tile,
+                              self.n_streams - i * self.per_tile)
 
     @property
     def x_rows(self) -> int:
-        return _round8(self.d_in)
-
-    @property
-    def out_per_block(self) -> int:
-        """Output streams per row block (all of them unless n_out is wide)."""
-        return min(self.n_streams, WPAD // self.n_out)
-
-    @property
-    def out_blocks(self) -> int:
-        return -(-self.n_streams // self.out_per_block)
-
-    @property
-    def block_rows(self) -> int:
-        return _round8(self.out_per_block * self.n_out)
+        return _round8(self.d_in + 1)
 
     @property
     def out_rows(self) -> int:
-        return self.out_blocks * self.block_rows
+        return _round8(self.n_streams * self.n_out)
 
     def out_row(self, k: int) -> int:
         """The row of stream k's first output."""
-        b, r = divmod(k, self.out_per_block)
-        return b * self.block_rows + r * self.n_out
+        return k * self.n_out
+
+    @property
+    def res_rows(self) -> int:
+        return self.n_streams * self.seg
 
 
 def _round8(n: int) -> int:
@@ -200,216 +201,134 @@ def _round8(n: int) -> int:
 
 
 def layout(d_in: int, width: int, n_out: int) -> Layout:
-    """Pack all 1 + 2·d_in streams into one tile, ``width`` lanes each, when
-    they fit in WPAD lanes (``width``: the net's widest layer, hidden or
-    output); else one stream per tile."""
-    return Layout(d_in, width if (1 + 2 * d_in) * width <= WPAD else WPAD,
-                  n_out)
+    """The stream layout of a net whose widest layer (hidden or output) is
+    ``width`` <= WPAD."""
+    assert width <= WPAD, width
+    return Layout(d_in, _round8(width), n_out)
 
 
-def _roll(v, lanes):
-    """Rotate a tile by ``lanes`` along the lane axis (lane i -> i + lanes,
-    mod WPAD): a whole-tile XLU rotation, never an unaligned slice."""
-    lanes %= WPAD
-    return pltpu.roll(v, lanes, 1) if lanes else v
-
-
-def _lane():
-    return jax.lax.broadcasted_iota(jnp.int32, (1, WPAD), 1)
-
-
-def _by_kind(lay, i, h, t, s):
-    """Tile ``i`` of a stream-wise expression whose value on the h, t_j and
-    s_j streams is given by the zero-argument callables ``h``, ``t`` and
-    ``s``.  A tile of one stream evaluates only its own kind; the packed
-    tile selects by lane (lanes past the last segment take ``s``)."""
-    if not lay.packed:
-        return (h if i == 0 else t if i <= lay.d_in else s)()
-    lane = _lane()
-    return jnp.where(lane < lay.seg, h(),
-                     jnp.where(lane < (1 + lay.d_in) * lay.seg, t(), s()))
-
-
-def _spread_h(lay, tiles):
-    """One tile whose every stream holds h: what each stream's activation
-    factors phi^(k)(a·h) are computed from (the h tile itself when streams
-    have tiles of their own)."""
-    p = tiles[0]
-    if not lay.packed:
-        return p
-    lane, out = _lane(), p
-    for k in range(1, lay.n_streams):
-        out = jnp.where(lane >= k * lay.seg, _roll(p, k * lay.seg), out)
-    return out
-
-
-def _shift(lay, tiles, d):
-    """Per tile, stream k's lanes hold stream k - d (junk where k - d is
-    no stream; callers read only the streams they align)."""
-    if lay.packed:
-        return [_roll(tiles[0], d * lay.seg)]
-    n = lay.n_tiles
-    return [tiles[(i - d) % n] for i in range(n)]
-
-
-def _sum_streams(lay, tiles):
-    """A tile whose stream-0 lanes hold the sum over every stream."""
-    if not lay.packed:
-        return sum(tiles[1:], tiles[0])
-    p = tiles[0]
-    out = p
-    for k in range(1, lay.n_streams):
-        out = out + _roll(p, -k * lay.seg)
-    return out
-
-
-def _pad_rows(v):
-    """(r, block_n) rows -> (WPAD, block_n), zero rows appended."""
-    r, n = v.shape
-    if r == WPAD:
-        return v
-    return jnp.concatenate([v, jnp.zeros((WPAD - r, n), v.dtype)], axis=0)
-
-
-def _rows_to_tile(v):
-    """(r, block_n) rows, points on the lanes -> (block_n, WPAD) tile with
-    row j in lane j (one whole-tile transpose)."""
-    return _pad_rows(v).T
-
-
-def _lanes_at(lane, at, n):
-    return (lane >= at) & (lane < at + n)
-
-
-def _store_outputs(lay, tiles, out_ref):
-    """The output streams (in ``lay``'s tiles, outputs at the start of each
-    stream's lanes) -> ``out_ref`` rows: each row block gathers its streams'
-    outputs side by side on the lanes of one tile, then transposes it."""
-    lane, per, m = _lane(), lay.out_per_block, lay.block_rows
-    for b in range(lay.out_blocks):
-        acc = None
-        for k in range(b * per, min((b + 1) * per, lay.n_streams)):
-            at = (k - b * per) * lay.n_out
-            v = _roll(tiles[k // lay.per_tile],
-                      at - (k % lay.per_tile) * lay.seg)
-            acc = v if acc is None else jnp.where(
-                _lanes_at(lane, at, lay.n_out), v, acc)
-        out_ref[b * m:(b + 1) * m, :] = acc.T[:m]
-
-
-def _load_outputs(lay, ct_ref):
-    """Inverse of :func:`_store_outputs`: ``ct_ref`` rows -> the cotangent
-    streams in ``lay``'s tiles, zero outside each stream's outputs."""
-    lane, per, m = _lane(), lay.out_per_block, lay.block_rows
-    blocks = [_rows_to_tile(ct_ref[b * m:(b + 1) * m, :])
-              for b in range(lay.out_blocks)]
-    tiles = []
+def _tiles(lay, streams):
+    """The streams stacked on the sublanes into (WPAD, block_n) tiles."""
+    n, tiles = streams[0].shape[1], []
     for i in range(lay.n_tiles):
-        acc = jnp.zeros_like(blocks[0])
-        for k in range(i * lay.per_tile, (i + 1) * lay.per_tile):
-            at = (k % lay.per_tile) * lay.seg
-            v = _roll(blocks[k // per], at - (k % per) * lay.n_out)
-            acc = jnp.where(_lanes_at(lane, at, lay.n_out), v, acc)
-        tiles.append(acc)
+        blocks = streams[i * lay.per_tile:(i + 1) * lay.per_tile]
+        if lay.tile_rows(i) < WPAD:
+            blocks = blocks + [jnp.zeros((WPAD - lay.tile_rows(i), n),
+                                         streams[0].dtype)]
+        tiles.append(jnp.concatenate(blocks, axis=0))
     return tiles
 
 
-_CHAINS = 4  # independent tile chains the forward's scheduler overlaps
+def _affine(lay, ws, tiles):
+    """Per tile i, the first ``tile_rows(i)`` rows of the weight block
+    ``ws[i]`` (a ref view) times ``tiles[i]``, split back into streams."""
+    out = []
+    for i, (w, v) in enumerate(zip(ws, tiles)):
+        p = w[...][:lay.tile_rows(i)] @ v
+        out += [p[r:r + lay.seg] for r in range(0, lay.tile_rows(i), lay.seg)]
+    return out
 
 
-def _row_groups(block_n, lay):
-    """Row slices of a block, each carried through the layer stack as its
-    own chain, so the scheduler overlaps one chain's matmul with another's
-    activation stage (one chain of dependent layers leaves the MXU and the
-    vector units taking turns).  One stream per tile already gives
-    ``n_tiles`` chains per layer; the packed tile is split into _CHAINS
-    row groups (8-row aligned)."""
-    n = max(1, _CHAINS // lay.n_tiles)
-    while n > 1 and block_n % (8 * n):
-        n -= 1
-    step = block_n // n
-    return [slice(r, r + step) for r in range(0, block_n, step)]
+def _dot_nt(u, v):
+    """``u @ vᵀ`` contracting the lanes (the points) of both."""
+    return jax.lax.dot_general(u, v, (((1,), (1,)), ((), ())))
 
 
-def _kernel2_run(x_ref, w_ref, b_ref, a_ref, out_ref, res_ref, *, n_layers,
-                 lay, act):
+def _lane_sum(v, m):
+    """(r, block_n) -> (r, m): the sum of the m-lane column blocks (whole
+    vregs for m = WPAD; the caller reduces the lanes once, outside)."""
+    return sum(v[:, c:c + m] for c in range(m, v.shape[1], m)) + v[:, :m]
+
+
+def _store_outputs(lay, streams, out_ref):
+    """Each stream's first ``n_out`` rows -> its rows of ``out_ref``."""
+    m = lay.n_out
+    for k, v in enumerate(streams):
+        out_ref[lay.out_row(k):lay.out_row(k) + m, :] = v[:m]
+
+
+def _load_outputs(lay, ct_ref):
+    """Inverse of :func:`_store_outputs`: the cotangent streams, zero
+    outside each stream's outputs."""
+    m, n = lay.n_out, ct_ref.shape[1]
+    pad = jnp.zeros((lay.seg - m, n), ct_ref.dtype)
+    return [jnp.concatenate([ct_ref[lay.out_row(k):lay.out_row(k) + m, :],
+                             pad], axis=0)
+            for k in range(lay.n_streams)]
+
+
+def _kernel2_run(x_ref, w0_ref, w_ref, b_ref, a_ref, out_ref, res_ref, *,
+                 n_layers, lay, act):
     """Shared second-order recurrence body (ONE copy of the tangent math).
 
     Per direction j the streams are (t_j, s_j) = (first, second) forward
     tangents of the running affine output h.  Through an activation
     ``g = phi(a h)``:  ``t -> phi'(a h)·a·t``,  ``s -> phi''(a h)·a²·t² +
     phi'(a h)·a·s``; through an affine layer all streams multiply by W.
-    The first layer's tangents t_j = row j of W₀ do not depend on x, so they
-    enter as bias (``b_ref[0]``) and s₀ = 0.
+    The activation factors are evaluated once, on h's rows, and each
+    stream's rule runs on its own rows.  The first layer is one matmul
+    against x's rows: the ones row brings h's bias and the tangents
+    t_j = W₀[j], which do not depend on x (s₀ = 0).
 
     ``res_ref`` is the optional residual spill of the training forward (None
     for the inference kernel): residual saving must never fork the
     recurrence itself.
     """
-    x = _rows_to_tile(x_ref[...])
-    groups = _row_groups(x.shape[0], lay)
-    ps = []
-    for rows in groups:
-        h0 = x[rows] @ w_ref[0]
-        p = [h0 + b_ref[0, 0][None, :]]
-        p += [jnp.broadcast_to(b_ref[0, i][None, :], h0.shape)
-              for i in range(1, lay.n_tiles)]
-        ps.append(p)
+    d, seg = lay.d_in, lay.seg
+    x = x_ref[...]
+    p = _affine(lay, [w0_ref.at[i] for i in range(lay.n_tiles)],
+                [x] * lay.n_tiles)
     for l in range(n_layers):
+        if res_ref is not None:
+            for k, v in enumerate(p):
+                res_ref[l, k * seg:(k + 1) * seg, :] = v
         a = a_ref[l:l + 1, :]
-        w = w_ref[l + 1]
-        for r, rows in enumerate(groups):
-            p = ps[r]
-            if res_ref is not None:
-                for i in range(lay.n_tiles):
-                    res_ref[l, i, rows, :] = p[i]
-            g, p1, p2 = _act_derivs(act, a * _spread_h(lay, p), 2)
-            d1 = p1 * a
-            d2 = p2 * (a * a)
-            t = _shift(lay, p, lay.d_in)   # t_j aligned with s_j
-            q = [_by_kind(lay, i, lambda: g, lambda i=i: d1 * p[i],
-                          lambda i=i: d2 * t[i] * t[i] + d1 * p[i])
-                 for i in range(lay.n_tiles)]
-            p = [qi @ w for qi in q]
-            p[0] = p[0] + b_ref[l + 1, 0][None, :]
-            ps[r] = p
-    cat = (lambda vs: vs[0]) if len(ps) == 1 else (
-        lambda vs: jnp.concatenate(vs, axis=0))
-    _store_outputs(lay, [cat([p[i] for p in ps]) for i in range(lay.n_tiles)],
-                   out_ref)
+        g, p1, p2 = _act_derivs(act, a * p[0], 2)
+        d1 = p1 * a
+        d2 = p2 * (a * a)
+        t, s = p[1:1 + d], p[1 + d:]
+        q = ([g] + [d1 * tj for tj in t]
+             + [d2 * tj * tj + d1 * sj for tj, sj in zip(t, s)])
+        p = _affine(lay, [w_ref.at[l]] * lay.n_tiles, _tiles(lay, q))
+        p[0] = p[0] + b_ref[l]
+    _store_outputs(lay, p, out_ref)
 
 
-def _kernel2(x_ref, w_ref, b_ref, a_ref, out_ref, *, n_layers, lay, act):
+def _kernel2(x_ref, w0_ref, w_ref, b_ref, a_ref, out_ref, *, n_layers, lay,
+             act):
     """Second-order variant: one block of collocation points.
 
-    x_ref:   (x_rows, block_n)             coordinates, one per row
-    w_ref:   (n_layers+1, WPAD, WPAD)      W₀ padded, then blockdiag(W_l)
-    b_ref:   (n_layers+1, n_tiles, WPAD)   biases in stream 0; row 0 also
-                                           holds t₀,j = W₀[j] in stream j+1
-    a_ref:   (n_layers+1, WPAD)            adaptive slopes over lanes
-    out_ref: (out_rows, block_n)           (u, du_j, d²u/dx_j²) rows
-                                           (``Layout.out_row``)
+    x_ref:   (x_rows, block_n)            coordinates, one per row, then ones
+    w0_ref:  (n_tiles, WPAD, x_rows)      first layer per tile: W₀ᵀ in h's
+                                          rows; in the ones column h's bias
+                                          and t₀,j = W₀[j] in t_j's rows
+    w_ref:   (n_layers, WPAD, WPAD)       blockdiag(W_lᵀ) of layers 1..L
+    b_ref:   (n_layers, seg, block_n)     biases of layers 1..L over lanes
+    a_ref:   (n_layers, block_n)          adaptive slopes over lanes
+    out_ref: (out_rows, block_n)          (u, du_j, d²u/dx_j²) rows
+                                          (``Layout.out_row``)
     """
-    _kernel2_run(x_ref, w_ref, b_ref, a_ref, out_ref, None,
+    _kernel2_run(x_ref, w0_ref, w_ref, b_ref, a_ref, out_ref, None,
                  n_layers=n_layers, lay=lay, act=act)
 
 
-def _kernel2_res(x_ref, w_ref, b_ref, a_ref, out_ref, res_ref, *, n_layers,
-                 lay, act):
+def _kernel2_res(x_ref, w0_ref, w_ref, b_ref, a_ref, out_ref, res_ref, *,
+                 n_layers, lay, act):
     """:func:`_kernel2` that ALSO spills the reverse sweep's residuals.
 
-    res_ref: (n_layers, n_tiles, block_n, WPAD)  the streams (h, t, s)
-             ENTERING each activation stage
+    res_ref: (n_layers, res_rows, block_n)  the streams (h, t, s) ENTERING
+             each activation stage, stacked (stream k at rows k·seg)
 
     — exactly what :func:`_kernel2_bwd` re-derives the activation factors from
     (phi^(k)(a·h) are recomputed from h; no matmul is ever recomputed).
     """
-    _kernel2_run(x_ref, w_ref, b_ref, a_ref, out_ref, res_ref,
+    _kernel2_run(x_ref, w0_ref, w_ref, b_ref, a_ref, out_ref, res_ref,
                  n_layers=n_layers, lay=lay, act=act)
 
 
-def _kernel2_bwd(x_ref, w_ref, a_ref, res_ref, ct_ref,
-                 cx_ref, cw_ref, cb_ref, ca_ref, *, n_layers, lay, act):
+def _kernel2_bwd(x_ref, w0t_ref, w_ref, a_ref, res_ref, ct_ref,
+                 cx_ref, cw0_ref, cw_ref, cb_ref, ca_ref, *, n_layers, lay,
+                 act):
     """Hand-derived fused reverse sweep of :func:`_kernel2` (one VMEM pass).
 
     One block of collocation points walks the layer stack BACKWARD carrying
@@ -417,92 +336,93 @@ def _kernel2_bwd(x_ref, w_ref, a_ref, res_ref, ct_ref,
     the saved streams reproduce the activation factors p_k = phi^(k)(a·h)
     and the cotangent rules are the paper-derivation transposes of the
     forward tangent rules (see ``ref._ref2_bwd`` — the jnp twin of this
-    kernel — for the formulas).  h̄ gathers a term from every stream
-    (:func:`_sum_streams`), t̄_j one from s̄_j (:func:`_shift`).
+    kernel — for the formulas).  h̄ gathers a term from every stream and
+    t̄_j one from s̄_j, row block onto row block.
 
     Weight / bias / slope cotangents accumulate ACROSS grid blocks: every grid
-    step maps cw/cb/ca to the same block (TPU grid iteration is sequential),
-    zero-initialized at step 0.
+    step maps cw0/cw/cb/ca to the same block (TPU grid iteration is
+    sequential), zero-initialized at step 0.
 
-    x_ref:  (x_rows, block_n)            coordinates, one per row
-    ct_ref: (out_rows, block_n)          (ū, d̄u_j, d̄2u_j) rows (pruned rows
+    x_ref:   (x_rows, block_n)           coordinates and the ones row
+    w0t_ref: (x_rows, WPAD)              W₀ (row j: W₀[j] over h's rows)
+    w_ref:   (n_layers, WPAD, WPAD)      blockdiag(W_l) of layers 1..L
+    ct_ref:  (out_rows, block_n)         (ū, d̄u_j, d̄2u_j) rows (pruned rows
                                          pre-zeroed by the caller)
-    cx_ref: (x_rows, block_n)            x̄ rows out
-    cw_ref: (n_layers+1, WPAD, WPAD)     accumulated Σ_tiles Aᵀ@B̄: W̄_l is the
-                                         sum of its stream-diagonal blocks
-    cb_ref: (n_layers+1, n_tiles, WPAD)  accumulated row sums of B̄: b̄_l in
-                                         stream 0; row 0 also Σ t̄₀,j (W̄₀[j])
-    ca_ref: (n_layers+1, WPAD)           ā lane-partials (reduce lanes
-                                         outside; row n_layers unused)
+    cx_ref:  (x_rows, block_n)           x̄ rows out (the ones row's is junk)
+    cw0_ref: (n_tiles, WPAD, x_rows)     accumulated B̄₀ @ xᵀ per tile: W̄₀ᵀ in
+                                         h's rows; the ones column holds each
+                                         row's Σ over points (b̄₀, Σ t̄₀,j)
+    cw_ref:  (n_layers, WPAD, WPAD)      accumulated Σ_tiles Q @ B̄ᵀ: W̄_l is
+                                         the sum of its stream-diagonal blocks
+                                         (rows past tile 0's streams stay 0)
+    cb_ref:  (n_layers, seg, m)          b̄_l lane-partials (reduce outside;
+                                         m = WPAD, or block_n if smaller)
+    ca_ref:  (n_layers, seg, m)          ā lane- and row-partials
     """
-    n = lay.n_tiles
-    i = pl.program_id(0)
+    d, seg = lay.d_in, lay.seg
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        cw_ref[...] = jnp.zeros(cw_ref.shape, cw_ref.dtype)
-        cb_ref[...] = jnp.zeros(cb_ref.shape, cb_ref.dtype)
-        ca_ref[...] = jnp.zeros(ca_ref.shape, ca_ref.dtype)
+        for ref in (cw0_ref, cw_ref, cb_ref, ca_ref):
+            ref[...] = jnp.zeros(ref.shape, ref.dtype)
 
     bar = _load_outputs(lay, ct_ref)
     for l in reversed(range(n_layers)):
         a = a_ref[l:l + 1, :]
-        wt = w_ref[l + 1].T
-        p = [res_ref[l, k] for k in range(n)]
-        hs = _spread_h(lay, p)
-        t = _shift(lay, p, lay.d_in)   # t_j aligned with s_j
-        g, p1, p2, p3 = _act_derivs(act, a * hs, 3)
+        p = [res_ref[l, k * seg:(k + 1) * seg, :]
+             for k in range(lay.n_streams)]
+        h, t, s = p[0], p[1:1 + d], p[1 + d:]
+        g, p1, p2, p3 = _act_derivs(act, a * h, 3)
         d1 = p1 * a
         d2v = p2 * (a * a)
-        q = [_by_kind(lay, k, lambda: g, lambda k=k: d1 * p[k],
-                      lambda k=k: d2v * t[k] * t[k] + d1 * p[k])
-             for k in range(n)]
-        # ---- affine layer l+1: pull the cotangents through Wᵀ ------------
-        qb = [bk @ wt for bk in bar]
+        tt = [tj * tj for tj in t]
+        q = ([g] + [d1 * tj for tj in t]
+             + [d2v * ttj + d1 * sj for ttj, sj in zip(tt, s)])
+        # ---- affine layer l+1: the cotangents through Wᵀ -----------------
+        b_tiles = _tiles(lay, bar)
+        qb = _affine(lay, [w_ref.at[l]] * lay.n_tiles, b_tiles)
+        gb, tb, sb = qb[0], qb[1:1 + d], qb[1 + d:]
         # ---- activation stage l: ā partial, then (h̄, t̄, s̄) --------------
-        e1 = p2 * hs * a + p1                    # ∂(phi'·a)/∂a
-        e2 = p3 * hs * (a * a) + 2.0 * p2 * a    # ∂(phi''·a²)/∂a
-        ca = [_by_kind(lay, k, lambda k=k: qb[k] * (p1 * hs),
-                       lambda k=k: qb[k] * p[k] * e1,
-                       lambda k=k: qb[k] * (t[k] * t[k] * e2 + p[k] * e1))
-              for k in range(n)]
-        ca_ref[l] += jnp.sum(sum(ca[1:], ca[0]), axis=0)
+        e1 = p2 * h * a + p1                    # ∂(phi'·a)/∂a
+        e2 = p3 * h * (a * a) + 2.0 * p2 * a    # ∂(phi''·a²)/∂a
         p3a3 = p3 * (a * a * a)
-        c = [_by_kind(lay, k, lambda k=k: qb[k] * d1,
-                      lambda k=k: qb[k] * p[k] * d2v,
-                      lambda k=k: qb[k] * (t[k] * t[k] * p3a3 + p[k] * d2v))
-             for k in range(n)]
-        bar_h = _sum_streams(lay, c)
-        v = [qb[k] * (2.0 * d2v) * t[k] if lay.packed or k > lay.d_in
-             else None for k in range(n)]
-        v = _shift(lay, v, -lay.d_in)  # s̄_j's term in t_j's lanes
+        ca, bar_h = gb * (p1 * h), gb * d1
+        for tj, ttj, sj, tbj, sbj in zip(t, tt, s, tb, sb):
+            ca = ca + tbj * tj * e1 + sbj * (ttj * e2 + sj * e1)
+            bar_h = bar_h + tbj * tj * d2v + sbj * (ttj * p3a3 + sj * d2v)
+        ca_ref[l] += _lane_sum(ca, ca_ref.shape[-1])
         # ---- affine layer l+1: W̄ and b̄ (after the activation stage, so
-        # the MXU overlaps its work) -------------------------------------
-        cw = q[0].T @ bar[0]
-        for k in range(1, n):
-            cw += q[k].T @ bar[k]
-        cw_ref[l + 1] += cw
-        cb_ref[l + 1, 0] += jnp.sum(bar[0], axis=0)
-        bar = [_by_kind(lay, k, lambda: bar_h,
-                        lambda k=k: qb[k] * d1 + v[k],
-                        lambda k=k: qb[k] * d1)
-               for k in range(n)]
-    # ---- input affine layer: x̄ and W̄₀ from h̄; t₀,j entered as bias ------
-    cx_ref[...] = (bar[0] @ w_ref[0].T).T[:lay.x_rows]
-    cw_ref[0] += _pad_rows(x_ref[...]) @ bar[0]        # xᵀ @ h̄
-    for k in range(n):
-        cb_ref[0, k] += jnp.sum(bar[k], axis=0)
+        # the MXU overlaps its work); Q's zero rows past tile 0's streams
+        # add nothing to W̄ ----------------------------------------------
+        r0 = lay.tile_rows(0)
+        cw = None
+        for qi, bi in zip(_tiles(lay, q), b_tiles):
+            c = _dot_nt(qi[:r0], bi)
+            cw = c if cw is None else cw + c
+        cw_ref[l, :r0, :] += cw
+        cb_ref[l] += _lane_sum(bar[0], cb_ref.shape[-1])
+        bar = ([bar_h] + [tbj * d1 + sbj * (2.0 * d2v) * tj
+                          for tj, tbj, sbj in zip(t, tb, sb)]
+               + [sbj * d1 for sbj in sb])
+    # ---- input affine layer: x̄ from h̄; W̄₀, b̄₀ and Σ t̄₀,j against x's rows
+    b_tiles = _tiles(lay, bar)
+    x = x_ref[...]
+    cx_ref[...] = w0t_ref[...] @ b_tiles[0]
+    for k, bi in enumerate(b_tiles):
+        cw0_ref[k] += _dot_nt(bi, x)
 
 
-def _lane_slopes(a_vec):
-    """(L+1,) slopes -> (L+1, WPAD) lane-broadcast tile.  A 1-D slope operand
-    stops compiling once the launch is vmapped over subdomains: its batched
-    block (1, L+1) breaks Mosaic's rule that the last two block dims be
-    (8, 128)-divisible or span the array."""
-    return jnp.broadcast_to(a_vec[:, None], (a_vec.shape[0], WPAD))
+def _lanes(v, n):
+    """Broadcast ``v`` over a new last axis of ``n`` lanes.  Slopes and
+    biases enter the kernels this way, not as 1-D operands: a batched 1-D
+    block (1, L) breaks Mosaic's rule that the last two block dims be
+    (8, 128)-divisible or span the array once the launch is vmapped over
+    subdomains, and a (1, 1) value cannot be broadcast in both sublanes
+    and lanes inside a kernel."""
+    return jnp.broadcast_to(v[..., None], v.shape + (n,))
 
 
-def _launch(phase, kernel, args, layout="per_stream", **spec):
+def _launch(phase, kernel, args, layout, **spec):
     """Call ``pl.pallas_call(kernel, **spec)`` on ``args`` under the launch's
     fixed name (``SCOPES[phase]``), given both as the call's ``name`` and as
     a named scope: the compiled custom call is then ``%<name>.N`` whatever
@@ -524,7 +444,7 @@ def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
     kernel = functools.partial(_kernel, n_layers=n_layers, d_in=d_in, act=act)
     return _launch(
         "kernel_eval1", kernel,
-        (x_pad, w_stack, b_stack, _lane_slopes(a_vec)),
+        (x_pad, w_stack, b_stack, _lanes(a_vec, WPAD)), layout="first_order",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, WPAD), lambda i: (i, 0)),
@@ -544,114 +464,110 @@ def pinn_mlp_pallas(x_pad, w_stack, b_stack, a_vec, *, d_in, act="tanh",
     )
 
 
-def _specs2(lay, n_layers, block_n):
-    """Block specs of the second-order launches' shared operands: x rows,
-    the weight stack, the bias stack, the slopes."""
-    return [
-        pl.BlockSpec((lay.x_rows, block_n), lambda i: (0, i)),
-        pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
-        pl.BlockSpec((n_layers + 1, lay.n_tiles, WPAD), lambda i: (0, 0, 0)),
-        pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
-    ]
-
-
 def _rows_spec(rows, block_n):
     return pl.BlockSpec((rows, block_n), lambda i: (0, i))
 
 
-def pinn_mlp_pallas2(x_rows, w_stack, b_stack, a_vec, *, lay, act="tanh",
-                     block_n=256, interpret=False):
-    """Second-order launch over ``ops.pack_mlp2``'s stacks and x as
-    (x_rows, N) rows: returns the output streams (u, du_j, d²u/dx_j²,
-    diagonal only) as (out_rows, N) rows (``Layout.out_row``)."""
+def _whole(shape):
+    """A block that is the whole operand, the same at every grid step."""
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+
+def _fwd_operands(lay, x_rows, w0, w_stack, b_stack, a_vec, block_n):
+    """The second-order forward launches' operands and their block specs."""
+    n_layers = w_stack.shape[0]
     n = x_rows.shape[1]
     assert x_rows.shape[0] == lay.x_rows and n % block_n == 0
-    n_layers = w_stack.shape[0] - 1
+    args = (x_rows, w0, w_stack, _lanes(b_stack, block_n),
+            _lanes(a_vec, block_n))
+    specs = [_rows_spec(lay.x_rows, block_n), _whole(w0.shape),
+             _whole(w_stack.shape), _whole((n_layers, lay.seg, block_n)),
+             _whole((n_layers, block_n))]
+    return n_layers, n, args, specs
+
+
+def pinn_mlp_pallas2(x_rows, w0, w_stack, b_stack, a_vec, *, lay, act="tanh",
+                     block_n=256, interpret=False):
+    """Second-order launch over ``ops.pack_mlp2``'s operands and x as
+    (x_rows, N) rows: returns the output streams (u, du_j, d²u/dx_j²,
+    diagonal only) as (out_rows, N) rows (``Layout.out_row``)."""
+    n_layers, n, args, specs = _fwd_operands(lay, x_rows, w0, w_stack,
+                                             b_stack, a_vec, block_n)
     kernel = functools.partial(_kernel2, n_layers=n_layers, lay=lay, act=act)
     return _launch(
-        "kernel_eval2", kernel,
-        (x_rows, w_stack, b_stack, _lane_slopes(a_vec)), layout=lay.name,
-        grid=(n // block_n,),
-        in_specs=_specs2(lay, n_layers, block_n),
+        "kernel_eval2", kernel, args, layout=lay.name,
+        grid=(n // block_n,), in_specs=specs,
         out_specs=_rows_spec(lay.out_rows, block_n),
         out_shape=jax.ShapeDtypeStruct((lay.out_rows, n), x_rows.dtype),
         interpret=interpret,
     )
 
 
-def pinn_mlp_pallas2_res(x_rows, w_stack, b_stack, a_vec, *, lay,
+def pinn_mlp_pallas2_res(x_rows, w0, w_stack, b_stack, a_vec, *, lay,
                          act="tanh", block_n=256, interpret=False):
     """Training-forward launch: :func:`pinn_mlp_pallas2`'s output PLUS the
-    reverse sweep's residual stack (L, n_tiles, N, WPAD)."""
-    n = x_rows.shape[1]
-    assert x_rows.shape[0] == lay.x_rows and n % block_n == 0
-    n_layers = w_stack.shape[0] - 1
+    reverse sweep's residual stack (L, res_rows, N)."""
+    n_layers, n, args, specs = _fwd_operands(lay, x_rows, w0, w_stack,
+                                             b_stack, a_vec, block_n)
     assert n_layers >= 1, "residual-saving kernel needs >= 1 hidden layer"
     kernel = functools.partial(_kernel2_res, n_layers=n_layers, lay=lay,
                                act=act)
     dt = x_rows.dtype
     return _launch(
-        "kernel_res", kernel,
-        (x_rows, w_stack, b_stack, _lane_slopes(a_vec)), layout=lay.name,
-        grid=(n // block_n,),
-        in_specs=_specs2(lay, n_layers, block_n),
+        "kernel_res", kernel, args, layout=lay.name,
+        grid=(n // block_n,), in_specs=specs,
         out_specs=[
             _rows_spec(lay.out_rows, block_n),
-            pl.BlockSpec((n_layers, lay.n_tiles, block_n, WPAD),
-                         lambda i: (0, 0, i, 0)),
+            pl.BlockSpec((n_layers, lay.res_rows, block_n),
+                         lambda i: (0, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((lay.out_rows, n), dt),
-            jax.ShapeDtypeStruct((n_layers, lay.n_tiles, n, WPAD), dt),
+            jax.ShapeDtypeStruct((n_layers, lay.res_rows, n), dt),
         ],
         interpret=interpret,
     )
 
 
-def pinn_mlp_pallas2_bwd(x_rows, w_stack, a_vec, res, ct, *, lay,
+def pinn_mlp_pallas2_bwd(x_rows, w0t, w_stack, a_vec, res, ct, *, lay,
                          act="tanh", block_n=256, interpret=False):
     """Fused reverse-sweep launch (:func:`_kernel2_bwd`).
 
     Grid over point blocks; x̄ streams out per block while the parameter
-    cotangents (W̄ stack, b̄ stack, ā lane-partials) accumulate in one
-    revisited VMEM block across the sequential grid.  ``res`` is
+    cotangents (W̄₀ per tile, W̄ stack, b̄ and ā partials) accumulate in one
+    revisited VMEM block across the sequential grid.  ``w0t`` is W₀ padded
+    to (x_rows, WPAD) and ``w_stack`` holds blockdiag(W_l) (the forward's
+    weights in their other orientation); ``res`` is
     :func:`pinn_mlp_pallas2_res`'s residual stack and ``ct`` the output
-    streams' cotangents as (out_rows, N) rows.  Returns
-    (cx (x_rows, N), cw (L+1, WPAD, WPAD), cb (L+1, n_tiles, WPAD),
-    ca_part (L+1, WPAD) — sum the lane axis for ā); ``ops`` folds cw's
-    stream blocks and cb's streams into W̄ and b̄.
+    streams' cotangents as (out_rows, N) rows.  Returns (cx (x_rows, N),
+    cw0 (n_tiles, WPAD, x_rows), cw (L, WPAD, WPAD), cb (L, seg, m),
+    ca (L, seg, m)) with m = WPAD lanes of partials (block_n if smaller);
+    ``ops`` folds them into (W̄s, b̄s, ā).
     """
     n = x_rows.shape[1]
     assert x_rows.shape[0] == lay.x_rows and n % block_n == 0
-    n_layers = w_stack.shape[0] - 1
+    n_layers = w_stack.shape[0]
     assert n_layers >= 1
     kernel = functools.partial(_kernel2_bwd, n_layers=n_layers, lay=lay,
                                act=act)
     dt = x_rows.dtype
-    nt = lay.n_tiles
+    m = WPAD if block_n % WPAD == 0 else block_n   # partials' lanes
+    shapes = [(lay.n_tiles, WPAD, lay.x_rows), (n_layers, WPAD, WPAD),
+              (n_layers, lay.seg, m), (n_layers, lay.seg, m)]
     return _launch(
         "bwd_fused", kernel,
-        (x_rows, w_stack, _lane_slopes(a_vec), res, ct), layout=lay.name,
-        grid=(n // block_n,),
+        (x_rows, w0t, w_stack, _lanes(a_vec, block_n), res, ct),
+        layout=lay.name, grid=(n // block_n,),
         in_specs=[
-            _rows_spec(lay.x_rows, block_n),
-            pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
-            pl.BlockSpec((n_layers, nt, block_n, WPAD),
-                         lambda i: (0, 0, i, 0)),
+            _rows_spec(lay.x_rows, block_n), _whole(w0t.shape),
+            _whole(w_stack.shape), _whole((n_layers, block_n)),
+            pl.BlockSpec((n_layers, lay.res_rows, block_n),
+                         lambda i: (0, 0, i)),
             _rows_spec(lay.out_rows, block_n),
         ],
-        out_specs=[
-            _rows_spec(lay.x_rows, block_n),
-            pl.BlockSpec((n_layers + 1, WPAD, WPAD), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_layers + 1, nt, WPAD), lambda i: (0, 0, 0)),
-            pl.BlockSpec((n_layers + 1, WPAD), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((lay.x_rows, n), dt),
-            jax.ShapeDtypeStruct((n_layers + 1, WPAD, WPAD), dt),
-            jax.ShapeDtypeStruct((n_layers + 1, nt, WPAD), dt),
-            jax.ShapeDtypeStruct((n_layers + 1, WPAD), dt),
-        ],
+        out_specs=[_rows_spec(lay.x_rows, block_n)]
+        + [_whole(s) for s in shapes],
+        out_shape=[jax.ShapeDtypeStruct((lay.x_rows, n), dt)]
+        + [jax.ShapeDtypeStruct(s, dt) for s in shapes],
         interpret=interpret,
     )

@@ -156,20 +156,21 @@ _launches: dict[tuple[str, str], int] = defaultdict(int)
 
 def count_launch(phase: str, layout: str) -> None:
     """Count one trace of a Pallas PINN launch (``phase`` from ``SCOPES``)
-    under its stream layout: ``"packed"`` (every stream in one 128-lane
-    tile) or ``"per_stream"`` (one stream per tile).  Trace time only, so a
-    step that runs a compiled program costs nothing."""
+    under its stream layout: ``"<n>_per_tile"`` for the second-order
+    kernels (n streams stacked on the sublanes of each tile,
+    ``kernels.pinn_mlp.Layout``) or ``"first_order"``.  Trace time only, so
+    a step that runs a compiled program costs nothing."""
     _launches[(phase, layout)] += 1
 
 
 def launch_counts() -> dict:
     """Process-lifetime traced Pallas PINN launches by layout, and by
     ``phase/layout`` (monotone, like :func:`compile_counts`)."""
-    out = {"packed": 0, "per_stream": 0}
+    out: dict[str, int] = defaultdict(int)
     for (phase, layout), n in sorted(_launches.items()):
         out[layout] += n
         out[f"{phase}/{layout}"] = n
-    return out
+    return dict(out)
 
 
 _collectives: dict[str, list[int]] = defaultdict(lambda: [0, 0])

@@ -376,11 +376,12 @@ def test_bwd_parity_sweep(act, d_in, width, depth, out, d2_dirs):
 
 @pytest.mark.parametrize("width,packed", [(20, True), (80, False)])
 def test_stream_layout_of_saved_residuals(width, packed):
-    """The training forward saves ONE residual stack.  The Burgers net (width
-    20, d_in 2: 5 x 20 lanes) packs its five streams into one tile per
-    layer, a fifth of the one-stream-per-tile bytes; the heat net's width
-    80 keeps one stream per tile.  The launch counter names the layout the
-    traced launches took."""
+    """The training forward saves ONE residual stack: per layer the five
+    streams stacked on the rows, ``seg`` rows each, with the points on the
+    lanes.  The Burgers net (width 20, d_in 2) stacks its five 24-row
+    streams in one tile, 120 of the 128 rows a tile per stream would store;
+    the heat net's width 80 takes a tile per stream and stores 80 rows of
+    each.  The launch counter names the layout the traced launches took."""
     from repro.obs import launch_counts
 
     L, d_in, n, block_n = 5, 2, 300, 256
@@ -394,19 +395,80 @@ def test_stream_layout_of_saved_residuals(width, packed):
     after = launch_counts()
     (res,) = saved[3:]
     n_pad, streams = 512, 1 + 2 * d_in
-    per_stream_bytes = L * streams * n_pad * ops.WPAD * 4
-    got_bytes = res.size * res.dtype.itemsize
-    kind = "packed" if packed else "per_stream"
-    if packed:
-        assert res.shape == (L, 1, n_pad, ops.WPAD)
-        assert got_bytes <= per_stream_bytes // 4
-    else:
-        assert res.shape == (L, streams, n_pad, ops.WPAD)
-        assert got_bytes == per_stream_bytes
+    seg, kind = (24, "5_per_tile") if packed else (80, "1_per_tile")
+    assert res.shape == (L, streams * seg, n_pad)
     assert after[f"kernel_res/{kind}"] == before.get(f"kernel_res/{kind}",
                                                      0) + 1
-    other = "per_stream" if packed else "packed"
-    assert after[other] == before[other]
+    assert {k for k in after if "/" not in k and after[k] != before.get(k)
+            } == {kind}
+
+
+@pytest.mark.parametrize("d_in,width,seg,per_tile,n_tiles", [
+    (2, 20, 24, 5, 1),    # the Burgers cells' net: five streams, one tile
+    (2, 25, 32, 4, 2),    # 32-row streams: four fit a tile
+    (3, 18, 24, 5, 2),    # seven streams of 24 rows
+    (2, 80, 80, 1, 5),    # the heat map's width: a tile per stream
+])
+def test_layout_rule(d_in, width, seg, per_tile, n_tiles):
+    """Each stream takes the widest layer rounded up to 8 rows, a tile
+    stacks as many as fit in 128 rows, and every stream starts on an 8-row
+    boundary inside its tile."""
+    from repro.kernels.pinn_mlp import layout
+
+    lay = layout(d_in, width, 1)
+    assert (lay.seg, lay.per_tile, lay.n_tiles) == (seg, per_tile, n_tiles)
+    assert lay.name == f"{per_tile}_per_tile"
+    rows = [lay.row(k) for k in range(lay.n_streams)]
+    assert all(r % 8 == 0 and r % ops.WPAD + seg <= ops.WPAD for r in rows)
+    assert len(set(r // seg for r in rows)) == lay.n_streams
+
+
+def _eqns(jaxpr, in_kernel=False):
+    """The equations of ``jaxpr`` and every jaxpr nested in it, each with
+    whether it lies inside a Pallas kernel body."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        yield eqn, in_kernel
+        inside = in_kernel or eqn.primitive.name == "pallas_call"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else [v]):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    yield from _eqns(sub, inside)
+
+
+def test_second_order_kernels_move_no_point_tile_across_lanes():
+    """At the Burgers shape the training forward and the reverse kernels
+    carry the streams on the sublanes: their bodies hold no lane roll and
+    no transpose of a value with a ``block_n`` axis (a 128 x 128 weight
+    tile may be transposed)."""
+    from repro.kernels.pinn_mlp import (layout, pinn_mlp_pallas2_bwd,
+                                        pinn_mlp_pallas2_res)
+
+    L, block_n, n = 5, 256, 1024
+    lay = layout(2, 20, 1)
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    kw = dict(lay=lay, act="tanh", block_n=block_n, interpret=False)
+    w, a = sds(L, ops.WPAD, ops.WPAD), sds(L)
+    x = sds(lay.x_rows, n)
+    launches = [
+        jax.make_jaxpr(lambda *r: pinn_mlp_pallas2_res(*r, **kw))(
+            x, sds(lay.n_tiles, ops.WPAD, lay.x_rows), w, sds(L, lay.seg), a),
+        jax.make_jaxpr(lambda *r: pinn_mlp_pallas2_bwd(*r, **kw))(
+            x, sds(lay.x_rows, ops.WPAD), w, a, sds(L, lay.res_rows, n),
+            sds(lay.out_rows, n)),
+    ]
+    for closed in launches:
+        eqns = [e for e, inside in _eqns(closed.jaxpr) if inside]
+        names = {e.primitive.name for e in eqns}
+        assert "dot_general" in names, names      # a kernel body was walked
+        assert not {nm for nm in names if "roll" in nm}, names
+        moved = [e.invars[0].aval.shape for e in eqns
+                 if e.primitive.name == "transpose"
+                 and block_n in e.invars[0].aval.shape]
+        assert not moved, moved
 
 
 def test_bwd_selector_roundtrip():

@@ -230,7 +230,9 @@ def test_engine_hlo_packs_weights_once():
         [-1, 0], [1, 1], size=(40, 2)), bucket=32)
     fn = eng._get_fn(order=2)
     txt = fn.lower(*eng._device_args(routed)).compile().as_text()
-    n_layer_mats = 3  # depth-2 MLP: 2 hidden + 1 output weight matrix
+    # depth-2 MLP: the hidden and the output weight matrix; the first
+    # layer packs against x's rows, not into the (L, 128, 128) stack
+    n_layer_mats = 2
     pads = sum(1 for ln in txt.splitlines()
                if " pad(" in ln and "f32[4,128,128]" in ln)
     assert pads == n_layer_mats, f"expected {n_layer_mats} weight packs, got {pads}"

@@ -163,7 +163,9 @@ def test_guarded_chunk_adds_no_network_entries_or_weight_packs():
         state, b, 3, ones).compile().as_text()
     unguarded = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
         state, b, 3).compile().as_text()
-    assert weight_pads(guarded) == weight_pads(unguarded) == 3
+    # one (L, 128, 128) stack per loss eval: the depth-2 net's hidden and
+    # output matrices (the first layer packs against x's rows)
+    assert weight_pads(guarded) == weight_pads(unguarded) == 2
 
 
 # ---------------------------------------------------------------- supervisor

@@ -4,7 +4,7 @@ Compiles against a described (not attached) ``v5e:2x2`` topology, so the
 chip's own compiler refuses here what interpret mode cannot see: block
 tiling rules, VMEM limits, device memory.  Shapes are the chip smoke's
 (``chip_smoke.py``): 5 hidden layers of width 20 (the five tangent
-streams packed into one 128-lane tile), ``d_in = 2``, ``block_n = 256``
+streams stacked on the sublanes of one tile), ``d_in = 2``, ``block_n = 256``
 and 20,224 rows (20,000 residual, 40 interface and 80 boundary points of
 one subdomain, padded to the block).  The four-chip cPINN strip's guarded
 chunk compiles over all four described chips at the benchmark cell's size.
@@ -68,19 +68,19 @@ def _compile(fn, args, precision):
 
 def _kernel_case(name, sds, width):
     """A kernel's launch and operand shapes for a net of ``width``: the
-    chip smoke's 20 packs all five streams into one tile, the heat map's
-    80 keeps one stream per tile."""
+    chip smoke's 20 stacks all five streams in one tile, the heat map's
+    80 takes a tile per stream."""
     lay = layout(D_IN, width, 1)
     x = sds(lay.x_rows, ROWS)
-    w, b, a = (sds(L + 1, WPAD, WPAD), sds(L + 1, lay.n_tiles, WPAD),
-               sds(L + 1))
+    w, a = sds(L, WPAD, WPAD), sds(L)
     kw = dict(lay=lay, act="tanh", block_n=BLOCK_N, interpret=False)
+    fwd = (x, sds(lay.n_tiles, WPAD, lay.x_rows), w, sds(L, lay.seg), a)
     if name == "_kernel2":
-        return lambda *r: pinn_mlp_pallas2(*r, **kw), (x, w, b, a)
+        return lambda *r: pinn_mlp_pallas2(*r, **kw), fwd
     if name == "_kernel2_res":
-        return lambda *r: pinn_mlp_pallas2_res(*r, **kw), (x, w, b, a)
+        return lambda *r: pinn_mlp_pallas2_res(*r, **kw), fwd
     return (lambda *r: pinn_mlp_pallas2_bwd(*r, **kw),
-            (x, w, a, sds(L, lay.n_tiles, ROWS, WPAD),
+            (x, sds(lay.x_rows, WPAD), w, a, sds(L, lay.res_rows, ROWS),
              sds(lay.out_rows, ROWS)))
 
 
@@ -101,7 +101,7 @@ _PHASES = {"_kernel2": "kernel_eval2", "_kernel2_res": "kernel_res",
                                     "_kernel2_bwd-w80"])
 def test_kernel_compiles_for_v5e(one_chip, kernel, precision):
     """Each second-order kernel compiles under its fixed name: at the
-    smoke's width 20 with the streams packed into one tile, and (``-w80``)
+    smoke's width 20 with the streams stacked in one tile, and (``-w80``)
     at width 80 with one stream per tile."""
     sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
     name, _, width = kernel.partition("-w")
@@ -119,12 +119,19 @@ def test_vmapped_training_grad_compiles_for_v5e(one_chip, precision):
     before = launch_counts()
     hlo = _compile(_training_grad(), _mlp_shapes(sds), precision)
     after = launch_counts()
-    # width 20: both kernels take the packed layout, none one stream per tile
-    assert after["packed"] - before["packed"] >= 2
-    assert after["per_stream"] == before["per_stream"]
+    # width 20: both kernels stack all five streams in one tile
+    _assert_launch_layouts(before, after, "5_per_tile")
     assert hlo.count("tpu_custom_call") >= 2   # _kernel2_res + _kernel2_bwd
     assert "pinn2-bwd-fused" in hlo and "pinn2-bwd-ref" not in hlo
     _assert_kernel_names(hlo, ("kernel_res", "bwd_fused"))
+
+
+def _assert_launch_layouts(before, after, name):
+    """At least the two training launches were traced between the two
+    ``launch_counts()`` readings, all of them in layout ``name``."""
+    grew = {k: n - before.get(k, 0) for k, n in after.items()
+            if "/" not in k and n != before.get(k, 0)}
+    assert set(grew) == {name} and grew[name] >= 2, grew
 
 
 def _training_grad():
@@ -200,9 +207,9 @@ def test_kernel_names_are_stable_for_v5e(one_chip):
 def test_four_chip_cpinn_chunk_compiles_for_v5e(v5e_2x2):
     """The benchmark cell ``burgers_cpinn_4x1.train_4chip``: the sharded
     trainer's guarded 100-step chunk, one subdomain per described chip,
-    runs the packed fused kernels, and each scan step issues the halo's 4
-    collective-permutes (2 edge colours x ``u`` and the flux, 80 B each)
-    and the guard's one all-reduce.  ``interpret=False`` is set on the
+    runs the fused kernels on one tile of streams, and each scan step
+    issues the halo's 4 collective-permutes (2 edge colours x ``u`` and the
+    flux, 80 B each) and the guard's one all-reduce.  ``interpret=False`` is set on the
     trainer's residual path because ``ops._on_tpu()`` sees the CPU here."""
     import dataclasses
 
@@ -236,8 +243,7 @@ def test_four_chip_cpinn_chunk_compiles_for_v5e(v5e_2x2):
     hlo = _compile(tr._build_guarded_chunk(100), args,
                    cfg["matmul_precision"])
     after = launch_counts()
-    assert after["packed"] - before["packed"] >= 2
-    assert after["per_stream"] == before["per_stream"]
+    _assert_launch_layouts(before, after, "5_per_tile")
     assert "tpu_custom_call" in hlo and "pinn2-bwd-ref" not in hlo
     _assert_kernel_names(hlo, ("kernel_res", "bwd_fused"))
     in_step = [ln for ln in hlo.splitlines() if "/while/body/" in ln]

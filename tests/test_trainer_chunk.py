@@ -122,8 +122,9 @@ def test_chunk_body_has_one_network_entry_per_loss_eval(local_steps):
 def test_chunk_hlo_packs_weights_once_per_loss_eval():
     """HLO extension of the PR-1 pad-count test: the compiled scanned chunk
     pads/stacks the layer weights exactly once per loss evaluation (here
-    local_steps=1 -> one (L, 128, 128) pack for the whole body), and the
-    megabatch means ONE padded point tensor, not one per res/iface/data set."""
+    local_steps=1 -> one (L, 128, 128) pack of the layers after the first
+    for the whole body, which the reverse reuses), and the megabatch means
+    ONE padded point tensor, not one per res/iface/data set."""
     pde, dec, topo, cfg, b = _setup(n_res=32, width=16, depth=2)
     tr = ReferenceTrainer(pde, cfg, topo, DDConfig(residual_path="pallas"))
     # force the padded Pallas dispatch (interpret mode); the CPU production
@@ -138,7 +139,9 @@ def test_chunk_hlo_packs_weights_once_per_loss_eval():
 
     txt3 = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
         state, b, 3).compile().as_text()
-    n_layer_mats = 3  # depth-2 MLP: 2 hidden + 1 output weight matrix
+    # depth-2 MLP: the hidden and the output weight matrix; the first
+    # layer packs against x's rows, not into the (L, 128, 128) stack
+    n_layer_mats = 2
     assert weight_pads(txt3) == n_layer_mats, \
         "chunk body packs the weight stack more than once per loss evaluation"
     # chunk length must not change the per-body pack count
