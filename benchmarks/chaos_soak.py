@@ -15,7 +15,8 @@ fault kind and measures:
   (``SupervisorReport.recovery_s``, stamped by the injectable obs clock) and
   corrupt→reswapped latency on the serve side,
 * **integrity write overhead** — paired ``ckpt.save`` with and without the
-  checksum envelope (fig4 round-robin + paired-ratio idiom, acceptance <= 5%).
+  checksum envelope (round-robin + paired-ratio idiom of
+  ``benchmarks.common``, acceptance <= 5%).
 
 Writes ``BENCH_chaos.json`` at the repo root, appends headline rows to the
 ``BENCH_history.jsonl`` perf trajectory, and routes every ``corruption`` /
@@ -51,8 +52,8 @@ from repro.runtime import (ChaosInjector, Fault, STORAGE_FAULT_KINDS,
                            Supervisor, SupervisorConfig, corrupt_generation)
 from repro.serve import FieldEngine, ServeFrontend, export_bundle, load_bundle
 
-from benchmarks.common import bench_path, emit, history_append
-from benchmarks.fig4_cost_profile import _interleaved, _med, _paired_ratio
+from benchmarks.common import (_interleaved, _med, _paired_ratio, bench_path,
+                               emit, history_append)
 
 OVERHEAD_BOUND_PCT = 5.0
 
@@ -168,8 +169,8 @@ def _check(summary: dict) -> None:
 def save_overhead(iters: int = 8) -> dict:
     """Paired checkpoint-write cost with vs without the integrity envelope.
 
-    Round-robin interleaved saves into two sibling roots so the container's
-    CPU-quota drift cancels in the paired ratio (the fig4 idiom).  The tree
+    Round-robin interleaved saves into two sibling roots so the host's
+    CPU-quota drift cancels in the paired ratio (``benchmarks.common``).  The tree
     is ~16 MB so array bytes dominate the save (the quickstart tree is a few
     hundred KB — at that size a save is ~4 ms of filesystem latency and the
     paired ratio measures noise, not the envelope)."""

@@ -20,6 +20,8 @@ import os
 import subprocess
 import sys
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 RESULTS = os.path.join(REPO, "benchmarks", "results")
@@ -114,3 +116,48 @@ def flush_history_gate() -> list[dict]:
 
     return [gate(HISTORY, bench, rows, mode=mode)
             for bench, rows, mode in pending]
+
+
+# ------------------------------------------------------ paired CPU timing
+
+def _interleaved(fns: dict, arg, iters: int) -> dict:
+    """Per-round us samples per candidate, measured in ROUND-ROBIN.
+
+    A shared host's CPU quota drifts on minute scales; timing candidate A for
+    its full budget and then candidate B confounds the comparison with the
+    drift.  One pass per round over every candidate puts competing paths
+    seconds (not minutes) apart, so PAIRED per-round statistics (differences,
+    ratios) see the same machine.  Returns the raw per-round lists — derive
+    medians / paired diffs from them, never a difference of medians.
+    """
+    import time as _time
+
+    import jax
+
+    for fn in fns.values():
+        jax.block_until_ready(fn(arg))  # compile + warm
+        jax.block_until_ready(fn(arg))
+    ts = {k: [] for k in fns}
+    for _ in range(iters):
+        for k, fn in fns.items():
+            t0 = _time.perf_counter()
+            jax.block_until_ready(fn(arg))
+            ts[k].append((_time.perf_counter() - t0) * 1e6)
+    return {k: np.asarray(v) for k, v in ts.items()}
+
+
+def _med(x) -> float:
+    return float(np.median(x))
+
+
+def _paired_ratio(num, den):
+    """Median of per-round ratios over rounds where both diffs are positive
+    (a quota dip can make a small same-round difference go non-positive);
+    falls back to the ratio of median diffs, and to NaN when even the medians
+    are non-positive — a visible sentinel, never a fabricated huge speedup."""
+    num, den = np.asarray(num), np.asarray(den)
+    ok = (num > 0) & (den > 0)
+    if ok.any():
+        return float(np.median(num[ok] / den[ok]))
+    mn, md = float(np.median(num)), float(np.median(den))
+    return mn / md if mn > 0 and md > 0 else float("nan")

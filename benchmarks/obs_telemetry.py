@@ -5,12 +5,13 @@ Three claims of EXPERIMENTS.md §Observability, measured and enforced:
 * **telemetry overhead <= 2%** — the in-graph per-step metric rows
   (grad/param norms, interface mismatch, lr, guard flags) ride the scanned
   chunk's stacked outputs; the guarded chunk with ``telemetry=True`` must
-  stay within 2% of the plain guarded chunk (fig4 round-robin + paired-ratio
-  idiom, so CPU-quota drift cancels);
-* **retrace flatness** — once warmed, serve batch-size buckets, guarded and
-  unguarded chunks, and ``lr_scale`` changes must all dispatch with ZERO new
-  backend compiles (``repro.obs.CompileWatcher``; a cache-hit dispatch emits
-  no compile events, so the assertion is a flat line, not a heuristic);
+  stay within 2% of the plain guarded chunk (round-robin + paired-ratio
+  idiom of ``benchmarks.common``, so CPU-quota drift cancels);
+* **retrace flatness** — once warmed, serve batch-size buckets, the per-step
+  reference and the guarded chunk, and ``lr_scale`` changes must all dispatch
+  with ZERO new backend compiles (``repro.obs.CompileWatcher``; a cache-hit
+  dispatch emits no compile events, so the assertion is a flat line, not a
+  heuristic);
 * **JSONL schema** — a supervised training run with an attached event log
   must produce a stream that passes ``repro.obs.validate_events`` (manifest
   first, schema version match, typed required fields); a malformed stream
@@ -43,8 +44,8 @@ from repro.obs import (CompileWatcher, ObsSchemaError, make_obs,
                        validate_events)
 from repro.runtime import Fault, FaultInjector, Supervisor, SupervisorConfig
 
-from benchmarks.common import emit, save_json
-from benchmarks.fig4_cost_profile import _interleaved, _med, _paired_ratio
+from benchmarks.common import (_interleaved, _med, _paired_ratio, emit,
+                               save_json)
 
 OVERHEAD_BOUND_PCT = 2.0
 
@@ -199,11 +200,12 @@ def retrace_rows():
     rows.append(("obs/retrace/serve_buckets_compiles",
                  w_serve.backend_compiles, ""))
 
-    # (b) guarded vs unguarded chunks: both warmed, then interleaved
-    st = tr.run_chunk(tr.init(0), b, 3)[0]
+    # (b) the per-step reference and the guarded chunk: both warmed, then
+    # interleaved
+    st = tr.step(tr.init(0), b)[0]
     st2, _t, _h = tr.run_chunk_guarded(tr.init(0), b, 3)
     with CompileWatcher() as w_chunk:
-        st = tr.run_chunk(st, b, 3)[0]
+        st = tr.step(st, b)[0]
         st2 = tr.run_chunk_guarded(st2, b, 3)[0]
     rows.append(("obs/retrace/chunk_guard_compiles",
                  w_chunk.backend_compiles, ""))

@@ -1,15 +1,13 @@
 """Benchmark driver: one function per paper table/figure.
 
-``python -m benchmarks.run [--quick] [--smoke] [--only fig4,fig6,...]``
+``python -m benchmarks.run [--quick] [--smoke] [--only fig13,serve,...]``
 
 Prints ``name,value,unit`` CSV rows per benchmark; raw measurements land in
 benchmarks/results/*.json.  The roofline rows read the dry-run outputs
 (run ``python -m repro.launch.dryrun`` first for those).
 
-``--smoke`` is the CI-fast mode: a single tiny fig4 configuration with the
-jvp-vs-pallas residual comparison plus the analytic residual roofline —
-seconds, not minutes; the full kernel sweeps stay on-demand
-(``pytest -m kernel`` / the unflagged benchmark runs).
+``--smoke`` is the CI-fast mode: seconds, not minutes; the full kernel
+sweeps stay on-demand (``pytest -m kernel`` / the unflagged benchmark runs).
 """
 from __future__ import annotations
 
@@ -24,37 +22,25 @@ from benchmarks.common import emit
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="comma list: fig4,fig6,fig8,fig9,table2,fig13,serve,"
-                         "slo,ft,chaos,obs,trace,roofline")
+                    help="comma list: fig13,serve,slo,chaos,obs,trace,"
+                         "roofline")
     ap.add_argument("--quick", action="store_true", help="fewer sizes/iters")
     ap.add_argument("--smoke", action="store_true",
-                    help="seconds-fast subset: tiny fig4 jvp-vs-pallas + "
-                         "run_chunk e2e + supervisor crash/NaN recovery + "
+                    help="seconds-fast subset: serve throughput + "
                          "serve-SLO clean/faulted acceptance + storage-chaos "
                          "durability acceptance + validated trace exports + "
                          "perf-regression gate + roofline")
     args = ap.parse_args()
 
-    from benchmarks import (chaos_soak, fig4_cost_profile, fig6_comp_comm,
-                            fig8_weak_scaling, fig9_strong_scaling,
-                            fig13_inverse, ft_overhead, obs_telemetry,
+    from benchmarks import (chaos_soak, fig13_inverse, obs_telemetry,
                             roofline, serve_slo, serve_throughput,
-                            table2_spacetime, trace_observatory)
+                            trace_observatory)
 
     if args.smoke:
         # history appends buffer until the gate below: a regressing run is
         # flagged BEFORE it can enter its own baseline
         common.defer_history()
-        # the pallas fig4 pass exercises BOTH custom-VJP backwards (fused
-        # hand-derived vs checkpointed-ref) and reports the fwd/bwd split
-        rows = fig4_cost_profile.run(iters=3, path="pallas", smoke=True)
-        # selector round-trip: fused-bwd and ref-bwd training must agree
-        rows += fig4_cost_profile.bwd_parity_rows()
-        rows += fig4_cost_profile.run_e2e(iters=1, smoke=True)
-        rows += serve_throughput.run(iters=2, smoke=True)
-        # supervisor recovery acceptance: one crash (bitwise replay) + one NaN
-        # (guard trip -> backoff -> finite completion)
-        rows += ft_overhead.recovery_smoke_rows()
+        rows = serve_throughput.run(iters=2, smoke=True)
         # serve-SLO acceptance: Poisson load, clean + injected fault matrix;
         # FAILS if any ticket is lost / the queue wedges / goodput under
         # faults drops below the floor
@@ -86,18 +72,9 @@ def main() -> None:
 
     quick = args.quick
     suite = {
-        "fig4": lambda: fig4_cost_profile.run(iters=3 if quick else 10),
-        "fig6": lambda: fig6_comp_comm.run(sizes=(4,) if quick else (4, 8, 12),
-                                           iters=3 if quick else 5),
-        "fig8": lambda: fig8_weak_scaling.run(sizes=(1, 4) if quick else (1, 2, 4, 8),
-                                              iters=3 if quick else 5),
-        "fig9": lambda: fig9_strong_scaling.run(sizes=(1, 4) if quick else (1, 2, 4, 8),
-                                                iters=3 if quick else 5),
-        "table2": lambda: table2_spacetime.run(iters=3 if quick else 5),
         "fig13": lambda: fig13_inverse.run(iters=3 if quick else 5),
         "serve": lambda: serve_throughput.run(iters=3 if quick else 5),
         "slo": lambda: serve_slo.run(smoke=quick),
-        "ft": lambda: ft_overhead.run(iters=3 if quick else 10),
         "chaos": lambda: chaos_soak.run(iters=3 if quick else 8,
                                         smoke=quick),
         "obs": lambda: obs_telemetry.run(iters=3 if quick else 10,

@@ -61,8 +61,8 @@ topo = build_topology(dec, 20)
 cfg = SubdomainModelConfig(nets={"u": MLPConfig(2, 1, 20, 5)})
 b = make_batch(dec, topo, pde, 200, 20, np.random.default_rng(0)).device_arrays()
 tr = DistributedDDTrainer(pde, cfg, topo, DDConfig(method=XPINN), lrs=1e-3)
-hlo = tr._build_chunk(4).lower(tr.shard_state(tr.init(0)),
-                               tr.shard_batch(b)).compile().as_text()
+hlo = tr._chunk_guarded.lower(tr.shard_state(tr.init(0)), tr.shard_batch(b), 4,
+                              np.ones(4, np.float32)).compile().as_text()
 print("RESULT:" + json.dumps(halo_traffic(hlo)))
 """
 

@@ -9,7 +9,7 @@ One process runs the phases in order and the first failure exits non-zero:
 2. train: the quickstart problem (viscous Burgers, 2x2 space-time XPINN, one
    tanh MLP per subdomain) at the paper's Table-2 size (80,000 residual
    points in all, 80 boundary and 20 interface points, width 20, depth 5)
-   on the fused Pallas path, through ``Supervisor`` + guarded ``run_chunk``;
+   on the fused Pallas path, through ``Supervisor`` + ``run_chunk_guarded``;
    the compiled chunk must hold the Pallas kernels and the fused backward;
 3. oracle: loss and gradients of the fused path against the per-point jvp
    oracle, both at ``highest`` matmul precision;
@@ -215,7 +215,7 @@ def loss_and_grads(tr, params, batch):
     """Summed subdomain loss and its gradients, as the trainer's step
     differentiates them (received payloads enter as constants)."""
     def total(p):
-        outs = jax.vmap(tr._net_eval)(p, tr.act_codes, tr.width_masks, batch)
+        outs = jax.vmap(tr._net_eval)(p, tr.act_codes, batch)
         recv = jax.lax.stop_gradient(
             halo.exchange_tree_gather(outs[1], tr.topo))
         tot, _ = jax.vmap(tr._assemble)(batch, *outs, recv)
@@ -334,18 +334,22 @@ def four_chip_compare(size: Size) -> dict:
         check(len(leaf.sharding.device_set) == 4,
               f"{name}: sharded state spans {leaf.sharding.device_set}")
         t0 = time.perf_counter()
-        hlo = dist._build_chunk(size.compare_steps).lower(
-            s_d, b_d).compile().as_text()
+        hlo = dist._chunk_guarded.lower(
+            s_d, b_d, size.compare_steps,
+            jnp.ones_like(dist.lrs)).compile().as_text()
         compile_s = time.perf_counter() - t0
         check("collective-permute" in hlo,
               f"{name}: compiled chunk has no collective-permute")
         t0 = time.perf_counter()
-        s_d, t_d = dist.run_chunk(s_d, b_d, size.compare_steps)
+        s_d, t_d, h_d = dist.run_chunk_guarded(s_d, b_d, size.compare_steps)
         jax.block_until_ready(t_d)
         dist_s = time.perf_counter() - t0
         with jax.default_device(devs[0]):
-            s_r, t_r = ref.run_chunk(ref.init(0), prob.batch,
-                                     size.compare_steps)
+            s_r, t_r, h_r = ref.run_chunk_guarded(ref.init(0), prob.batch,
+                                                  size.compare_steps)
+        for who, h in (("sharded", h_d), ("reference", h_r)):
+            check(bool(h["ok"]), f"{name}: {who} chunk tripped its guard: "
+                  f"ok_sub={np.asarray(h['ok_sub']).tolist()}")
         leaf = jax.tree.leaves(s_d.params)[0]
         check(len(leaf.sharding.device_set) == 4,
               f"{name}: trained state collapsed onto {leaf.sharding.device_set}")

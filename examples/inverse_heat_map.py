@@ -156,7 +156,13 @@ def main():
         t_done = done
         while done < args.steps:
             n = min(max(args.chunk, 1), args.steps - done)
-            state, terms = trainer.run_chunk(state, b, n)
+            state, terms, health = trainer.run_chunk_guarded(state, b, n)
+            if not bool(health["ok"]):
+                raise RuntimeError(
+                    f"[inverse] loss or parameters went non-finite at step "
+                    f"{done + int(health['good_steps'])}: ok_sub="
+                    f"{np.asarray(health['ok_sub']).tolist()}; --supervised "
+                    "rolls back and retries")
             prev, done = done, done + n
             if args.save_every and done // args.save_every > prev // args.save_every:
                 save_train_state(args.ckpt, state)

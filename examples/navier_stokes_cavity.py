@@ -34,7 +34,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=4000)
     ap.add_argument("--chunk", type=int, default=500,
-                    help="outer steps per device dispatch (lax.scan driver)")
+                    help="outer steps per device dispatch (guarded lax.scan "
+                         "driver)")
     args = ap.parse_args()
 
     pde = NavierStokes2D(re=100.0)
@@ -54,7 +55,12 @@ def main():
     done = 0
     while done < args.steps:
         n = min(max(args.chunk, 1), args.steps - done, 500 - done % 500)
-        state, terms = trainer.run_chunk(state, b, n)
+        state, terms, health = trainer.run_chunk_guarded(state, b, n)
+        if not bool(health["ok"]):
+            raise RuntimeError(
+                f"[cavity] loss or parameters went non-finite at step "
+                f"{done + int(health['good_steps'])}: ok_sub="
+                f"{np.asarray(health['ok_sub']).tolist()}")
         done += n
         if done % 500 == 0 or done == args.steps:
             loss = float(np.asarray(terms["loss"])[-1].sum())

@@ -41,8 +41,8 @@ def main():
                     help="residual evaluation: fused kernel (default) or the "
                          "per-point jvp oracle")
     ap.add_argument("--chunk", type=int, default=250,
-                    help="outer steps per device dispatch (lax.scan driver); "
-                         "1 falls back to the per-step jit loop")
+                    help="outer steps per device dispatch (guarded lax.scan "
+                         "driver); 1 falls back to the per-step jit loop")
     ap.add_argument("--save-every", type=int, default=0,
                     help="checkpoint the TrainState every N steps (0 = off)")
     ap.add_argument("--ckpt", default="ckpt_quickstart",
@@ -127,7 +127,13 @@ def main():
             state, terms = trainer.step(state, b)
             n, last_loss = 1, float(np.asarray(terms["loss"]).sum())
         else:
-            state, terms = trainer.run_chunk(state, b, n)
+            state, terms, health = trainer.run_chunk_guarded(state, b, n)
+            if not bool(health["ok"]):
+                raise RuntimeError(
+                    f"[quickstart] loss or parameters went non-finite at step "
+                    f"{done + int(health['good_steps'])}: ok_sub="
+                    f"{np.asarray(health['ok_sub']).tolist()}; --supervised "
+                    "rolls back and retries")
             last_loss = float(np.asarray(terms["loss"])[-1].sum())
         prev, done = done, done + n
         if args.save_every and done // args.save_every > prev // args.save_every:

@@ -22,30 +22,33 @@ Straggler mitigation / communication avoidance: ``local_steps = k`` runs k Adam
 steps per halo exchange (received payloads frozen in between) — beyond-paper, see
 EXPERIMENTS.md §Perf.
 
-Single-dispatch training (EXPERIMENTS.md §Step fusion): every trainer exposes
-``run_chunk(state, batch, steps)`` — a ``lax.scan`` over outer steps compiled
-into ONE jitted dispatch with ``TrainState`` buffers donated (params/opt update
-in place), the halo exchange living inside the scan body.  Each loss evaluation
-enters the network exactly once: ``losses.network_eval`` megabatches residual +
-interface + data points, ``jax.vjp`` captures that single forward so the
-exchange payload and the differentiated loss share it, and the assembled loss's
-cotangents chain back through the saved VJP.
+One step body (``_DDCommon._outer_body``) serves both domain-decomposed
+trainers; each names only how a per-subdomain function maps over its layout
+(``jax.vmap`` over the stacked axis, or the squeezed shard as it is) and how
+the interface payloads cross (gather, or ppermute over ``"sub"``).  Each loss
+evaluation enters the network exactly once: ``losses.network_eval``
+megabatches residual + interface + data points, ``jax.vjp`` captures that
+single forward so the exchange payload and the differentiated loss share it,
+and the assembled loss's cotangents chain back through the saved VJP.
 
-Guarded chunks (EXPERIMENTS.md §Robustness): every trainer also exposes
-``run_chunk_guarded(state, batch, steps, lr_scale)`` — the same scanned
-single-dispatch driver with an IN-GRAPH health guard in the scan body.  After
-each outer step the body checks that the per-subdomain losses and the updated
-parameters are finite; once any check trips, a ``lax.cond`` freezes the carried
-state for the remaining steps (early exit without breaking the static scan
-length, donation, or the one-entry-per-loss-eval contract).  The chunk returns
-``(state, terms, health)`` where ``health`` records the per-subdomain ok flags
-and the number of applied steps, so the supervisor (``runtime.supervisor``)
-can roll back to the last good checkpoint and retry with per-subdomain
-learning-rate backoff — ``lr_scale`` rides the dispatch as a plain argument,
-so backoff never recompiles.
+One training driver (EXPERIMENTS.md §Step fusion, §Robustness): every trainer
+exposes ``run_chunk_guarded(state, batch, steps, lr_scale)`` — a ``lax.scan``
+over outer steps compiled into ONE jitted dispatch with the state donated
+(params/opt update in place), the exchange inside the scan body, and the
+in-graph health guard of :func:`_guarded_scan`.  After each outer step the
+guard checks that the per-subdomain losses and the updated parameters are
+finite; once any check trips, a ``lax.cond`` freezes the carried state for the
+remaining steps.  The chunk returns ``(state, terms, health)``, where
+``health`` records the per-subdomain ok flags and the number of applied steps,
+so the supervisor (``runtime.supervisor``) can roll back to the last good
+checkpoint and retry with per-subdomain learning-rate backoff — ``lr_scale``
+rides the dispatch as a plain argument, so backoff never recompiles.  A caller
+without a supervisor stops on ``health["ok"] == False``.  ``step`` runs one
+plain outer step; it is the per-step reference the chunk is tested against.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Sequence
 
@@ -111,8 +114,51 @@ def _nan_like(shapes):
     return jax.tree.map(lambda s: jnp.full(s.shape, jnp.nan, s.dtype), shapes)
 
 
+def _guarded_scan(live, probe, carry, ok_shape, steps, agree, norm,
+                  telemetry, hoist_nan=False):
+    """``steps`` guarded outer steps as one ``lax.scan`` — the health guard of
+    every trainer's chunk.
+
+    ``live(carry) -> (carry, terms)`` is one outer step; the guard runs it
+    only while ``agree(ok)`` holds (``jnp.all`` over a stacked ok vector, a
+    collective vote across shards, or a replicated flag as it is), then
+    freezes the carry.  The live branch IS the step — same trace, same single
+    network entry per loss evaluation — so guarding never adds a dispatch.
+    The frozen branch's NaN terms take their shapes from ``probe`` (``live``
+    without the exchange), so a trace issues (and counts) the halo once.
+    They are built in the scan body, or once before the scan with
+    ``hoist_nan``: the values are the same, but XLA compiles the two
+    placements into different programs, so each trainer keeps the one its
+    benchmark cell was measured with.  After each step, ``ok`` keeps the
+    subdomains whose loss and updated parameters (``norm`` of ``carry[0]``)
+    are finite; after a trip it stays as it was, so the caller sees WHICH
+    subdomains diverged.  Returns
+    ``((carry, ok, good_steps), terms)``; with ``telemetry`` each terms row
+    also holds ``step_ok``, the ok flags after that step."""
+    nan_like = lambda c: _nan_like(jax.eval_shape(probe, c)[1])
+    hoisted = nan_like(carry) if hoist_nan else None
+
+    def body(c, _):
+        inner, ok, good = c
+        nan_terms = hoisted if hoist_nan else nan_like(inner)
+        all_ok = agree(ok)
+        inner, terms = jax.lax.cond(all_ok, live, lambda a: (a, nan_terms),
+                                    inner)
+        # health of the step just applied: finite loss AND finite updated
+        # params (catches NaN grads/moments the loss can't see yet)
+        healthy = jnp.isfinite(terms["loss"]) & jnp.isfinite(norm(inner[0]))
+        ok = jnp.where(all_ok, ok & healthy, ok)
+        if telemetry:
+            # added outside the cond so the frozen branch records too
+            terms = dict(terms, step_ok=ok)
+        return (inner, ok, good + all_ok.astype(jnp.int32)), terms
+
+    carry0 = (carry, jnp.ones(ok_shape, bool), jnp.zeros((), jnp.int32))
+    return jax.lax.scan(body, carry0, None, length=steps)
+
+
 def _traced_dispatch(trainer, name: str, steps, call):
-    """Host-side chunk span around a public ``run_chunk*`` dispatch.
+    """Host-side chunk span around a public ``run_chunk_guarded`` dispatch.
 
     ``trainer.tracer is None`` (the default) takes ``call()`` verbatim — no
     span object, no annotation, no clock read, and the jitted program is the
@@ -134,22 +180,22 @@ def _traced_dispatch(trainer, name: str, steps, call):
 
 # ------------------------------------------------------- in-graph telemetry
 
-def _telemetry_terms(terms: dict, params, grads, lr, stacked: bool) -> dict:
+def _telemetry_terms(terms: dict, params, grads, lr, norm) -> dict:
     """Per-step metric rows riding the scan's ``terms`` output (EXPERIMENTS.md
     §Observability).  Pure arithmetic on values the step already computed —
-    two parameter-tree reductions, a few scalar ops — so the chunk stays ONE
-    dispatch and the measured overhead is bounded at 2%:
+    two parameter-tree reductions (``norm``: per subdomain on stacked trees),
+    a few scalar ops — so the chunk stays ONE dispatch and the measured
+    overhead is bounded at 2%:
 
     * ``grad_norm`` / ``param_norm`` — L2 norms of the (last local step's)
-      loss gradient and the updated parameters, per subdomain on stacked
-      trees; the early-warning signals for the divergences the guard trips on;
+      loss gradient and the updated parameters; the early-warning signals for
+      the divergences the guard trips on;
     * ``lr`` — the EFFECTIVE per-subdomain learning rate of this step
       (includes the supervisor's recovery ``lr_scale`` backoff);
     * ``iface_mismatch`` — RMS interface disagreement sqrt(MSE_avg + MSE_F/flux),
       the paper's Figs 6-9 coupling-quality axis, when the loss has interface
       terms (the data-parallel baseline has none).
     """
-    norm = _stacked_sqnorm if stacked else _sqnorm
     t = dict(terms)
     t["grad_norm"] = jnp.sqrt(norm(grads))
     t["param_norm"] = jnp.sqrt(norm(params))
@@ -161,7 +207,11 @@ def _telemetry_terms(terms: dict, params, grads, lr, stacked: bool) -> dict:
 
 
 class _DDCommon:
-    """Shared setup + per-subdomain step body."""
+    """Shared setup, the one outer step and the public guarded driver of the
+    domain-decomposed trainers.  A subclass names its layout: ``_per_sub``
+    (how a per-subdomain function maps over it), ``_exchange`` (how the
+    interface payloads cross), ``_agree`` and ``_norm`` (the guard's vote and
+    parameter norm), and builds ``_step`` and ``_run_chunk_guarded``."""
 
     def __init__(
         self,
@@ -171,13 +221,12 @@ class _DDCommon:
         cfg: DDConfig,
         act_codes: Sequence[str | int] | None = None,
         lrs: float | Sequence[float] = 1e-3,
-        width_fracs: dict[str, Sequence[float]] | None = None,
     ):
         self.pde, self.model_cfg, self.topo, self.cfg = pde, model_cfg, topo, cfg
         n = topo.n_sub
         self._act_codes_in = act_codes
         # optional repro.obs.Tracer: host-side chunk spans around the public
-        # run_chunk* dispatches (the supervisor wires its obs tracer in here)
+        # run_chunk_guarded dispatch (the supervisor wires its obs tracer in)
         self.tracer = None
         # fused-kernel residual dispatch: requires (a) a single activation
         # shared by all subdomains (the kernel is specialized statically) and
@@ -205,16 +254,9 @@ class _DDCommon:
             np.array(lrs, np.float32)
         )
         assert self.lrs.shape == (n,)
-        # per-subdomain width masks (paper: per-subdomain architecture freedom)
-        self.width_masks = None
-        if width_fracs is not None:
-            self.width_masks = {}
-            for name, fr in width_fracs.items():
-                w = model_cfg.nets[name].width
-                m = np.zeros((n, w), np.float32)
-                for q, f in enumerate(fr):
-                    m[q, : max(1, int(round(f * w)))] = 1.0
-                self.width_masks[name] = jnp.asarray(m)
+        self.step = jax.jit(self._step)
+        self._chunk_guarded = jax.jit(self._run_chunk_guarded,
+                                      static_argnums=(2,), donate_argnums=(0,))
 
     def init(self, seed: int = 0) -> TrainState:
         params, self.act_codes = nets.stacked_init(
@@ -224,11 +266,11 @@ class _DDCommon:
         return TrainState(params=params, opt=opt, step=jnp.zeros((), jnp.int32))
 
     # ---- single-subdomain pieces (no stacked axis) -------------------------------
-    def _net_eval(self, params, act_code, wmask, batch: SubBatch):
+    def _net_eval(self, params, act_code, batch: SubBatch):
         """All network-dependent quantities in one entry (megabatched on the
         fused path): (res, normal-projected own payload, data_pred)."""
         return losses.network_eval(
-            self.pde, self.model_cfg, self.cfg.method, params, act_code, wmask,
+            self.pde, self.model_cfg, self.cfg.method, params, act_code, None,
             batch, self.res_path,
         )
 
@@ -244,138 +286,64 @@ class _DDCommon:
             return recv
         return jax.tree.map(jax.lax.stop_gradient, recv)
 
+    # ---- the outer step ----------------------------------------------------------
+    def _outer_body(self, params, opt, act_codes, lrs, batch: SubBatch,
+                    exchange: bool = True):
+        """One outer step (exchange + ``local_steps`` Adam updates) on this
+        trainer's layout.  ONE network entry per loss evaluation: ``jax.vjp``
+        captures the megabatched forward, the exchange payload is a slice of
+        that SAME forward (no separate payload entry), and the assembled
+        loss's cotangents chain back through the saved VJP.  ``lrs`` are the
+        effective learning rates (guarded chunks scale them for recovery
+        backoff).  ``exchange=False`` puts the local payload in place of the
+        received one, as ``disable_exchange`` does: same shapes, no
+        communication.  Returns ``(params, opt, terms)``."""
+        cfg, per_sub = self.cfg, self._per_sub
+        net_eval = lambda p: per_sub(self._net_eval)(p, act_codes, batch)
 
-class ReferenceTrainer(_DDCommon):
-    """Single-device oracle: vmap over subdomains + gather exchange."""
-
-    def __init__(self, *args, **kw):
-        super().__init__(*args, **kw)
-        self.step = jax.jit(self._step)
-        self._chunk_const = jax.jit(self._run_chunk_const, static_argnums=(2,),
-                                    donate_argnums=(0,))
-        self._chunk_stacked = jax.jit(self._run_chunk_stacked, donate_argnums=(0,))
-        self._chunk_guarded = jax.jit(self._run_chunk_guarded, static_argnums=(2,),
-                                      donate_argnums=(0,))
-
-    def _outer_body(self, carry, batch: SubBatch, lrs=None):
-        """One outer step (exchange + local_steps Adam updates) on stacked
-        arrays.  ONE network entry per loss evaluation: ``jax.vjp`` captures
-        the megabatched forward, the exchange payload is a slice of that SAME
-        forward (no separate payload entry), and the assembled loss's
-        cotangents chain back through the saved VJP.  ``lrs`` overrides the
-        per-subdomain learning rates (guarded chunks scale them for recovery
-        backoff)."""
-        lrs = self.lrs if lrs is None else lrs
-        params, opt, step = carry
-        wm = self.width_masks  # dict of (n_sub, w) or None (None = empty pytree: vmap ok)
-        net_eval = lambda p: jax.vmap(self._net_eval)(p, self.act_codes, wm, batch)
-
-        def assemble_all(outs, recv):
+        def assemble(outs, recv):
             res, own, data_pred = outs
-            total, terms = jax.vmap(self._assemble)(batch, res, own, data_pred, recv)
-            return jnp.sum(total), terms
+            total, terms = per_sub(self._assemble)(batch, res, own, data_pred,
+                                                   recv)
+            # the stacked layout sums its subdomains' losses: their gradients
+            # stay separate, as each rank differentiates only its own
+            return (jnp.sum(total) if total.ndim else total), terms
 
         # communicate once per outer step (Algorithm 1), then k local updates;
         # the exchange payload rides on inner step 1's forward
         with jax.named_scope("dd-comp-forward"):
             outs, vjp_fn = jax.vjp(net_eval, params)
         own0 = outs[1]
-        if self.cfg.disable_exchange:
+        if cfg.disable_exchange or not exchange:
             recv = self._maybe_stop(own0)
         else:
-            recv = self._maybe_stop(halo.exchange_tree_gather(own0, self.topo))
+            recv = self._maybe_stop(self._exchange(own0))
 
         terms = None
-        for i in range(self.cfg.local_steps):
+        for i in range(cfg.local_steps):
             if i > 0:  # received payloads stay frozen; fresh forward on new params
                 with jax.named_scope("dd-comp-forward"):
                     outs, vjp_fn = jax.vjp(net_eval, params)
             with jax.named_scope("dd-comp-update"):
-                (_, terms), gouts = jax.value_and_grad(assemble_all, has_aux=True)(outs, recv)
+                (_, terms), gouts = jax.value_and_grad(assemble, has_aux=True)(outs, recv)
                 (grads,) = vjp_fn(gouts)
-                params, opt = adam_lib.adam_update(grads, opt, params, lrs, self.cfg.adam)
-        if self.cfg.telemetry:
-            terms = _telemetry_terms(terms, params, grads, lrs, stacked=True)
-        return (params, opt, step + 1), terms
-
-    def _step(self, state: TrainState, batch: SubBatch) -> tuple[TrainState, dict]:
-        carry, terms = self._outer_body((state.params, state.opt, state.step), batch)
-        params, opt, step = carry
-        return TrainState(params=params, opt=opt, step=step), terms
-
-    def _run_chunk_const(self, state, batch, steps):
-        carry, terms = jax.lax.scan(
-            lambda c, _: self._outer_body(c, batch),
-            (state.params, state.opt, state.step), None, length=steps)
-        params, opt, step = carry
-        return TrainState(params=params, opt=opt, step=step), terms
-
-    def _run_chunk_stacked(self, state, batches):
-        carry, terms = jax.lax.scan(
-            self._outer_body, (state.params, state.opt, state.step), batches)
-        params, opt, step = carry
-        return TrainState(params=params, opt=opt, step=step), terms
-
-    def run_chunk(self, state: TrainState, batch: SubBatch, steps: int | None = None):
-        """Run a whole chunk of outer steps in ONE jitted dispatch (lax.scan).
-
-        ``batch`` is either a normal stacked SubBatch reused every step
-        (``steps`` gives the chunk length) or, with ``steps=None``, a SubBatch
-        whose leaves carry an extra LEADING chunk axis (one batch per step —
-        e.g. resampled collocation points).  ``state`` is DONATED: params and
-        optimizer buffers alias in place, so the caller must rebind
-        (``state, terms = trainer.run_chunk(state, batch, n)``) and never touch
-        the old state again.  Returns (state, terms) with every term stacked
-        over the chunk axis, shape (steps, n_sub).
-        """
-        if steps is None:
-            return _traced_dispatch(self, "train.run_chunk", None,
-                                    lambda: self._chunk_stacked(state, batch))
-        return _traced_dispatch(self, "train.run_chunk", steps,
-                                lambda: self._chunk_const(state, batch, steps))
-
-    # ------------------------------------------------------------ guarded chunk
-    def _guarded_body(self, carry, batch: SubBatch, lrs):
-        """Scan body with the in-graph health guard: run one outer step only
-        while every subdomain is healthy, then freeze the carry.  The live
-        branch IS ``_outer_body`` — same trace, same single network entry per
-        loss evaluation — so guarding never adds a dispatch."""
-        inner, ok_sub, good = carry
-        live = lambda c: self._outer_body(c, batch, lrs)
-        nan_terms = _nan_like(jax.eval_shape(live, inner)[1])
-        all_ok = jnp.all(ok_sub)
-        inner, terms = jax.lax.cond(all_ok, live, lambda c: (c, nan_terms), inner)
-        # health of the step just applied: finite per-subdomain loss AND finite
-        # updated params (catches NaN grads/moments the loss can't see yet)
-        healthy = (jnp.isfinite(terms["loss"])
-                   & jnp.isfinite(_stacked_sqnorm(inner[0])))
-        # after a trip the NaN terms would flag everyone — keep the trip-time
-        # ok vector so the supervisor sees WHICH subdomains diverged
-        ok_sub = jnp.where(all_ok, ok_sub & healthy, ok_sub)
-        if self.cfg.telemetry:
-            # per-step guard row: which subdomains were still ok AFTER this
-            # step (added outside the cond so the frozen branch records too)
-            terms = dict(terms, step_ok=ok_sub)
-        return (inner, ok_sub, good + all_ok.astype(jnp.int32)), terms
-
-    def _run_chunk_guarded(self, state, batch, steps, lr_scale):
-        lrs = self.lrs * lr_scale
-        carry0 = ((state.params, state.opt, state.step),
-                  jnp.ones((self.topo.n_sub,), bool), jnp.zeros((), jnp.int32))
-        (inner, ok_sub, good), terms = jax.lax.scan(
-            lambda c, _: self._guarded_body(c, batch, lrs), carry0, None,
-            length=steps)
-        params, opt, step = inner
-        health = {"ok": jnp.all(ok_sub), "ok_sub": ok_sub, "good_steps": good}
-        return TrainState(params=params, opt=opt, step=step), terms, health
+                params, opt = adam_lib.adam_update(grads, opt, params, lrs, cfg.adam)
+        if cfg.telemetry:
+            terms = _telemetry_terms(terms, params, grads, lrs, self._norm)
+        return params, opt, terms
 
     def run_chunk_guarded(self, state: TrainState, batch: SubBatch, steps: int,
                           lr_scale=None):
-        """``run_chunk`` with the in-graph health guard — still ONE jitted
-        dispatch with ``state`` donated.  Returns ``(state, terms, health)``:
-        ``health["ok_sub"]`` (n_sub,) marks subdomains whose loss/params went
-        non-finite, ``health["good_steps"]`` counts applied outer steps (the
-        carry freezes once tripped; terms rows after the trip are NaN).
+        """Run ``steps`` guarded outer steps in ONE jitted dispatch.
+
+        ``state`` is DONATED: params and optimizer buffers alias in place, so
+        the caller must rebind (``state, terms, health =
+        trainer.run_chunk_guarded(state, batch, n)``) and never touch the old
+        state again.  Returns ``(state, terms, health)``: term leaves are
+        stacked (steps, n_sub); ``health["ok_sub"]`` (n_sub,) marks
+        subdomains whose loss/params went non-finite, ``health["good_steps"]``
+        counts applied outer steps (the carry freezes once tripped; terms rows
+        after the trip are NaN) and ``health["ok"]`` is their conjunction.
         ``lr_scale`` (n_sub,) scales the per-subdomain learning rates without
         recompiling (recovery backoff)."""
         if lr_scale is None:
@@ -386,8 +354,50 @@ class ReferenceTrainer(_DDCommon):
                                         jnp.asarray(lr_scale)))
 
 
+class ReferenceTrainer(_DDCommon):
+    """Single-device oracle: vmap over subdomains + gather exchange."""
+
+    _per_sub = staticmethod(jax.vmap)
+    _agree = staticmethod(jnp.all)
+    _norm = staticmethod(_stacked_sqnorm)
+
+    def _exchange(self, own):
+        return halo.exchange_tree_gather(own, self.topo)
+
+    def _live(self, carry, batch: SubBatch, lrs, exchange: bool = True):
+        params, opt, step = carry
+        params, opt, terms = self._outer_body(params, opt, self.act_codes, lrs,
+                                              batch, exchange)
+        return (params, opt, step + 1), terms
+
+    def _step(self, state: TrainState, batch: SubBatch) -> tuple[TrainState, dict]:
+        carry, terms = self._live((state.params, state.opt, state.step), batch,
+                                  self.lrs)
+        params, opt, step = carry
+        return TrainState(params=params, opt=opt, step=step), terms
+
+    def _run_chunk_guarded(self, state, batch, steps, lr_scale):
+        lrs = self.lrs * lr_scale
+        live = functools.partial(self._live, batch=batch, lrs=lrs)
+        (inner, ok_sub, good), terms = _guarded_scan(
+            live, functools.partial(live, exchange=False),
+            (state.params, state.opt, state.step), (self.topo.n_sub,), steps,
+            self._agree, self._norm, self.cfg.telemetry)
+        params, opt, step = inner
+        health = {"ok": jnp.all(ok_sub), "ok_sub": ok_sub, "good_steps": good}
+        return TrainState(params=params, opt=opt, step=step), terms, health
+
+
 class DistributedDDTrainer(_DDCommon):
-    """shard_map over the ("sub",) mesh — one device per subdomain (Algorithm 1)."""
+    """shard_map over the ("sub",) mesh — one device per subdomain (Algorithm 1).
+
+    Each shard runs the one outer step on its own subdomain (no leading
+    axis), with ppermute as the exchange; the guard's per-step ``all_ok`` is
+    a scalar ``pmin`` vote, so every shard freezes the moment ANY shard trips,
+    and ``health["ok_sub"]`` comes back stitched (n_sub,)."""
+
+    _per_sub = staticmethod(lambda f: f)
+    _norm = staticmethod(_sqnorm)
 
     def __init__(self, *args, mesh: Mesh | None = None, **kw):
         super().__init__(*args, **kw)
@@ -398,8 +408,6 @@ class DistributedDDTrainer(_DDCommon):
             mesh = Mesh(np.array(devs[:n]), ("sub",))
         assert mesh.shape["sub"] == n
         self.mesh = mesh
-        self.step = self._build_step()
-        self._chunk_cache: dict[int, Any] = {}
 
     def init(self, seed: int = 0) -> TrainState:
         state = super().init(seed)
@@ -407,196 +415,76 @@ class DistributedDDTrainer(_DDCommon):
         state.opt["count"] = jnp.zeros((self.topo.n_sub,), jnp.int32)
         return state
 
-    def _local_outer_body(self, params, opt, act_code, lr, wmask, batch: SubBatch,
-                          exchange: bool = True):
-        """One outer step for ONE shard (no leading axis), inside shard_map.
-        Same single-entry-per-loss-evaluation structure as the reference
-        trainer, with ppermute as the exchange (``exchange=False``: the
-        local payload in its place, as ``disable_exchange`` does — same
-        shapes, no collective)."""
-        cfg = self.cfg
-        net_eval = lambda p: self._net_eval(p, act_code, wmask, batch)
+    def _exchange(self, own):
+        return halo.exchange_tree_ppermute(own, self.topo, "sub")
 
-        def assemble(outs, recv):
-            res, own, data_pred = outs
-            return self._assemble(batch, res, own, data_pred, recv)
+    @staticmethod
+    def _agree(ok):
+        """Collective agreement: one scalar pmin per step — the SPMD analogue
+        of the reference trainer's ``jnp.all`` over the stacked ok vector."""
+        vote = ok.astype(jnp.int32)
+        with scope("sync"):
+            all_ok = jax.lax.pmin(vote, "sub") > 0
+        count_collective("sync", "all-reduce", vote.dtype.itemsize)
+        return all_ok
 
-        with jax.named_scope("dd-comp-forward"):
-            outs, vjp_fn = jax.vjp(net_eval, params)
-        own0 = outs[1]
-        if cfg.disable_exchange or not exchange:
-            recv = self._maybe_stop(own0)
-        else:
-            recv = self._maybe_stop(halo.exchange_tree_ppermute(own0, self.topo, "sub"))
+    def _sharded(self, local, out_specs, state: TrainState, batch: SubBatch,
+                 lr_scale):
+        """Run ``local(params, opt, step, act_code, lr, batch)``, written for
+        ONE subdomain, as a shard_map over "sub": the per-subdomain arguments
+        come in split over "sub" with the shard axis squeezed (``lr`` already
+        scaled by ``lr_scale``), the step replicated.  Each output gets its
+        shard axis back where its spec in ``out_specs`` puts ``"sub"``."""
+        sub = P("sub")
 
-        terms = None
-        for i in range(cfg.local_steps):
-            if i > 0:
-                with jax.named_scope("dd-comp-forward"):
-                    outs, vjp_fn = jax.vjp(net_eval, params)
-            with jax.named_scope("dd-comp-update"):
-                (_, terms), gouts = jax.value_and_grad(assemble, has_aux=True)(outs, recv)
-                (grads,) = vjp_fn(gouts)
-                params, opt = adam_lib.adam_update(grads, opt, params, lr, cfg.adam)
-        if cfg.telemetry:
-            terms = _telemetry_terms(terms, params, grads, lr, stacked=False)
-        return params, opt, terms
-
-    def _build_step(self):
-        spec = P("sub")
-
-        def local_step(params, opt, step, act_code, lr, wmask, batch: SubBatch):
-            # leading axis is the local shard (size 1): squeeze
+        def shard(params, opt, step, act_code, lr, lr_scale, batch):
             sq = lambda t: jax.tree.map(lambda x: x[0], t)
-            params, opt_l, terms = self._local_outer_body(
-                sq(params), sq(opt), act_code[0], lr[0], sq(wmask), sq(batch))
-            unsq = lambda t: jax.tree.map(lambda x: x[None], t)
-            return unsq(params), unsq(opt_l), step + 1, unsq(terms)
+            out = local(sq(params), sq(opt), step, act_code[0],
+                        lr[0] * lr_scale[0], sq(batch))
+            return tuple(
+                o if "sub" not in spec else jax.tree.map(
+                    functools.partial(jnp.expand_dims, axis=spec.index("sub")), o)
+                for o, spec in zip(out, out_specs))
 
-        shmapped = jax.shard_map(
-            local_step,
-            mesh=self.mesh,
-            in_specs=(spec, spec, P(), spec, spec, spec, spec),
-            out_specs=(spec, spec, P(), spec),
-            check_vma=False,
-        )
+        return jax.shard_map(shard, mesh=self.mesh,
+                             in_specs=(sub, sub, P(), sub, sub, sub, sub),
+                             out_specs=out_specs, check_vma=False)(
+            state.params, state.opt, state.step, self.act_codes, self.lrs,
+            lr_scale, batch)
 
-        @jax.jit
-        def step(state: TrainState, batch: SubBatch):
-            p, o, s, terms = shmapped(
-                state.params, state.opt, state.step, self.act_codes, self.lrs,
-                self.width_masks, batch,
-            )
-            return TrainState(params=p, opt=o, step=s), terms
+    def _step(self, state: TrainState, batch: SubBatch):
+        def local(params, opt, step, act_code, lr, batch):
+            params, opt, terms = self._outer_body(params, opt, act_code, lr,
+                                                  batch)
+            return params, opt, step + 1, terms
 
-        return step
+        sub = P("sub")
+        p, o, s, terms = self._sharded(local, (sub, sub, P(), sub), state,
+                                       batch, jnp.ones_like(self.lrs))
+        return TrainState(params=p, opt=o, step=s), terms
 
-    def _build_chunk(self, steps: int):
-        spec = P("sub")
-
-        def local_chunk(params, opt, step, act_code, lr, wmask, batch: SubBatch):
-            sq = lambda t: jax.tree.map(lambda x: x[0], t)
-            p, o = sq(params), sq(opt)
-            ac, l, wm, b = act_code[0], lr[0], sq(wmask), sq(batch)
-
-            def body(carry, _):
-                p, o = carry
-                p, o, terms = self._local_outer_body(p, o, ac, l, wm, b)
+    def _run_chunk_guarded(self, state, batch, steps, lr_scale):
+        def local(params, opt, step, act_code, lr, batch):
+            def live(carry, exchange=True):
+                p, o, terms = self._outer_body(*carry, act_code, lr, batch,
+                                               exchange)
                 return (p, o), terms
 
-            (p, o), terms = jax.lax.scan(body, (p, o), None, length=steps)
-            unsq = lambda t: jax.tree.map(lambda x: x[None], t)
-            # term leaves are (steps,); the shard axis goes SECOND so the
+            ((params, opt), ok, good), terms = _guarded_scan(
+                live, functools.partial(live, exchange=False), (params, opt),
+                (), steps, self._agree, self._norm, self.cfg.telemetry,
+                hoist_nan=True)
+            # good is collectively agreed -> identical on all shards (out P());
+            # term leaves are (steps,), the shard axis goes SECOND so the
             # stitched result is (steps, n_sub)
-            terms = jax.tree.map(lambda x: x[:, None], terms)
-            return unsq(p), unsq(o), step + steps, terms
+            return params, opt, step + good, ok, good, terms
 
-        shmapped = jax.shard_map(
-            local_chunk,
-            mesh=self.mesh,
-            in_specs=(spec, spec, P(), spec, spec, spec, spec),
-            out_specs=(spec, spec, P(), P(None, "sub")),
-            check_vma=False,
-        )
-
-        def chunk(state: TrainState, batch: SubBatch):
-            p, o, s, terms = shmapped(
-                state.params, state.opt, state.step, self.act_codes, self.lrs,
-                self.width_masks, batch,
-            )
-            return TrainState(params=p, opt=o, step=s), terms
-
-        return jax.jit(chunk, donate_argnums=(0,))
-
-    def run_chunk(self, state: TrainState, batch: SubBatch, steps: int):
-        """`steps` outer steps (exchange inside the scan body) in ONE jitted
-        dispatch; ``state`` is donated — rebind it.  Returns (state, terms)
-        with term leaves stacked (steps, n_sub)."""
-        fn = self._chunk_cache.get(steps)
-        if fn is None:
-            fn = self._chunk_cache[steps] = self._build_chunk(steps)
-        return _traced_dispatch(self, "train.run_chunk", steps,
-                                lambda: fn(state, batch))
-
-    # ------------------------------------------------------------ guarded chunk
-    def _build_guarded_chunk(self, steps: int):
-        spec = P("sub")
-
-        def local_chunk(params, opt, step, act_code, lr, lr_scale, wmask,
-                        batch: SubBatch):
-            sq = lambda t: jax.tree.map(lambda x: x[0], t)
-            p, o = sq(params), sq(opt)
-            ac, l, wm, b = act_code[0], lr[0] * lr_scale[0], sq(wmask), sq(batch)
-
-            def live(args):
-                p, o = args
-                p2, o2, t = self._local_outer_body(p, o, ac, l, wm, b)
-                return (p2, o2), t
-
-            # the frozen branch's terms take their shapes from a probe that
-            # skips the exchange, so a trace issues (and counts) the halo once
-            nan_terms = _nan_like(jax.eval_shape(
-                lambda a: self._local_outer_body(*a, ac, l, wm, b,
-                                                 exchange=False)[2], (p, o)))
-
-            def body(carry, _):
-                (p, o), ok, good = carry
-                # collective agreement: every shard freezes the moment ANY
-                # shard trips (one scalar pmin per step — the SPMD analogue of
-                # the reference trainer's jnp.all over the stacked ok vector)
-                vote = ok.astype(jnp.int32)
-                with scope("sync"):
-                    all_ok = jax.lax.pmin(vote, "sub") > 0
-                count_collective("sync", "all-reduce", vote.dtype.itemsize)
-                (p, o), terms = jax.lax.cond(all_ok, live,
-                                             lambda a: (a, nan_terms), (p, o))
-                healthy = jnp.isfinite(terms["loss"]) & jnp.isfinite(_sqnorm(p))
-                ok = jnp.where(all_ok, ok & healthy, ok)
-                if self.cfg.telemetry:
-                    terms = dict(terms, step_ok=ok)
-                return ((p, o), ok, good + all_ok.astype(jnp.int32)), terms
-
-            carry0 = ((p, o), jnp.ones((), bool), jnp.zeros((), jnp.int32))
-            ((p, o), ok, good), terms = jax.lax.scan(body, carry0, None,
-                                                     length=steps)
-            unsq = lambda t: jax.tree.map(lambda x: x[None], t)
-            terms = jax.tree.map(lambda x: x[:, None], terms)
-            # good is collectively agreed -> identical on all shards (out P())
-            return unsq(p), unsq(o), step + good, ok[None], good, terms
-
-        shmapped = jax.shard_map(
-            local_chunk,
-            mesh=self.mesh,
-            in_specs=(spec, spec, P(), spec, spec, spec, spec, spec),
-            out_specs=(spec, spec, P(), spec, P(), P(None, "sub")),
-            check_vma=False,
-        )
-
-        def chunk(state: TrainState, batch: SubBatch, lr_scale):
-            p, o, s, ok, good, terms = shmapped(
-                state.params, state.opt, state.step, self.act_codes, self.lrs,
-                lr_scale, self.width_masks, batch,
-            )
-            health = {"ok": jnp.all(ok), "ok_sub": ok, "good_steps": good}
-            return TrainState(params=p, opt=o, step=s), terms, health
-
-        return jax.jit(chunk, donate_argnums=(0,))
-
-    def run_chunk_guarded(self, state: TrainState, batch: SubBatch, steps: int,
-                          lr_scale=None):
-        """Guarded ``run_chunk`` (see :meth:`ReferenceTrainer.run_chunk_guarded`)
-        on the SPMD path: each shard checks its own loss/params, a per-step
-        scalar ``pmin`` agrees the freeze collectively, and ``health["ok_sub"]``
-        comes back stitched (n_sub,).  Still one jitted dispatch, state
-        donated; ``lr_scale`` is sharded over "sub" like the learning rates."""
-        if lr_scale is None:
-            lr_scale = jnp.ones_like(self.lrs)
-        fn = self._chunk_cache.get(("guarded", steps))
-        if fn is None:
-            fn = self._chunk_cache[("guarded", steps)] = self._build_guarded_chunk(steps)
-        return _traced_dispatch(
-            self, "train.run_chunk_guarded", steps,
-            lambda: fn(state, batch, jnp.asarray(lr_scale)))
+        sub = P("sub")
+        p, o, s, ok, good, terms = self._sharded(
+            local, (sub, sub, P(), sub, P(), P(None, "sub")), state, batch,
+            lr_scale)
+        health = {"ok": jnp.all(ok), "ok_sub": ok, "good_steps": good}
+        return TrainState(params=p, opt=o, step=s), terms, health
 
     def shard_batch(self, batch: SubBatch) -> SubBatch:
         sh = NamedSharding(self.mesh, P("sub"))
@@ -657,7 +545,8 @@ class DataParallelTrainer:
             mesh = Mesh(np.array(devs[:n_workers]), ("sub",))
         self.mesh = mesh
         self.step = self._build_step()
-        self._chunk_cache: dict[int, Any] = {}
+        self._chunk_guarded = jax.jit(self._run_chunk_guarded,
+                                      static_argnums=(2,), donate_argnums=(0,))
         self.tracer = None   # optional repro.obs.Tracer (host chunk spans)
 
     def init(self, seed: int = 0):
@@ -696,7 +585,7 @@ class DataParallelTrainer:
         if self.telemetry:
             # post-allreduce gradient and updated (replicated) params: rows are
             # identical on every worker, matching the terms' P() out-spec
-            terms = _telemetry_terms(terms, new_params, g, lr, stacked=False)
+            terms = _telemetry_terms(terms, new_params, g, lr, _sqnorm)
         return new_params, new_opt, err_l, terms
 
     def _specs(self):
@@ -731,51 +620,7 @@ class DataParallelTrainer:
 
         return step
 
-    def _build_chunk(self, steps: int):
-        comp = self.compression
-
-        def local_chunk(params, opt, err, step, batch: SubBatch):
-            batch = jax.tree.map(lambda x: x[0], batch)
-            err_l = jax.tree.map(lambda x: x[0], err) if comp is not None else err
-
-            def body(carry, _):
-                params, opt, err_l = carry
-                params, opt, err_l, terms = self._local_update(params, opt, err_l, batch)
-                return (params, opt, err_l), terms
-
-            (params, opt, err_l), terms = jax.lax.scan(
-                body, (params, opt, err_l), None, length=steps)
-            err_new = jax.tree.map(lambda x: x[None], err_l) if comp is not None else err
-            return params, opt, err_new, step + steps, terms
-
-        in_specs = self._specs()
-        shmapped = jax.shard_map(
-            local_chunk,
-            mesh=self.mesh,
-            in_specs=in_specs,
-            out_specs=in_specs[:4] + (P(),),
-            check_vma=False,
-        )
-
-        def chunk(state, batch: SubBatch):
-            p, o, e, s, terms = shmapped(
-                state["params"], state["opt"], state["err"], state["step"], batch
-            )
-            return {"params": p, "opt": o, "err": e, "step": s}, terms
-
-        return jax.jit(chunk, donate_argnums=(0,))
-
-    def run_chunk(self, state, batch: SubBatch, steps: int):
-        """`steps` allreduce-Adam updates in ONE jitted dispatch (lax.scan with
-        donated state); term leaves come back stacked (steps,)."""
-        fn = self._chunk_cache.get(steps)
-        if fn is None:
-            fn = self._chunk_cache[steps] = self._build_chunk(steps)
-        return _traced_dispatch(self, "train.run_chunk", steps,
-                                lambda: fn(state, batch))
-
-    # ------------------------------------------------------------ guarded chunk
-    def _build_guarded_chunk(self, steps: int):
+    def _run_chunk_guarded(self, state, batch, steps, lr_scale):
         comp = self.compression
 
         def local_chunk(params, opt, err, step, lr_scale, batch: SubBatch):
@@ -788,60 +633,38 @@ class DataParallelTrainer:
                                                 lr_scale)
                 return (p, o, e), t
 
-            nan_terms = _nan_like(jax.eval_shape(live, (params, opt, err_l))[1])
-
-            def body(carry, _):
-                args, ok, good = carry
-                # params/loss are replicated after the allreduce, so every
-                # worker computes the same verdict — no extra collective
-                args, terms = jax.lax.cond(ok, live,
-                                           lambda a: (a, nan_terms), args)
-                healthy = jnp.isfinite(terms["loss"]) & jnp.isfinite(_sqnorm(args[0]))
-                ok, good = ok & healthy, good + ok.astype(jnp.int32)
-                if self.telemetry:
-                    terms = dict(terms, step_ok=ok)
-                return (args, ok, good), terms
-
-            carry0 = ((params, opt, err_l), jnp.ones((), bool),
-                      jnp.zeros((), jnp.int32))
-            ((params, opt, err_l), ok, good), terms = jax.lax.scan(
-                body, carry0, None, length=steps)
+            # params/loss are replicated after the allreduce, so every worker
+            # computes the same verdict — no extra collective
+            ((params, opt, err_l), ok, good), terms = _guarded_scan(
+                live, live, (params, opt, err_l), (), steps, lambda ok: ok,
+                _sqnorm, self.telemetry, hoist_nan=True)
             err_new = jax.tree.map(lambda x: x[None], err_l) if comp is not None else err
             return params, opt, err_new, step + good, ok, good, terms
 
         in_specs = self._specs()[:4] + (P(), P("sub"))
-        shmapped = jax.shard_map(
+        p, o, e, s, ok, good, terms = jax.shard_map(
             local_chunk,
             mesh=self.mesh,
             in_specs=in_specs,
             out_specs=self._specs()[:4] + (P(), P(), P()),
             check_vma=False,
-        )
-
-        def chunk(state, batch: SubBatch, lr_scale):
-            p, o, e, s, ok, good, terms = shmapped(
-                state["params"], state["opt"], state["err"], state["step"],
-                lr_scale, batch,
-            )
-            health = {"ok": ok, "ok_sub": ok, "good_steps": good}
-            return {"params": p, "opt": o, "err": e, "step": s}, terms, health
-
-        return jax.jit(chunk, donate_argnums=(0,))
+        )(state["params"], state["opt"], state["err"], state["step"],
+          lr_scale, batch)
+        health = {"ok": ok, "ok_sub": ok, "good_steps": good}
+        return {"params": p, "opt": o, "err": e, "step": s}, terms, health
 
     def run_chunk_guarded(self, state, batch: SubBatch, steps: int,
                           lr_scale=None):
-        """Guarded ``run_chunk``: in-graph non-finite loss/param detection with
-        ``lax.cond`` freeze (see :meth:`ReferenceTrainer.run_chunk_guarded`).
-        One network + replicated state means ``health["ok_sub"]`` is the scalar
-        ``ok`` and ``lr_scale`` is a replicated scalar."""
+        """The guarded chunk (see :meth:`_DDCommon.run_chunk_guarded`) of the
+        data-parallel baseline.  One network + replicated state means
+        ``health["ok_sub"]`` is the scalar ``ok`` and ``lr_scale`` is a
+        replicated scalar."""
         if lr_scale is None:
             lr_scale = jnp.ones(())
-        fn = self._chunk_cache.get(("guarded", steps))
-        if fn is None:
-            fn = self._chunk_cache[("guarded", steps)] = self._build_guarded_chunk(steps)
         return _traced_dispatch(
             self, "train.run_chunk_guarded", steps,
-            lambda: fn(state, batch, jnp.asarray(lr_scale, jnp.float32)))
+            lambda: self._chunk_guarded(state, batch, steps,
+                                        jnp.asarray(lr_scale, jnp.float32)))
 
 
 # ------------------------------------------------------------------ checkpointing
@@ -861,7 +684,7 @@ def restore_train_state(root: str, like: TrainState,
 
     ``like`` (e.g. a fresh ``trainer.init()``) fixes the pytree structure;
     restored leaves come back as committed device arrays so the result feeds
-    straight into the donating ``run_chunk`` drivers.  Bitwise resume is
+    straight into the donating guarded chunk.  Bitwise resume is
     asserted in ``tests/test_serve.py``.
     """
     from repro.checkpoint import ckpt
@@ -884,7 +707,6 @@ def evaluate_l2(
     pde: PDE,
     n_pts: int = 2000,
     seed: int = 0,
-    width_masks=None,
 ) -> float:
     """Relative L2 error of the stitched solution (eq. 4) against pde.exact.
 
@@ -908,7 +730,7 @@ def evaluate_l2(
     # the batched *_from_derivs methods (jvp-fallback-only) must still eval
     bundle = FieldBundle(model_cfg=model_cfg, params=params, decomp=decomp,
                          act_codes=np.asarray(act_codes, np.int32),
-                         width_masks=width_masks, pde=None)
+                         pde=None)
     # tol=0: the points are sampled strictly inside their subdomains (no
     # interface widening needed), and plain containment routing keeps custom
     # Decomposition subclasses working (tol > 0 is Cartesian/Polygon-only)

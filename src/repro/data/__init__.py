@@ -1,2 +1,1 @@
-from repro.data.points import (StackedBatch, make_batch, make_vanilla_batch,
-                               stack_batches)
+from repro.data.points import StackedBatch, make_batch, make_vanilla_batch

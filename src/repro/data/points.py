@@ -4,13 +4,14 @@ Builds the stacked, padded :class:`~repro.core.losses.SubBatch` arrays consumed 
 the trainers.  Per-subdomain residual counts may differ (paper Table 3); arrays are
 padded to the max and masked.  ``balance=True`` equalizes points per worker — the
 straggler mitigation the paper itself suggests for its §7.6 load-imbalance problem.
+A trainer's guarded chunk (``run_chunk_guarded``) reuses one batch for all of its
+steps; resampling points means a new batch for the next chunk.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -113,17 +114,6 @@ def make_batch(
         iface_nrm=topo.iface_normal.astype(np.float32),
         edge_mask=topo.edge_mask.astype(np.float32),
     )
-
-
-def stack_batches(batches: Sequence[SubBatch]) -> SubBatch:
-    """Stack per-step SubBatches along a NEW leading chunk axis.
-
-    The result feeds ``trainer.run_chunk(state, stacked)`` (steps=None): the
-    scanned epoch driver consumes one batch per outer step — e.g. freshly
-    resampled collocation points — while still compiling to a single dispatch.
-    All batches must share the padded layout (same point counts).
-    """
-    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *batches)
 
 
 def make_vanilla_batch(

@@ -198,7 +198,7 @@ def training_timeline(chunk_spans, topo, halo: dict | None = None,
     """Per-subdomain lanes + halo arrows for a supervised training trace.
 
     ``chunk_spans``: committed chunk-level spans (one per supervisor chunk or
-    run_chunk dispatch).  ``topo``: a ``core.domain.Topology`` (its
+    guarded chunk dispatch).  ``topo``: a ``core.domain.Topology`` (its
     ``neighbor`` table gives the directed edges).  ``halo``: the dict from
     :func:`repro.obs.profiling.halo_traffic` on the lowered chunk HLO, used
     to weight the arrows with real byte counts (0 when absent, e.g. the

@@ -3,7 +3,7 @@
 PR 8's registry answers "how much" (histograms, counters); this module answers
 "where in the lifecycle": every serve request and every training chunk gets a
 **trace** — a tree of timestamped **spans** (admission -> queue -> microbatch
-pack -> dispatch -> engine eval; supervisor chunk -> run_chunk dispatch ->
+pack -> dispatch -> engine eval; supervisor chunk -> guarded chunk dispatch ->
 rollback/recovery) sharing one ``trace_id`` that travels with the work across
 subsystem boundaries (it surfaces on ``ServeResult.trace_id`` and in the
 supervisor's JSONL events).  The span buffer feeds the Chrome-trace/Perfetto

@@ -12,7 +12,7 @@ metric by a common factor run-to-run, and a naive per-metric threshold either
 fires on that noise or is too loose to catch real regressions.  So each
 metric's ratio vs its trailing median is divided by the *median ratio across
 all metrics of the run* (the common-mode drift estimate — the same
-paired-ratio philosophy as ``fig4_cost_profile``, applied across the history
+paired-ratio philosophy as ``benchmarks.common``, applied across the history
 axis): a global 30% slow day cancels out; one benchmark doubling while its
 peers hold still does not.  Gating needs ``min_runs`` prior records for a
 metric (a cold history never blocks) and only metrics whose UNIT names a

@@ -13,9 +13,10 @@ CPU, at two granularities:
   through ``launch/train.py --resume`` (see tests/test_checkpoint.py).
 
 * the **chunk-granular fault matrix** — :class:`Fault` / :class:`FaultInjector`
-  drive the PINN trainers' single-dispatch chunk world (one ``run_chunk`` ==
-  one scheduling unit), consumed by ``runtime.supervisor.Supervisor``.  Beyond
-  crashes it covers the failure modes a crash-only harness can't see:
+  drive the PINN trainers' single-dispatch chunk world (one
+  ``run_chunk_guarded`` == one scheduling unit), consumed by
+  ``runtime.supervisor.Supervisor``.  Beyond crashes it covers the failure
+  modes a crash-only harness can't see:
 
   ========== ============================================================
   kind        effect at the scheduled chunk

@@ -99,8 +99,8 @@ print("RESUME-OK")
 
 
 # PINN-trainer checkpoint wiring (save_train_state / restore_train_state with
-# bitwise resume through run_chunk) is covered in tests/test_serve.py, which
-# stays collected when `hypothesis` is absent and this module is skipped.
+# bitwise resume through the guarded chunk) is covered in tests/test_serve.py,
+# which stays collected when `hypothesis` is absent and this module is skipped.
 
 
 # ---------------------------------------------------------------- elasticity

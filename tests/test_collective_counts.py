@@ -34,8 +34,8 @@ for name, off in (("sound", False), ("no_exchange", True)):
     assert bool(health["ok"]), health
     out[name] = delta(before, collective_counts())
     if name == "sound":
-        hlo = tr._chunk_cache[("guarded", 1)].lower(
-            tr.shard_state(tr.init(0)), batch, np.ones(4, np.float32)
+        hlo = tr._chunk_guarded.lower(
+            tr.shard_state(tr.init(0)), batch, 1, np.ones(4, np.float32)
         ).compile().as_text()
         out["all_reduce_op_names"] = [
             ln.split('op_name="')[1].split('"')[0]

@@ -187,7 +187,8 @@ def test_elastic_resume_4_to_6_reconverges(tmp_path):
                            n_pts=400)
     assert err_warm < err_cold, (err_warm, err_cold)
 
-    resumed, terms = tr2.run_chunk(resumed, b2, 400)
+    resumed, terms, health = tr2.run_chunk_guarded(resumed, b2, 400)
+    assert bool(health["ok"])
     err_new = evaluate_l2(dec2, cfg2, resumed.params, tr2.act_codes, pde2,
                           n_pts=400)
     assert np.isfinite(np.asarray(terms["loss"])).all()

@@ -183,7 +183,7 @@ def _max_diff(a, b):
 
 def test_telemetry_rows_shapes_and_values():
     _, _, _, b, tr = _setup(telemetry=True, lrs=1e-3)
-    _, terms = tr.run_chunk(tr.init(0), b, 3)
+    _, terms, _ = tr.run_chunk_guarded(tr.init(0), b, 3)
     for k in ("grad_norm", "param_norm", "lr", "iface_mismatch"):
         assert terms[k].shape == (3, 4), k
         assert np.isfinite(np.asarray(terms[k])).all(), k
@@ -197,8 +197,8 @@ def test_telemetry_rows_shapes_and_values():
 def test_telemetry_off_keeps_terms_and_params_bitwise():
     _, _, _, b, tr_off = _setup(telemetry=False)
     _, _, _, _, tr_on = _setup(telemetry=True)
-    s_off, t_off = tr_off.run_chunk(tr_off.init(0), b, 3)
-    s_on, t_on = tr_on.run_chunk(tr_on.init(0), b, 3)
+    s_off, t_off, _ = tr_off.run_chunk_guarded(tr_off.init(0), b, 3)
+    s_on, t_on, _ = tr_on.run_chunk_guarded(tr_on.init(0), b, 3)
     assert set(t_off) == {"loss", "mse_data", "mse_res", "mse_avg",
                           "mse_iface"}           # off-mode key regression
     assert set(t_on) > set(t_off)
@@ -222,7 +222,7 @@ def test_guarded_telemetry_step_ok_and_lr_scale_row():
 def test_data_parallel_telemetry_rows():
     pde, dec, cfg, b, _tr = _setup()
     tr = DataParallelTrainer(pde, cfg, n_workers=1, lr=1e-3, telemetry=True)
-    _, terms = tr.run_chunk(tr.init(0), b, 2)
+    _, terms, _ = tr.run_chunk_guarded(tr.init(0), b, 2)
     assert "iface_mismatch" not in terms    # data-parallel has no interfaces
     for k in ("grad_norm", "param_norm", "lr"):
         assert terms[k].shape[0] == 2 and np.isfinite(np.asarray(terms[k])).all()
@@ -247,8 +247,7 @@ def test_telemetry_guarded_single_dispatch_donation_and_hlo():
     ops.pinn_mlp_forward2 = lambda *a, **k: (calls.append(1),
                                              orig(*a, **k))[1]
     try:
-        jax.jit(tr._run_chunk_guarded, static_argnums=(2,)).lower(
-            state, b, 5, ones)
+        tr._chunk_guarded.lower(state, b, 5, ones)
     finally:
         ops.pinn_mlp_forward2 = orig
     assert len(calls) == 2
@@ -257,9 +256,8 @@ def test_telemetry_guarded_single_dispatch_donation_and_hlo():
         return sum(1 for ln in txt.splitlines()
                    if " pad(" in ln and "f32[4,128,128]" in ln)
 
-    telem = jax.jit(tr._run_chunk_guarded, static_argnums=(2,)).lower(
-        state, b, 3, ones).compile().as_text()
-    plain = jax.jit(tr_plain._run_chunk_guarded, static_argnums=(2,)).lower(
+    telem = tr._chunk_guarded.lower(state, b, 3, ones).compile().as_text()
+    plain = tr_plain._chunk_guarded.lower(
         tr_plain.init(0), b, 3, ones).compile().as_text()
     # one (L, 128, 128) stack per loss eval: the depth-2 net's hidden and
     # output matrices (the first layer packs against x's rows)
@@ -275,8 +273,8 @@ def test_telemetry_guarded_single_dispatch_donation_and_hlo():
 
 def test_named_scopes_survive_into_compiled_hlo():
     _, _, _, b, tr = _setup(n_res=32)
-    hlo = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
-        tr.init(0), b, 2).compile().as_text()
+    hlo = tr._chunk_guarded.lower(tr.init(0), b, 2,
+                                  jnp.ones((4,))).compile().as_text()
     scopes = named_scope_counts(hlo, prefix="dd-")
     assert scopes.get("dd-comp-forward", 0) > 0
     assert scopes.get("dd-comp-update", 0) > 0
@@ -405,13 +403,14 @@ def test_compile_tracer(case, tmp_path):
 
 
 def test_retrace_flat_across_guard_and_lr_scale():
-    """Warm both chunk drivers once; interleaving them and sweeping lr_scale
-    must never compile again (the supervisor backoff guarantee, asserted)."""
+    """Warm the per-step reference and the guarded chunk once; interleaving
+    them and sweeping lr_scale must never compile again (the supervisor
+    backoff guarantee, asserted)."""
     _, _, _, b, tr = _setup(n_res=32, telemetry=True)
-    st = tr.run_chunk(tr.init(0), b, 2)[0]
+    st = tr.step(tr.init(0), b)[0]
     stg = tr.run_chunk_guarded(tr.init(0), b, 2)[0]
     with CompileWatcher() as w:
-        st = tr.run_chunk(st, b, 2)[0]
+        st = tr.step(st, b)[0]
         for s in (1.0, 0.5, 0.25):
             stg = tr.run_chunk_guarded(stg, b, 2,
                                        lr_scale=jnp.full((4,), s))[0]
@@ -571,12 +570,12 @@ b = make_batch(dec, topo, pde, 32, 8, np.random.default_rng(0)).device_arrays()
 tr = DistributedDDTrainer(pde, cfg, topo,
                           DDConfig(method=XPINN, telemetry=True), lrs=1e-3)
 bd = tr.shard_batch(b)
-st, terms = tr.run_chunk(tr.shard_state(tr.init(0)), bd, 2)
+st, terms, _ = tr.run_chunk_guarded(tr.shard_state(tr.init(0)), bd, 2)
 assert terms["grad_norm"].shape == (2, 4), terms["grad_norm"].shape
 assert terms["lr"].shape == (2, 4)
 assert np.isfinite(np.asarray(terms["iface_mismatch"])).all()
-hlo = tr._build_chunk(2).lower(tr.shard_state(tr.init(0)), bd)\\
-    .compile().as_text()
+hlo = tr._chunk_guarded.lower(tr.shard_state(tr.init(0)), bd, 2,
+                              np.ones(4, np.float32)).compile().as_text()
 traffic = halo_traffic(hlo)
 assert traffic["collective_permute_ops"] > 0
 scopes = named_scope_counts(hlo, prefix="dd-")

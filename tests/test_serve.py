@@ -392,7 +392,7 @@ def test_frontend_lru_eviction():
 
 def test_pinn_train_resume_bitwise(tmp_path):
     """repro.checkpoint wired into the PINN trainers (save_train_state /
-    restore_train_state): a run interrupted mid-way through its run_chunk
+    restore_train_state): a run interrupted mid-way through its chunk
     schedule and resumed from the checkpoint matches the uninterrupted
     ReferenceTrainer run BITWISE."""
     from repro.core import (
@@ -411,15 +411,15 @@ def test_pinn_train_resume_bitwise(tmp_path):
     tr = ReferenceTrainer(pde, cfg, topo, DDConfig(method=XPINN,
                                                    residual_path="pallas"))
 
-    s_full, _ = tr.run_chunk(tr.init(0), b, 4)           # uninterrupted
+    s_full, _, _ = tr.run_chunk_guarded(tr.init(0), b, 4)   # uninterrupted
 
-    s_half, _ = tr.run_chunk(tr.init(0), b, 2)           # interrupted at 2...
+    s_half, _, _ = tr.run_chunk_guarded(tr.init(0), b, 2)   # interrupted at 2...
     root = str(tmp_path / "ckpt")
     save_train_state(root, s_half)
     del s_half
     s_res = restore_train_state(root, tr.init(0))        # ...resumed
     assert int(s_res.step) == 2 and ckpt.latest_step(root) == 2
-    s_res, _ = tr.run_chunk(s_res, b, 2)
+    s_res, _, _ = tr.run_chunk_guarded(s_res, b, 2)
 
     assert int(s_res.step) == int(s_full.step) == 4
     for a, c in zip(jax.tree.leaves((s_full.params, s_full.opt)),

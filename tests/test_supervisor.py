@@ -2,14 +2,16 @@
 
 Covers the robustness contract (EXPERIMENTS.md §Robustness):
 
-* ``run_chunk_guarded`` bitwise-matches ``run_chunk`` on healthy runs (all
-  three trainers; Distributed in a 4-device subprocess) and adds no compute
-  to the hot path — the guarded chunk body still traces/packs the megabatched
-  network entry exactly once per loss evaluation (trace + HLO asserted);
+* ``run_chunk_guarded`` bitwise-matches the unguarded per-step loop
+  (``step``) on healthy runs (all three trainers; Distributed in a 4-device
+  subprocess) and adds no compute to the hot path — the guarded chunk body
+  still traces/packs the megabatched network entry exactly once per loss
+  evaluation (trace + HLO asserted);
 * injected NaNs trip the guard within ONE chunk, with per-subdomain
   attribution: ``nan_params`` flags the poisoned subdomain and its interface
   neighbors (never the diagonal), ``nan_grads`` keeps the loss finite and is
-  caught by the param-norm check alone;
+  caught by the param-norm check alone; the stacked and the sharded trainer
+  share the guard and attribute a trip alike;
 * a crash mid-chunk (compute done, checkpoint lost) rolls back and replays —
   the recovered run equals the uninterrupted run BITWISE on ReferenceTrainer
   and DataParallelTrainer;
@@ -73,14 +75,20 @@ def _poison(tr, kind, subdomain):
 # ------------------------------------------------------------- guarded chunk
 
 def test_guarded_chunk_matches_unguarded_bitwise():
+    """On a healthy run the guarded chunk is the unguarded per-step loop:
+    same state and, row by row, the same terms."""
     pde, dec, cfg, b, tr = _setup()
-    s_u, t_u = tr.run_chunk(tr.init(0), b, 5)
+    s_u, t_u = tr.init(0), []
+    for _ in range(5):
+        s_u, t = tr.step(s_u, b)
+        t_u.append(t)
     s_g, t_g, health = tr.run_chunk_guarded(tr.init(0), b, 5)
     assert _max_diff(s_u.params, s_g.params) == 0.0
     assert _max_diff(s_u.opt, s_g.opt) == 0.0
     assert int(s_g.step) == 5
-    for k in t_u:
-        np.testing.assert_array_equal(np.asarray(t_u[k]), np.asarray(t_g[k]))
+    for k in t_g:
+        np.testing.assert_array_equal(np.stack([t[k] for t in t_u]),
+                                      np.asarray(t_g[k]))
     assert bool(health["ok"]) and np.asarray(health["ok_sub"]).all()
     assert int(health["good_steps"]) == 5
 
@@ -89,7 +97,9 @@ def test_guarded_data_parallel_matches_unguarded_bitwise():
     pde, dec, cfg, b, tr_ref = _setup()
     tr = DataParallelTrainer(pde, cfg, n_workers=1, residual_path="pallas")
     bd = jax.tree.map(lambda x: x[:1], b)
-    s_u, _ = tr.run_chunk(tr.init(0), bd, 4)
+    s_u = tr.init(0)
+    for _ in range(4):
+        s_u, _ = tr.step(s_u, bd)
     s_g, _, health = tr.run_chunk_guarded(tr.init(0), bd, 4)
     assert _max_diff(s_u["params"], s_g["params"]) == 0.0
     assert _max_diff(s_u["opt"], s_g["opt"]) == 0.0
@@ -133,9 +143,8 @@ def test_guarded_chunk_adds_no_network_entries_or_weight_packs():
     guarded body touches the megabatched entry twice per loss eval — one
     abstract ``eval_shape`` structure probe (compiles to nothing) plus the ONE
     live ``lax.cond`` branch — independent of chunk length.  HLO level: the
-    compiled guarded chunk packs the layer weight stack exactly as often as
-    the unguarded chunk (once per loss eval), so the frozen branch and health
-    checks add no network compute."""
+    compiled guarded chunk packs the layer weight stack once per loss eval,
+    so the frozen branch and health checks add no network compute."""
     pde, dec, cfg, b, tr = _setup(n_res=32)
     tr.res_path = ResidualPath(act="tanh", block_n=32, interpret=True)
     state = tr.init(0)
@@ -147,8 +156,7 @@ def test_guarded_chunk_adds_no_network_entries_or_weight_packs():
         ops.pinn_mlp_forward2 = lambda *a, **k: (calls.append(1),
                                                  orig(*a, **k))[1]
         try:
-            jax.jit(tr._run_chunk_guarded, static_argnums=(2,)).lower(
-                state, b, steps, ones)
+            tr._chunk_guarded.lower(state, b, steps, ones)
         finally:
             ops.pinn_mlp_forward2 = orig
         return len(calls)
@@ -159,13 +167,10 @@ def test_guarded_chunk_adds_no_network_entries_or_weight_packs():
         return sum(1 for ln in txt.splitlines()
                    if " pad(" in ln and "f32[4,128,128]" in ln)
 
-    guarded = jax.jit(tr._run_chunk_guarded, static_argnums=(2,)).lower(
-        state, b, 3, ones).compile().as_text()
-    unguarded = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
-        state, b, 3).compile().as_text()
+    guarded = tr._chunk_guarded.lower(state, b, 3, ones).compile().as_text()
     # one (L, 128, 128) stack per loss eval: the depth-2 net's hidden and
     # output matrices (the first layer packs against x's rows)
-    assert weight_pads(guarded) == weight_pads(unguarded) == 2
+    assert weight_pads(guarded) == 2
 
 
 # ---------------------------------------------------------------- supervisor
@@ -185,7 +190,7 @@ def test_supervisor_crash_recovery_bitwise(tmp_path):
 
     s_b = tr.init(0)
     for _ in range(3):
-        s_b, _ = tr.run_chunk(s_b, b, 3)
+        s_b, _, _ = tr.run_chunk_guarded(s_b, b, 3)
     assert int(s_f.step) == int(s_b.step) == 9
     assert _max_diff(s_f.params, s_b.params) == 0.0
     assert _max_diff(s_f.opt, s_b.opt) == 0.0
@@ -352,9 +357,11 @@ bd = tr.shard_batch(b)
 md = lambda a, c: max(float(np.max(np.abs(np.asarray(x)-np.asarray(y))))
                       for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(c)))
 
-# guarded == unguarded on the healthy path (separately compiled SPMD programs:
-# float-noise tolerance, same as the run_chunk-vs-step-loop contract)
-s_u, t_u = tr.run_chunk(tr.shard_state(tr.init(0)), bd, 4)
+# guarded == the unguarded step loop on the healthy path (separately compiled
+# SPMD programs: float-noise tolerance, same as the chunk-vs-step-loop contract)
+s_u = tr.shard_state(tr.init(0))
+for _ in range(4):
+    s_u, _ = tr.step(s_u, bd)
 s_g, t_g, health = tr.run_chunk_guarded(tr.shard_state(tr.init(0)), bd, 4)
 assert md(s_u.params, s_g.params) < 1e-7
 assert bool(np.asarray(health["ok"])) and int(np.asarray(health["good_steps"])) == 4
@@ -388,3 +395,59 @@ print("DIST-FT-OK")
 def test_distributed_guarded_and_crash_recovery(subproc):
     out = subproc(DIST_FT_CODE, n_devices=4, timeout=900)
     assert "DIST-FT-OK" in out
+
+
+TRIP_ACROSS_CODE = """
+import numpy as np, jax
+from repro.core import *
+from repro.core.nets import MLPConfig, SubdomainModelConfig
+from repro.core.trainer import TrainState
+from repro.data import make_batch
+from repro.runtime import inject_nan
+
+pde = Burgers1D()
+dec = CartesianDecomposition(((-1,1),(0,1)), nx=2, ny=2)
+topo = build_topology(dec, n_iface=8)
+cfg = SubdomainModelConfig(nets={"u": MLPConfig(2,1,16,2)})
+b = make_batch(dec, topo, pde, n_res=48, n_bnd=16,
+               rng=np.random.default_rng(0)).device_arrays()
+dd = DDConfig(method=XPINN, residual_path="pallas")
+lrs = [1e-3, 2e-3, 3e-3, 4e-3]
+
+def poisoned(tr):
+    st = tr.init(0)
+    tree = inject_nan({"params": st.params, "opt": st.opt, "step": st.step},
+                      "nan_params", 0)
+    return TrainState(params=tree["params"], opt=tree["opt"], step=tree["step"])
+
+ref = ReferenceTrainer(pde, cfg, topo, dd, lrs=lrs)
+s_r, t_r, h_r = ref.run_chunk_guarded(poisoned(ref), b, 4)
+dist = DistributedDDTrainer(pde, cfg, topo, dd, lrs=lrs)
+s_d, t_d, h_d = dist.run_chunk_guarded(dist.shard_state(poisoned(dist)),
+                                       dist.shard_batch(b), 4)
+ok_r, ok_d = np.asarray(h_r["ok_sub"]), np.asarray(h_d["ok_sub"])
+assert not ok_r[0] and ok_r[3], ok_r
+np.testing.assert_array_equal(ok_r, ok_d)
+assert int(h_r["good_steps"]) == int(h_d["good_steps"]) == 1
+assert not bool(h_r["ok"]) and not bool(h_d["ok"])
+assert int(s_r.step) == int(np.asarray(s_d.step)) == 1
+# the trip row and the frozen rows after it, term by term: NaN where the
+# other layout has NaN, and the finite terms to float32 rounding (the stacked
+# and the sharded step are separately compiled programs: the residual sums
+# may differ in the last bit, as in the chunk-vs-step-loop contract)
+assert set(t_r) == set(t_d)
+for k in t_r:
+    np.testing.assert_allclose(np.asarray(t_d[k]), np.asarray(t_r[k]),
+                               rtol=1e-6, atol=0, err_msg=k)
+print("TRIP-ACROSS-OK")
+"""
+
+
+@pytest.mark.slow
+def test_guard_trip_attribution_matches_across_trainers(subproc):
+    """The one guard on both layouts: a subdomain planted with NaN trips the
+    stacked trainer's ``jnp.all`` and the sharded trainer's ``pmin`` vote at
+    the same step, with the same ``ok_sub`` attribution, the same NaN rows
+    after the trip and the same finite terms before it."""
+    out = subproc(TRIP_ACROSS_CODE, n_devices=4, timeout=600)
+    assert "TRIP-ACROSS-OK" in out

@@ -240,8 +240,8 @@ def test_four_chip_cpinn_chunk_compiles_for_v5e(v5e_2x2):
             sds(np.ones(4, np.float32), sub))
 
     before = launch_counts()
-    hlo = _compile(tr._build_guarded_chunk(100), args,
-                   cfg["matmul_precision"])
+    hlo = _compile(lambda s, b, lr: tr._run_chunk_guarded(s, b, 100, lr),
+                   args, cfg["matmul_precision"])
     after = launch_counts()
     _assert_launch_layouts(before, after, "5_per_tile")
     assert "tpu_custom_call" in hlo and "pinn2-bwd-ref" not in hlo

@@ -1,15 +1,16 @@
-"""Single-dispatch training: scanned run_chunk drivers + megabatched entry.
+"""Single-dispatch training: the scanned guarded chunk + megabatched entry.
 
 Covers the step-fusion contract (EXPERIMENTS.md §Step fusion):
 
-* ``run_chunk(state, batch, n)`` bitwise-matches ``n`` sequential ``step()``
-  calls (Reference in-process, Distributed in a 4-device subprocess), incl.
-  ``local_steps > 1`` and per-step stacked batches;
+* ``run_chunk_guarded(state, batch, n)`` bitwise-matches ``n`` sequential
+  ``step()`` calls (Reference in-process, Distributed in a 4-device
+  subprocess), incl. ``local_steps > 1``;
 * ``TrainState`` donation: buffers really alias in place and repeated chunks
   never trip stale-buffer reuse;
-* one loss evaluation == ONE megabatched network entry (trace-counted) and one
-  packed weight stack per chunk body (HLO pad count — extends the PR-1 CSE
-  test to the scanned driver);
+* one loss evaluation == ONE megabatched network entry (trace-counted, beside
+  the guard's abstract shape probe) and one packed weight stack per chunk
+  body (HLO pad count — extends the weight-pack CSE test to the scanned
+  driver);
 * DataParallelTrainer derives its activation from the model config.
 """
 import jax
@@ -25,7 +26,7 @@ from repro.core import nets
 from repro.core.losses import CPINN, ResidualPath
 from repro.core.nets import MLPConfig, SubdomainModelConfig
 from repro.core.trainer import DataParallelTrainer
-from repro.data import make_batch, stack_batches
+from repro.data import make_batch
 from repro.kernels import ops
 
 
@@ -44,6 +45,11 @@ def _max_diff(a, b):
                for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
 
 
+def _lower_chunk(tr, state, b, steps):
+    """The trainer's guarded chunk, lowered (not run: the state survives)."""
+    return tr._chunk_guarded.lower(state, b, steps, jnp.ones_like(tr.lrs))
+
+
 @pytest.mark.parametrize("path", ["jvp", "pallas"])
 @pytest.mark.parametrize("method,local_steps", [(XPINN, 1), (CPINN, 2)])
 def test_reference_chunk_matches_step_loop_bitwise(path, method, local_steps):
@@ -54,7 +60,8 @@ def test_reference_chunk_matches_step_loop_bitwise(path, method, local_steps):
     s_loop = tr.init(0)
     for _ in range(3):
         s_loop, t_loop = tr.step(s_loop, b)
-    s_chunk, t_chunk = tr.run_chunk(tr.init(0), b, 3)
+    s_chunk, t_chunk, health = tr.run_chunk_guarded(tr.init(0), b, 3)
+    assert bool(health["ok"]) and int(health["good_steps"]) == 3
     assert _max_diff(s_loop.params, s_chunk.params) == 0.0
     assert _max_diff(s_loop.opt, s_chunk.opt) == 0.0
     assert int(s_chunk.step) == 3
@@ -64,21 +71,6 @@ def test_reference_chunk_matches_step_loop_bitwise(path, method, local_steps):
                                       np.asarray(t_loop[k]))
 
 
-def test_reference_chunk_stacked_batches_matches_sequential_steps():
-    """steps=None mode: leaves carry a leading chunk axis, one batch per step."""
-    pde, dec, topo, cfg, _ = _setup()
-    tr = ReferenceTrainer(pde, cfg, topo, DDConfig(residual_path="pallas"))
-    batches = [make_batch(dec, topo, pde, n_res=64, n_bnd=16,
-                          rng=np.random.default_rng(s)).device_arrays()
-               for s in range(3)]
-    s_loop = tr.init(1)
-    for bb in batches:
-        s_loop, _ = tr.step(s_loop, bb)
-    s_chunk, terms = tr.run_chunk(tr.init(1), stack_batches(batches))
-    assert _max_diff(s_loop.params, s_chunk.params) == 0.0
-    assert np.asarray(terms["loss"]).shape == (3, topo.n_sub)
-
-
 def test_run_chunk_donates_state_and_chains_cleanly():
     """donate_argnums on TrainState: the old buffers die (no silent copies)
     and chaining chunks off the returned state never hits stale-buffer reuse."""
@@ -86,12 +78,12 @@ def test_run_chunk_donates_state_and_chains_cleanly():
     tr = ReferenceTrainer(pde, cfg, topo, DDConfig(residual_path="pallas"))
     state = tr.init(0)
     leaves0 = jax.tree.leaves(state.params) + jax.tree.leaves(state.opt)
-    state, _ = tr.run_chunk(state, b, 2)
+    state, _, _ = tr.run_chunk_guarded(state, b, 2)
     assert all(leaf.is_deleted() for leaf in leaves0), \
         "donated TrainState buffers were copied instead of aliased"
     # the returned state is fresh and immediately reusable — twice
     for expect in (4, 6):
-        state, terms = tr.run_chunk(state, b, 2)
+        state, terms, _ = tr.run_chunk_guarded(state, b, 2)
         assert int(state.step) == expect
         assert np.isfinite(np.asarray(terms["loss"])).all()
 
@@ -100,8 +92,9 @@ def test_run_chunk_donates_state_and_chains_cleanly():
 def test_chunk_body_has_one_network_entry_per_loss_eval(local_steps):
     """Acceptance: the jitted chunk traces exactly ONE megabatched
     pinn_mlp_forward2 entry per loss evaluation — the exchange payload rides
-    on inner step 1's forward (jax.vjp), so local_steps == entries, regardless
-    of chunk length."""
+    on inner step 1's forward (jax.vjp), so local_steps == live entries,
+    regardless of chunk length.  The guard's abstract ``eval_shape`` probe of
+    the same step traces them once more and compiles to nothing."""
     pde, dec, topo, cfg, b = _setup(n_res=32, width=16, depth=2)
     tr = ReferenceTrainer(pde, cfg, topo,
                           DDConfig(residual_path="pallas",
@@ -111,12 +104,12 @@ def test_chunk_body_has_one_network_entry_per_loss_eval(local_steps):
     orig = ops.pinn_mlp_forward2
     ops.pinn_mlp_forward2 = lambda *a, **k: (calls.append(1), orig(*a, **k))[1]
     try:
-        jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(state, b, 5)
+        _lower_chunk(tr, state, b, 5)
     finally:
         ops.pinn_mlp_forward2 = orig
-    assert len(calls) == local_steps, (
+    assert len(calls) == 2 * local_steps, (
         f"chunk body traced {len(calls)} network entries for "
-        f"{local_steps} loss evaluations")
+        f"{local_steps} loss evaluations and their shape probe")
 
 
 def test_chunk_hlo_packs_weights_once_per_loss_eval():
@@ -137,16 +130,14 @@ def test_chunk_hlo_packs_weights_once_per_loss_eval():
         return sum(1 for ln in txt.splitlines()
                    if " pad(" in ln and "f32[4,128,128]" in ln)
 
-    txt3 = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
-        state, b, 3).compile().as_text()
+    txt3 = _lower_chunk(tr, state, b, 3).compile().as_text()
     # depth-2 MLP: the hidden and the output weight matrix; the first
     # layer packs against x's rows, not into the (L, 128, 128) stack
     n_layer_mats = 2
     assert weight_pads(txt3) == n_layer_mats, \
         "chunk body packs the weight stack more than once per loss evaluation"
     # chunk length must not change the per-body pack count
-    txt1 = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
-        state, b, 1).compile().as_text()
+    txt1 = _lower_chunk(tr, state, b, 1).compile().as_text()
     assert weight_pads(txt1) == weight_pads(txt3)
 
 
@@ -161,8 +152,7 @@ def test_chunk_backward_routes_through_selected_reverse(backward_path):
                           DDConfig(residual_path="pallas",
                                    backward_path=backward_path))
     state = tr.init(0)
-    txt = jax.jit(tr._run_chunk_const, static_argnums=(2,)).lower(
-        state, b, 2).compile().as_text()
+    txt = _lower_chunk(tr, state, b, 2).compile().as_text()
     has_fused, has_ref = "pinn2-bwd-fused" in txt, "pinn2-bwd-ref" in txt
     if backward_path == "fused":
         assert has_fused and not has_ref, (has_fused, has_ref)
@@ -180,7 +170,7 @@ def test_chunk_fused_and_ref_backward_agree():
         tr = ReferenceTrainer(pde, cfg, topo,
                               DDConfig(residual_path="pallas",
                                        backward_path=bp))
-        _, terms = tr.run_chunk(tr.init(0), b, 10)
+        _, terms, _ = tr.run_chunk_guarded(tr.init(0), b, 10)
         final[bp] = np.asarray(terms["loss"])[-1]
     np.testing.assert_allclose(final["fused"], final["ref"], rtol=1e-3,
                                atol=1e-6)
@@ -259,7 +249,9 @@ for path, local_steps in [("pallas", 1), ("jvp", 2)]:
     s_loop = tr.shard_state(tr.init(0))
     for _ in range(3):
         s_loop, t_loop = tr.step(s_loop, bd)
-    s_chunk, t_chunk = tr.run_chunk(tr.shard_state(tr.init(0)), bd, 3)
+    s_chunk, t_chunk, health = tr.run_chunk_guarded(tr.shard_state(tr.init(0)),
+                                                    bd, 3)
+    assert bool(health["ok"]) and int(health["good_steps"]) == 3
     err = max(float(np.max(np.abs(np.asarray(a)-np.asarray(c))))
               for a, c in zip(jax.tree.leaves(s_loop.params),
                               jax.tree.leaves(s_chunk.params)))
